@@ -392,7 +392,8 @@ _SKIP_DIRS = {".git", "__pycache__", ".pytest_cache", "node_modules",
 # top-level entry points. Tests are deliberately excluded (they use
 # literal keys and host syncs by design); fixtures under tests/ are
 # linted explicitly by tests/test_analysis.py.
-DEFAULT_TARGETS = ("bnsgcn_tpu", "tools", "bench.py", "__graft_entry__.py")
+DEFAULT_TARGETS = ("bnsgcn_tpu", "tools", "bench.py", "chip_smoke.py",
+                   "__graft_entry__.py")
 
 
 def iter_py_files(paths: list[str], root: str) -> list[str]:

@@ -91,7 +91,7 @@ def trace_variant(variant: Variant, g, art, full_set: bool = False,
     cfg = audit_config(g, variant)
     spec = ModelSpec(cfg.model, (g.n_feat, AUDIT_HIDDEN, g.n_class),
                      norm="layer", dropout=0.0, train_size=g.n_train)
-    mesh = AbstractMesh((("parts", AUDIT_PARTS),))
+    mesh = AbstractMesh((AUDIT_PARTS,), ("parts",))
     fns, hspec, tables, tables_full = build_step_fns(cfg, spec, art, mesh,
                                                      slot_map=slot_map)
     inp = abstract_step_inputs(cfg, spec, art, fns, tables)

@@ -15,13 +15,15 @@ from __future__ import annotations
 from dataclasses import dataclass, field
 
 import jax
+import jax.extend.core as jex_core
 import numpy as np
 
 # Communication primitives whose ordered sequence IS the collective
-# schedule. `psum` lowers as `psum2` inside shard_map on this jax; both
-# spellings are kept so the extractor survives version drift.
+# schedule. Inside shard_map `lax.psum` traces as `psum_invariant` (the
+# varying -> invariant reduce of the vma type system); `pvary`, its
+# inverse cast, moves no data and is deliberately absent.
 COLLECTIVE_PRIMS = frozenset({
-    "psum", "psum2", "pmax", "pmin", "pmean",
+    "psum", "psum_invariant", "pmax", "pmin", "pmean",
     "all_to_all", "all_gather", "all_gather_invariant",
     "ppermute", "pshuffle", "ragged_all_to_all",
     "psum_scatter", "reduce_scatter", "pbroadcast",
@@ -97,9 +99,9 @@ def _subjaxprs(eqn):
 
 
 def _as_jaxprs(v):
-    if isinstance(v, jax.core.ClosedJaxpr):
+    if isinstance(v, jex_core.ClosedJaxpr):
         yield v.jaxpr
-    elif isinstance(v, jax.core.Jaxpr):
+    elif isinstance(v, jex_core.Jaxpr):
         yield v
     elif isinstance(v, (tuple, list)):
         for item in v:
@@ -134,7 +136,7 @@ def _collect(jaxpr, stack: tuple, tainted: set, out_coll: list,
     for eqn in jaxpr.eqns:
         prim = eqn.primitive.name
         eqn_tainted = any(v in tainted for v in eqn.invars
-                          if not isinstance(v, jax.core.Literal))
+                          if not isinstance(v, jex_core.Literal))
         if prim in COLLECTIVE_PRIMS and (eqn.invars or eqn.outvars):
             # operand-less eqns (pbroadcast replication annotations) move
             # nothing and are not part of the wire schedule — skipped
@@ -157,7 +159,7 @@ def _collect(jaxpr, stack: tuple, tainted: set, out_coll: list,
             # everything inside the branches then executes on a subset of
             # ranks; a tainted payload operand alone cannot steer control
             pred = eqn.invars[0]
-            if (not isinstance(pred, jax.core.Literal)) and pred in tainted:
+            if (not isinstance(pred, jex_core.Literal)) and pred in tainted:
                 branch_forces = True
 
         for sub in _subjaxprs(eqn):
@@ -171,7 +173,7 @@ def _collect(jaxpr, stack: tuple, tainted: set, out_coll: list,
                 outer_ins = outer_ins[1:]
             if len(outer_ins) == len(sub.invars):
                 for ov, iv in zip(outer_ins, sub.invars):
-                    if not isinstance(ov, jax.core.Literal) and ov in tainted:
+                    if not isinstance(ov, jex_core.Literal) and ov in tainted:
                         sub_taint.add(iv)
             _collect(sub, stack + (prim,), sub_taint, out_coll, out_xfer,
                      branch_forces)
@@ -195,11 +197,11 @@ def peak_live_bytes(closed_jaxpr) -> int:
     last_use: dict = {}
     for i, eqn in enumerate(jx.eqns):
         for v in eqn.invars:
-            if not isinstance(v, jax.core.Literal):
+            if not isinstance(v, jex_core.Literal):
                 last_use[v] = i
     n = len(jx.eqns)
     for v in jx.outvars:
-        if not isinstance(v, jax.core.Literal):
+        if not isinstance(v, jex_core.Literal):
             last_use[v] = n
     live = 0
     for v in list(jx.invars) + list(jx.constvars):
@@ -212,7 +214,7 @@ def peak_live_bytes(closed_jaxpr) -> int:
         seen = set()
         for v in list(eqn.invars) + list(eqn.outvars):
             # Literal is unhashable — skip before deduplicating
-            if isinstance(v, jax.core.Literal) or v in seen:
+            if isinstance(v, jex_core.Literal) or v in seen:
                 continue
             seen.add(v)
             if last_use.get(v, -1) <= i:

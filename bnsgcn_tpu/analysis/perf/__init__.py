@@ -137,8 +137,8 @@ def check_variants(calib: dict, tune_schedule=None, progress=None):
     accounting and the model's have diverged."""
     from bnsgcn_tpu.analysis.ir.variants import enumerate_variants
     try:
-        table = C.backend_table(calib, "tpu")
-    except KeyError:
+        table = C.backend_table(calib, "tpu-v5e")
+    except KeyError:                     # an injected calibration without it
         table = next(iter(calib["backends"].values()))
     variants = enumerate_variants(tune_schedule=tune_schedule)
     findings, rows, errors = [], [], []

@@ -6,7 +6,8 @@ Schema (`tools/perf_calibration.json`, written by
 
     {"perf_calibration": 1,
      "backends": {
-       "<name>": {"gather_rows_per_s": {"<row bytes>": rows/s, ...},
+       "<name>": {"device_kind": "<jax.devices()[0].device_kind>",
+                  "gather_rows_per_s": {"<row bytes>": rows/s, ...},
                   "gather_materialize_factor": f,   # materialize-path tax
                   "dense_tile_us": {"<tile edge>": us, ...},
                   "dense_xla_factor": f,            # XLA dense vs pallas
@@ -17,8 +18,8 @@ Schema (`tools/perf_calibration.json`, written by
      "records": [{"name", "backend", "measured_s",
                   "features": {StepFeatures fields}}, ...]}
 
-The bundled v5e table is transcribed from the round-1..4 hardware
-microbenches (BENCH_NOTES: 390/267/106 M rows/s at 256/512/1024 B rows,
+The bundled v5e table is transcribed from the 2026-07-29..31 v5e
+microbenches (390/267/106 M rows/s at 256/512/1024 B rows,
 ~4.3 us per 512x512 int8 tile at H=256, XLA dense path 1.961x pallas,
 materialize gather 1.088x the pure-rate slope) and the bundled records
 are the round-4 per-chip ladder — gate 4 re-derives the ladder from the
@@ -51,6 +52,7 @@ def default_calibration() -> dict:
     """The bundled tables + round-4 ladder records (single source of truth;
     tools/perf_calibration.json is this, serialized)."""
     v5e = {
+        "device_kind": "TPU v5 lite",
         "gather_rows_per_s": {"256": 390e6, "512": 267e6, "1024": 106e6},
         "gather_materialize_factor": 1.088,
         "dense_tile_us": {"512": 4.3},
@@ -64,6 +66,7 @@ def default_calibration() -> dict:
         "calibrated": True,
     }
     cpu = {
+        "device_kind": "cpu",
         "gather_rows_per_s": {"32": 60e6, "256": 40e6, "1024": 15e6},
         "gather_materialize_factor": 1.0,
         "dense_tile_us": {"512": 2000.0},
@@ -76,8 +79,9 @@ def default_calibration() -> dict:
     }
     # round-4 per-chip ladder (ogbn-products, P=4, H=256, rate 1.0,
     # use_pp: 3 graph layers x fwd+bwd = 6 SpMM applications/step).
-    # wire_mb 0: those epochs are compute-bound (BENCH_NOTES: the residual
-    # gather alone is ~75% of the 0.5715 s epoch) and the probe timed the
+    # wire_mb 0: those epochs are compute-bound (by the July 2026 microbench
+    # arithmetic the residual gather alone is ~75% of the 0.5715 s epoch)
+    # and the probe timed the
     # exchange separately — the wire term is exercised by the CPU e2e and
     # the monotonicity tests instead.
     base = {"n_apps": 6, "row_bytes": 512, "tile": 512, "wire_mb": 0.0}
@@ -181,16 +185,20 @@ def save_calibration(calib: dict, path: str) -> None:
     os.replace(tmp, path)
 
 
-def backend_table(calib: dict, backend: str) -> dict:
-    """Resolve a jax backend name to a calibration table: exact key first,
-    then 'tpu' -> the first tpu-* table (device generations share the
-    schema, not the constants)."""
+def backend_table(calib: dict, device_kind: str) -> dict:
+    """The calibration table for a device, keyed on what the device says it
+    is: `jax.devices()[0].device_kind` ('TPU v5 lite', 'cpu') against each
+    table's 'device_kind' field. A table's own name matches too, for tools
+    that name one (`perf_rank.py --backend tpu-v5e`). A kind no table
+    claims is a KeyError: constants measured on one TPU generation are
+    never handed to another."""
     backends = calib["backends"]
-    if backend in backends:
-        return backends[backend]
-    if backend == "tpu":
-        for name in sorted(backends):
-            if name.startswith("tpu"):
-                return backends[name]
-    raise KeyError(f"no calibration table for backend {backend!r} "
-                   f"(have {sorted(backends)})")
+    if device_kind in backends:
+        return backends[device_kind]
+    for table in backends.values():
+        if table.get("device_kind") == device_kind:
+            return table
+    raise KeyError(
+        f"no calibration table for device kind {device_kind!r} (have "
+        + ", ".join(f"{n} [{t.get('device_kind', '?')}]"
+                    for n, t in sorted(backends.items())) + ")")

@@ -1,7 +1,7 @@
 """graftperf cost model: predicted step/wire time from layout geometry.
 
 A calibrated roofline over the three terms every variant of the training
-step decomposes into (BENCH_NOTES round-4 'layout-derived cost model'):
+step decomposes into (the round-4 'layout-derived cost model'):
 
   step_s = fixed + calib_scale * (n_apps * (gather_s + dense_s) + wire_s)
 

@@ -3,9 +3,9 @@
 Two checks ride every full-surface graftlint run (core.lint_paths):
 
 * **tune-schedule-invalid** — every ``--tune-schedule`` string literal in
-  ``scripts/*.sh``, ``bench.py`` and ``.watch_queue`` is parsed with the
-  REAL ``tune.parse_schedule`` grammar at lint time. A typo'd schedule
-  otherwise survives until the queued run dies at startup, hours later.
+  ``scripts/*.sh`` and ``bench.py`` is parsed with the REAL
+  ``tune.parse_schedule`` grammar at lint time. A typo'd schedule otherwise
+  survives until the run it belongs to dies at startup.
 
 * **config-doc-drift** — the README "Config knobs" table (between the
   ``knob-table:begin/end`` markers) must be byte-identical to what
@@ -104,9 +104,8 @@ def check_tune_schedules(root: str) -> list:
     from bnsgcn_tpu.config import ConfigError
     from bnsgcn_tpu.tune import parse_schedule
     targets = sorted(glob.glob(os.path.join(root, "scripts", "*.sh")))
-    targets += [p for p in (os.path.join(root, "bench.py"),
-                            os.path.join(root, ".watch_queue"))
-                if os.path.exists(p)]
+    if os.path.exists(os.path.join(root, "bench.py")):
+        targets.append(os.path.join(root, "bench.py"))
     out = []
     for path in targets:
         rel = os.path.relpath(path, root)

@@ -17,9 +17,8 @@ from typing import Optional
 
 class ConfigError(ValueError):
     """A named configuration error: main.py prints it and exits 2 (the
-    deterministic-argument-error code the bench supervisor and requeue
-    wrappers never relaunch), instead of a stack trace from deep inside
-    mesh construction."""
+    deterministic-argument-error code a requeue wrapper never relaunches),
+    instead of a stack trace from deep inside mesh construction."""
 
 
 @dataclass(frozen=True)

@@ -4,10 +4,10 @@ Replaces `dgl.distributed.partition_graph` + METIS (reference
 helper/utils.py:94-95). Methods:
 
   * 'random'  — balanced random assignment (reference part_method='random').
-  * 'metis'   — locality-minimizing partition. Uses the native C++ partitioner
-    (bnsgcn_tpu/native, greedy linear-deterministic + boundary refinement,
-    vol/cut objectives) when the shared library is available, else a pure-
-    Python BFS region-growing fallback with the same interface.
+  * 'metis'   — locality-minimizing partition by the native C++ partitioner
+    (bnsgcn_tpu/native: multilevel coarsening + greedy linear-deterministic
+    + boundary refinement, vol/cut objectives). The library builds on first
+    use; a failed build is an error, never another partitioner.
 
 Both return `part_id: [N] int32` with every node assigned to exactly one part;
 partition *artifacts* (halo metadata etc.) are built by `artifacts.py`.
@@ -32,59 +32,6 @@ def random_partition(g: Graph, n_parts: int, seed: int = 0) -> np.ndarray:
     return part_id
 
 
-def _csr(g: Graph):
-    order = np.argsort(g.src, kind="stable")
-    dst_sorted = g.dst[order]
-    indptr = np.zeros(g.n_nodes + 1, dtype=np.int64)
-    np.add.at(indptr[1:], g.src, 1)
-    indptr = np.cumsum(indptr)
-    return indptr, dst_sorted
-
-
-def bfs_partition(g: Graph, n_parts: int, seed: int = 0) -> np.ndarray:
-    """Balanced BFS region growing: grow each part from a random seed until it
-    reaches N/P nodes, keeping parts locally connected (low edge cut). Python
-    fallback for the native partitioner."""
-    rng = np.random.default_rng(seed)
-    indptr, adj = _csr(g)
-    n = g.n_nodes
-    cap = -(-n // n_parts)           # ceil
-    part_id = np.full(n, -1, dtype=np.int32)
-    seen = np.zeros(n, dtype=bool)          # enqueued-or-assigned guard
-    sizes = np.zeros(n_parts, dtype=np.int64)
-    order = rng.permutation(n)
-    cursor = 0
-    from collections import deque
-    for p in range(n_parts):
-        # find an unassigned seed
-        while cursor < n and part_id[order[cursor]] != -1:
-            cursor += 1
-        if cursor >= n:
-            break
-        q = deque([order[cursor]])
-        seen[order[cursor]] = True
-        while q and sizes[p] < cap:
-            u = q.popleft()
-            if part_id[u] != -1:
-                continue
-            part_id[u] = p
-            sizes[p] += 1
-            for v in adj[indptr[u]:indptr[u + 1]]:
-                if not seen[v]:
-                    seen[v] = True
-                    q.append(int(v))
-        # nodes left in the queue stay available for the next region
-        for u in q:
-            if part_id[u] == -1:
-                seen[u] = False
-    # any leftovers -> smallest parts
-    for u in np.nonzero(part_id == -1)[0]:
-        p = int(np.argmin(sizes))
-        part_id[u] = p
-        sizes[p] += 1
-    return part_id
-
-
 def partition_graph(g: Graph, n_parts: int, method: str = "metis",
                     obj: str = "vol", seed: int = 0) -> np.ndarray:
     if n_parts == 1:
@@ -92,14 +39,8 @@ def partition_graph(g: Graph, n_parts: int, method: str = "metis",
     if method == "random":
         return random_partition(g, n_parts, seed)
     if method == "metis":
-        try:
-            from bnsgcn_tpu.native import native_partition
-            pid = native_partition(g, n_parts, obj, seed)
-            if pid is not None:
-                return pid
-        except ImportError:
-            pass
-        return bfs_partition(g, n_parts, seed)
+        from bnsgcn_tpu.native import native_partition
+        return native_partition(g, n_parts, obj, seed)
     raise ValueError(f"unknown partition method {method!r}")
 
 

@@ -2,9 +2,15 @@
 
 The reference evaluates on the whole undistributed graph on CPU in a
 background thread. Here the eval forward is the same `apply_model` in eval
-mode (norms recomputed from the eval graph's degrees, module/layer.py:39-45),
-jitted on whichever backend the caller picks; the trainer can run it in a
-host thread to overlap with training exactly like the reference.
+mode (norms recomputed from the eval graph's degrees, module/layer.py:39-45).
+The full-graph forwards here compute on the caller's default device unless
+given a `device`. run.py owns `--eval-device`: under `host` it passes
+`host_device()` (the CPU backend), so the eval runs in a host thread that
+overlaps training exactly like the reference and never on the accelerator the
+mesh trains on — the full graph does not fit beside a part's blocks there.
+`--eval-device mesh` (`evaluate_mesh`) is the on-accelerator,
+partition-parallel alternative. Serving and continual training call the same
+forwards with no device: their tables are computed where they run.
 """
 
 from __future__ import annotations
@@ -16,6 +22,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from bnsgcn_tpu.config import ConfigError
 from bnsgcn_tpu.data.graph import Graph
 from bnsgcn_tpu.models.gnn import GraphEnv, ModelSpec, apply_model
 from bnsgcn_tpu.utils.metrics import calc_acc
@@ -23,6 +30,19 @@ from bnsgcn_tpu.utils.metrics import calc_acc
 
 def _identity_exchange(i, h):
     return h, None
+
+
+def host_device():
+    """The device `--eval-device host` computes on: the CPU backend's first
+    device, whatever the default backend is."""
+    try:
+        return jax.devices("cpu")[0]
+    except RuntimeError as ex:
+        raise ConfigError(
+            "--eval-device host runs the full-graph eval on the CPU backend, "
+            f"which this process did not initialize (JAX_PLATFORMS="
+            f"{jax.config.jax_platforms!r}): add cpu to JAX_PLATFORMS, or "
+            f"use --eval-device mesh") from ex
 
 
 def build_eval_env(g: Graph, spec: ModelSpec, edge_chunk: int = 0) -> GraphEnv:
@@ -49,33 +69,38 @@ def build_eval_env(g: Graph, spec: ModelSpec, edge_chunk: int = 0) -> GraphEnv:
 
 
 def full_graph_logits(params, state, spec: ModelSpec, g: Graph,
-                      edge_chunk: int = 0) -> np.ndarray:
-    env = build_eval_env(g, spec, edge_chunk)
-    feat = jnp.asarray(g.feat)
-    logits, _ = apply_model(params, state, spec, feat, env)
+                      edge_chunk: int = 0, device=None) -> np.ndarray:
+    """Eval-mode logits of every node of `g`, computed on `device` (None =
+    the caller's default device)."""
+    with jax.default_device(device):
+        env = build_eval_env(g, spec, edge_chunk)
+        feat = jnp.asarray(g.feat)
+        logits, _ = apply_model(params, state, spec, feat, env)
     return np.asarray(jax.device_get(logits))
 
 
 def full_graph_embeddings(params, state, spec: ModelSpec, g: Graph,
-                          edge_chunk: int = 0) -> tuple[np.ndarray, np.ndarray]:
+                          edge_chunk: int = 0,
+                          device=None) -> tuple[np.ndarray, np.ndarray]:
     """(hidden [N, H], logits [N, C]): the all-node embedding table the
     serving subsystem (serve.py) and `--dump-embeddings` precompute — the
     penultimate activations (final layer's input) plus the final-layer
     scores, through the SAME eval forward as `full_graph_logits`, so served
     tier-A scores are bitwise the full-eval logits."""
-    env = build_eval_env(g, spec, edge_chunk)
-    feat = jnp.asarray(g.feat)
-    logits, _, hidden = apply_model(params, state, spec, feat, env,
-                                    return_hidden=True)
+    with jax.default_device(device):
+        env = build_eval_env(g, spec, edge_chunk)
+        feat = jnp.asarray(g.feat)
+        logits, _, hidden = apply_model(params, state, spec, feat, env,
+                                        return_hidden=True)
     return (np.asarray(jax.device_get(hidden)),
             np.asarray(jax.device_get(logits)))
 
 
 def evaluate_trans(name: str, params, state, spec: ModelSpec, g: Graph,
                    result_file: Optional[str] = None,
-                   edge_chunk: int = 0) -> tuple[float, float]:
+                   edge_chunk: int = 0, device=None) -> tuple[float, float]:
     """Transductive: val+test in one pass (reference train.py:44-61)."""
-    logits = full_graph_logits(params, state, spec, g, edge_chunk)
+    logits = full_graph_logits(params, state, spec, g, edge_chunk, device)
     val_acc = calc_acc(logits[g.val_mask], np.asarray(g.label)[g.val_mask])
     test_acc = calc_acc(logits[g.test_mask], np.asarray(g.label)[g.test_mask])
     buf = "{:s} | Validation Accuracy {:.2%} | Test Accuracy {:.2%}".format(name, val_acc, test_acc)
@@ -85,10 +110,10 @@ def evaluate_trans(name: str, params, state, spec: ModelSpec, g: Graph,
 
 def evaluate_induc(name: str, params, state, spec: ModelSpec, g: Graph,
                    mode: str, result_file: Optional[str] = None,
-                   edge_chunk: int = 0) -> float:
+                   edge_chunk: int = 0, device=None) -> float:
     """Inductive: evaluate `mode` ('val'|'test') mask on subgraph g
     (reference train.py:22-41)."""
-    logits = full_graph_logits(params, state, spec, g, edge_chunk)
+    logits = full_graph_logits(params, state, spec, g, edge_chunk, device)
     mask = g.val_mask if mode == "val" else g.test_mask
     acc = calc_acc(logits[mask], np.asarray(g.label)[mask])
     buf = "{:s} | Accuracy {:.2%}".format(name, acc)

@@ -25,10 +25,12 @@ from bnsgcn_tpu import resilience
 from bnsgcn_tpu.config import Config, ConfigError, parse_config
 from bnsgcn_tpu.parallel import coord
 from bnsgcn_tpu.run import prepare_partition, run_training
+from bnsgcn_tpu.utils.platform import place_compile_cache
 
 
 def main(argv=None):
     argv = sys.argv[1:] if argv is None else list(argv)
+    place_compile_cache()
     if argv and argv[0] == "serve":
         # online inference serving rides the same flag vocabulary but a
         # different lifecycle (long-running server, drain-on-SIGTERM) —
@@ -107,8 +109,7 @@ def main(argv=None):
     except ConfigError as ex:
         # a named configuration error (e.g. replicas x parts x feat exceeds
         # the device budget): deterministic argument problem — exit 2 like
-        # argparse, so requeue wrappers and the bench supervisor never
-        # relaunch it
+        # argparse, so a requeue wrapper never relaunches it
         print(f"[config] {ex}", file=sys.stderr)
         sys.exit(2)
     except resilience.RankLostExit as ex:

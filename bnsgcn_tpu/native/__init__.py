@@ -1,14 +1,18 @@
 """ctypes bindings for the native C++ partitioner (build-on-demand).
 
-The shared library is compiled from partitioner.cpp on first use (make, then
-a direct g++ fallback) and cached next to the source. If no C++ toolchain is
-available, `native_partition` returns None and callers fall back to the
-pure-Python partitioner (data/partitioner.py).
+The shared library is compiled from partitioner.cpp on first use by the
+Makefile beside it. The file name carries a hash of the source and the
+build flags, so a library left over from other source or flags is never
+loaded, and the flags hold no -march=native, so one built on this host
+loads on any other. A failed build raises: `--partition-method metis` and
+the hybrid layout's cluster order have no second implementation to fall
+back on silently.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -17,37 +21,47 @@ from typing import Optional
 import numpy as np
 
 _DIR = os.path.dirname(os.path.abspath(__file__))
-_SO = os.path.join(_DIR, "libbnspartition.so")
 _lock = threading.Lock()
 _lib = None
-_build_failed = False
 
 
-def _build() -> bool:
-    src = os.path.join(_DIR, "partitioner.cpp")
-    if os.path.exists(_SO) and os.path.getmtime(_SO) >= os.path.getmtime(src):
-        return True
-    for cmd in (["make", "-C", _DIR],
-                ["g++", "-O3", "-fPIC", "-shared", "-std=c++17",
-                 "-o", _SO, src]):
-        try:
-            r = subprocess.run(cmd, capture_output=True, timeout=120)
-            if r.returncode == 0 and os.path.exists(_SO):
-                return True
-        except (OSError, subprocess.TimeoutExpired):
-            continue
-    return False
+def _so_path() -> str:
+    h = hashlib.sha1()
+    for name in ("partitioner.cpp", "Makefile"):
+        with open(os.path.join(_DIR, name), "rb") as f:
+            h.update(f.read())
+    for var in ("CXX", "CXXFLAGS"):          # the Makefile's `?=` inputs
+        h.update(os.environ.get(var, "").encode())
+    return os.path.join(_DIR, f"libbnspartition-{h.hexdigest()[:12]}.so")
+
+
+def _build() -> str:
+    so = _so_path()
+    if os.path.exists(so):
+        return so
+    # build under a per-process name, publish by rename: concurrent ranks
+    # never load a half-written library
+    tmp = f"{so}.{os.getpid()}.tmp"
+    try:
+        r = subprocess.run(["make", "-C", _DIR, f"OUT={tmp}"],
+                           capture_output=True, text=True, timeout=300)
+        if r.returncode != 0 or not os.path.exists(tmp):
+            raise RuntimeError(
+                f"building the native partitioner failed (make exit "
+                f"{r.returncode}):\n{r.stdout}{r.stderr}")
+        os.replace(tmp, so)
+    finally:
+        if os.path.exists(tmp):
+            os.remove(tmp)
+    return so
 
 
 def _load():
-    global _lib, _build_failed
+    global _lib
     with _lock:
-        if _lib is not None or _build_failed:
+        if _lib is not None:
             return _lib
-        if not _build():
-            _build_failed = True
-            return None
-        lib = ctypes.CDLL(_SO)
+        lib = ctypes.CDLL(_build())
         lib.bns_partition_v2.restype = ctypes.c_int
         lib.bns_partition_v2.argtypes = [
             ctypes.c_int64, ctypes.c_int64,
@@ -57,20 +71,15 @@ def _load():
             ctypes.c_int32, ctypes.c_int32,
             np.ctypeslib.ndpointer(np.int32, flags="C_CONTIGUOUS"),
         ]
-        try:
-            lib.bns_partition_v2_i32.restype = ctypes.c_int
-            lib.bns_partition_v2_i32.argtypes = [
-                ctypes.c_int64, ctypes.c_int64,
-                np.ctypeslib.ndpointer(np.int32, flags="C_CONTIGUOUS"),
-                np.ctypeslib.ndpointer(np.int32, flags="C_CONTIGUOUS"),
-                ctypes.c_int32, ctypes.c_int32, ctypes.c_uint64,
-                ctypes.c_int32, ctypes.c_int32, ctypes.c_int32,
-                np.ctypeslib.ndpointer(np.int32, flags="C_CONTIGUOUS"),
-            ]
-        except AttributeError:
-            # a stale cached .so predating the int32 entry: the int64 path
-            # (with its copy) still works
-            pass
+        lib.bns_partition_v2_i32.restype = ctypes.c_int
+        lib.bns_partition_v2_i32.argtypes = [
+            ctypes.c_int64, ctypes.c_int64,
+            np.ctypeslib.ndpointer(np.int32, flags="C_CONTIGUOUS"),
+            np.ctypeslib.ndpointer(np.int32, flags="C_CONTIGUOUS"),
+            ctypes.c_int32, ctypes.c_int32, ctypes.c_uint64,
+            ctypes.c_int32, ctypes.c_int32, ctypes.c_int32,
+            np.ctypeslib.ndpointer(np.int32, flags="C_CONTIGUOUS"),
+        ]
         lib.bns_edge_cut.restype = ctypes.c_int64
         lib.bns_edge_cut.argtypes = [
             ctypes.c_int64,
@@ -90,26 +99,20 @@ def _load():
         return _lib
 
 
-def native_available() -> bool:
-    return _load() is not None
-
-
 def native_partition(g, n_parts: int, obj: str = "vol", seed: int = 0,
                      refine_passes: int = 8, n_seeds: int = 3,
-                     multilevel: bool = True) -> Optional[np.ndarray]:
+                     multilevel: bool = True) -> np.ndarray:
     """Graph partition, best of `n_seeds` runs by the true objective
-    (directed comm volume for 'vol', edge cut for 'cut'); None if lib
-    unavailable. multilevel=True (default) runs HEM coarsening + weighted
-    LDG/FM + projection with per-level refinement — measurably better on
-    clustered graphs (the METIS-like pipeline); False keeps the flat
-    LDG+FM streaming pipeline (round-2 behavior)."""
+    (directed comm volume for 'vol', edge cut for 'cut'). multilevel=True
+    (default) runs HEM coarsening + weighted LDG/FM + projection with
+    per-level refinement — measurably better on clustered graphs (the
+    METIS-like pipeline); False keeps the flat LDG+FM streaming pipeline
+    (round-2 behavior)."""
     lib = _load()
-    if lib is None:
-        return None
     out = np.empty(g.n_nodes, dtype=np.int32)
     # int32 edge lists go through the zero-copy entry: the ascontiguousarray
     # int64 promotion was ~25.6 GB of transient at the 1.6B-edge scale
-    if g.src.dtype == np.int32 and hasattr(lib, "bns_partition_v2_i32"):
+    if g.src.dtype == np.int32:
         src = np.ascontiguousarray(g.src, dtype=np.int32)
         dst = np.ascontiguousarray(g.dst, dtype=np.int32)
         entry = lib.bns_partition_v2_i32
@@ -123,20 +126,20 @@ def native_partition(g, n_parts: int, obj: str = "vol", seed: int = 0,
         np.uint64(seed), np.int32(refine_passes),
         np.int32(n_seeds), np.int32(1 if multilevel else 0), out)
     if rc != 0:
-        return None
+        raise ValueError(
+            f"native partitioner rejected its input (code {rc}): "
+            f"{g.n_nodes} nodes into {n_parts} parts")
     return out
 
 
 def native_comm_volume(g, part_id: np.ndarray,
                        n_parts: int) -> Optional[int]:
-    """Directed communication volume via the C++ metric (None if lib absent)."""
+    """Directed communication volume via the C++ metric (None when the node
+    count exceeds the library's int32 ids)."""
     lib = _load()
-    if lib is None:
-        return None
     src = np.ascontiguousarray(g.src, dtype=np.int64)
     dst = np.ascontiguousarray(g.dst, dtype=np.int64)
     part = np.ascontiguousarray(part_id, dtype=np.int32)
     vol = int(lib.bns_comm_volume(g.n_nodes, src.shape[0], src, dst,
                                   np.int32(n_parts), part))
-    return None if vol < 0 else vol   # <0 = int32-id range exceeded;
-                                      # callers fall back to the Python metric
+    return None if vol < 0 else vol

@@ -673,8 +673,7 @@ int partition_v2_impl(int64_t n_nodes, int64_t n_edges, const T* src,
                       int32_t multilevel, int32_t* out_part) {
   if (n_parts <= 0 || n_nodes <= 0) return 1;
   if (n_nodes > INT32_MAX) return 3;   // adj stores int32 node ids; the
-                                       // Python binding falls back to the
-                                       // pure-Python partitioner on any
+                                       // Python binding raises on any
                                        // nonzero rc
   if (n_parts == 1) {
     std::memset(out_part, 0, sizeof(int32_t) * n_nodes);

@@ -199,7 +199,7 @@ def _build_tiles(perm_rows, perm_cols, n_rows, n_src, rows, cols,
     # chunked np.add.at histogram + full-stack >127 scan + int32->int8
     # cast — each a pass over B*tile_r*tile_c elements — with one O(E log E)
     # sort plus O(E) writes (2.1x on the scale-0.1 dcsbm build where edges
-    # fill ~2% of the selected tiles' cells; BENCH_NOTES has the runs).
+    # fill ~2% of the selected tiles' cells; CPU-container measurement, PR 2).
     area = tile_r * tile_c
     tiles8 = np.zeros((B, tile_r, tile_c), dtype=np.int8)
     cell = (e_rank[m] * area + (pr[m] % tile_r) * tile_c
@@ -528,7 +528,9 @@ def dense_edge_count(arrays, part: int = 0) -> int:
     for key in ("blk_tiles_fwd", "int_blk_tiles_fwd", "fro_blk_tiles_fwd"):
         tiles = arrays.get(key)
         if tiles is not None:
-            total += int(np.asarray(tiles[part]).astype(np.int64).sum())
+            # sum(dtype=) accumulates in int64 without an 8x copy of the
+            # (up to multi-GB) int8 stack
+            total += int(np.asarray(tiles[part]).sum(dtype=np.int64))
     return total
 
 
@@ -648,6 +650,30 @@ def _dense_apply(spec: BlockSpec, tiles, rowb, colb, perm_src, perm_out, h,
     return flat[perm_out]                                  # original row order
 
 
+# int8 Pallas accumulator bound: the fused kernel keeps exact int32 row sums
+# of |q|<=127 x |mult|<=127 products, so a row with more than
+# int32_max/(127*127) ~= 133k dense edges could silently wrap. The max
+# per-row dense edge count is static in the layout (max_row_dense; getattr
+# for layouts cached before the field existed -> 0 = unknown, guard
+# skipped). Overflow-risk rows route to the XLA path, whose int8
+# formulation rescales to f32 per chunk (no wrap possible).
+_I8_ROW_CAP = (2**31 - 1) // (127 * 127)
+
+
+def dense_path(spec_d: BlockSpec, use_pallas: bool, dense_dtype: str) -> str:
+    """Which implementation runs one direction's dense tiles: 'pallas' (the
+    fused Mosaic kernel — `use_pallas` on a TPU backend) or 'xla'
+    (_dense_apply: any other backend, use_pallas off, or an int8 layout
+    past the accumulator bound). The ONE place the choice is made: the
+    compiled step and the run header (trainer.StepFns.spmm_desc) both read
+    it, so a log always shows what ran."""
+    if (use_pallas and jax.default_backend() == "tpu"
+            and (dense_dtype != "int8"
+                 or getattr(spec_d, "max_row_dense", 0) <= _I8_ROW_CAP)):
+        return "pallas"
+    return "xla"
+
+
 def make_block_spmm(fwd: BlockSpec, bwd: BlockSpec, ell_pair,
                     use_pallas: bool = False, gather_dtype: str = "native",
                     dense_dtype: str = "native", accum: str = "auto"):
@@ -672,23 +698,9 @@ def make_block_spmm(fwd: BlockSpec, bwd: BlockSpec, ell_pair,
         return {k[len("res_"):]: v for k, v in arrays.items()
                 if k.startswith("res_")}
 
-    # int8 Pallas accumulator bound: the fused kernel keeps exact int32 row
-    # sums of |q|<=127 x |mult|<=127 products, so a row with more than
-    # int32_max/(127*127) ~= 133k dense edges could silently wrap. The max
-    # per-row dense edge count is static in the layout (max_row_dense;
-    # getattr for layouts cached before the field existed -> 0 = unknown,
-    # guard skipped). Overflow-risk rows route to the XLA path, whose int8
-    # formulation rescales to f32 per chunk (no wrap possible).
-    _I8_ROW_CAP = (2**31 - 1) // (127 * 127)
-
-    def _i8_pallas_safe(spec_d):
-        return getattr(spec_d, "max_row_dense", 0) <= _I8_ROW_CAP
-
     def _dense(spec_d, arrays, tiles_key, rowb_key, colb_key, perm_src_key,
                perm_out_key, h):
-        # Pallas fused grouped-matmul on TPU (use_pallas); XLA path elsewhere
-        if (use_pallas and jax.default_backend() == "tpu"
-                and (dense_dtype != "int8" or _i8_pallas_safe(spec_d))):
+        if dense_path(spec_d, use_pallas, dense_dtype) == "pallas":
             from bnsgcn_tpu.ops.pallas_block import dense_apply_pallas
             return dense_apply_pallas(
                 spec_d, arrays[tiles_key], arrays[rowb_key], arrays[colb_key],
@@ -746,22 +758,20 @@ def cluster_order(src, dst, n_rows, n_ext, target=TC
     dst = np.asarray(dst)
     inner = (src < n_rows) & (dst < n_rows)
     if n_clusters > 1 and inner.any():
-        try:
-            from bnsgcn_tpu.native import native_partition
+        from bnsgcn_tpu.native import native_partition
 
-            class _G:                       # minimal adapter for the binding
-                pass
+        class _G:                           # minimal adapter for the binding
+            pass
 
-            gg = _G()
-            gg.src = src[inner].astype(np.int64)
-            gg.dst = dst[inner].astype(np.int64)
-            gg.n_nodes = n_rows
-            cid = native_partition(gg, n_clusters, obj="cut",
-                                   seed=0, refine_passes=2, n_seeds=1)
-            if cid is not None:
-                order = np.argsort(cid, kind="stable")
-        except Exception:
-            order = None
+        gg = _G()
+        gg.src = src[inner].astype(np.int64)
+        gg.dst = dst[inner].astype(np.int64)
+        gg.n_nodes = n_rows
+        # a failed native build raises here: an unclustered order would
+        # silently build a layout with other tile coverage
+        cid = native_partition(gg, n_clusters, obj="cut",
+                               seed=0, refine_passes=2, n_seeds=1)
+        order = np.argsort(cid, kind="stable")
     if order is None:
         order = np.arange(n_rows)
     perm_inner = np.empty(n_rows, dtype=np.int64)

@@ -488,8 +488,8 @@ class FileTransport:
     decision at the same seq). So every run gets a fresh namespace: rank 0
     purges the directory and publishes a run token in `.boot`; peers adopt
     the token before their first exchange and every key is prefixed with
-    it. A peer that races ahead of a RELAUNCHING rank 0 (a tpu_watchdog5
-    requeue — the previous run's `.boot` AND its keys, under the same
+    it. A peer that races ahead of a RELAUNCHING rank 0 (a requeue
+    wrapper's relaunch — the previous run's `.boot` AND its keys, under the same
     deterministic names, are still on disk) must not adopt the dead run's
     namespace: the token embeds the minting host+pid, and a peer rejects a
     same-host token whose process is gone, polling until the new rank 0

@@ -55,8 +55,8 @@ class HaloSpec:
       * 'ragged' — ONE `lax.ragged_all_to_all` carrying each (sender, peer)
         pair's exact send_size[p, j] rows: shift's exact bytes without its
         P-1 serialized hops. Offsets/sizes are trace-time constants
-        (`pair_send`). Native on TPU backends that ship the collective;
-        elsewhere (XLA:CPU, old jax) a numerically identical emulation
+        (`pair_send`). Native on TPU; on XLA:CPU, where the collective
+        does not lower, a numerically identical emulation
         routes the same rows over the padded all_to_all through the same
         pack/unpack geometry, so the strategy is CPU-mesh-testable.
     `wire` picks the payload dtype on the interconnect:
@@ -153,7 +153,7 @@ def make_halo_spec(n_b: np.ndarray, pad_inner: int, pad_boundary: int,
 def _ragged_exact_rows(pair_send, n_parts: int) -> int:
     """Bottleneck device's exact off-diagonal send rows — what the ragged
     collective puts on the wire (matches the hw-probe accounting,
-    hw_logs/hw_session_r4.log:399: `send.sum(1).max()` with a zero diagonal)."""
+    2026-07-30 v5e probe: `send.sum(1).max()` with a zero diagonal)."""
     S = np.asarray(pair_send, dtype=np.int64).reshape(n_parts, n_parts).copy()
     np.fill_diagonal(S, 0)
     return int(S.sum(axis=1).max()) if S.size else 0
@@ -164,7 +164,7 @@ def wire_bytes(spec: HaloSpec, width: int, native_bytes: int = 4) -> int:
     width (excluding the [P] f32 scales, which are negligible). The backward
     exchange costs the same.
 
-    Accounting matches the hardware probe (hw_logs/hw_session_r4.log:399):
+    Accounting matches the 2026-07-30 v5e hardware probe:
     'padded' counts the full P-block tiled all_to_all buffer (the self block
     rides the same payload even though its hop is chip-local); 'shift' counts
     its per-diagonal pads; 'ragged' counts the bottleneck device's exact
@@ -187,7 +187,7 @@ def traced_wire_bytes(spec: HaloSpec, width: int, native_bytes: int = 4,
     ARE the accounting). 'ragged' differs by construction: the native
     collective ships the lane-aligned [T_pad, d] operand (the bottleneck
     device's exact rows INCLUDING the self chunk, rounded up to 8), while
-    the emulated path (XLA:CPU / old jax, `ragged_native_ok()` False)
+    the emulated path (XLA:CPU, `ragged_native_ok()` False)
     routes the same rows over the padded all_to_all — padded accounting,
     the documented emulation slack `wire_bytes()` deliberately ignores.
     The [P] f32 scale hop of the quantized wires is excluded on both sides
@@ -232,12 +232,9 @@ SHIFT_MIN_SAVING = 0.25
 
 
 def ragged_native_ok() -> bool:
-    """True when `lax.ragged_all_to_all` will lower natively here: the op
-    exists in this jax AND the backend is TPU (UNIMPLEMENTED on XLA:CPU —
-    hw_logs/hw_session_r4.log probe note). BNSGCN_RAGGED_EMULATE=1 forces
-    the emulation path for debugging."""
-    if not hasattr(jax.lax, "ragged_all_to_all"):
-        return False
+    """True when `lax.ragged_all_to_all` will lower natively here: the
+    backend is TPU (the op is UNIMPLEMENTED on XLA:CPU).
+    BNSGCN_RAGGED_EMULATE=1 forces the emulation path for debugging."""
     if os.environ.get("BNSGCN_RAGGED_EMULATE"):
         return False
     return jax.default_backend() == "tpu"
@@ -664,8 +661,8 @@ def _ragged_a2a(spec: HaloSpec, sizes: tuple, payload: jax.Array) -> jax.Array:
     blocks -> [P, S, d] per-sender recv blocks (rows >= sizes[q][me] zero).
 
     Native path: pack to the ragged operand and issue ONE
-    `lax.ragged_all_to_all` (v5e-validated, hw_logs/hw_session_r4.log).
-    Emulated path (XLA:CPU / old jax): the same pack/unpack geometry wrapped
+    `lax.ragged_all_to_all` (lowered on a v5e at axis size 1, 2026-07-30).
+    Emulated path (XLA:CPU): the same pack/unpack geometry wrapped
     around a padded all_to_all — identical numerics, so the CPU mesh tests
     exercise the real offset math even where the op cannot lower."""
     P, S, d = payload.shape

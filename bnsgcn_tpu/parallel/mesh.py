@@ -14,18 +14,6 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
-def shard_map(f, *, mesh, in_specs, out_specs):
-    """Version-portable `shard_map`: top-level `jax.shard_map` where it
-    exists (jax >= 0.5), else the `jax.experimental.shard_map` original
-    (0.4.x — the CPU-mesh test container). Call sites only ever pass
-    (mesh, in_specs, out_specs), which both signatures accept."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
-
-
 def make_parts_mesh(n_parts: int, devices=None) -> Mesh:
     """1-D mesh with one mesh slot per partition.
 

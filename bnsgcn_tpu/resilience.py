@@ -5,8 +5,8 @@ preemption and divergence — not throughput — bound a run. Before this module
 the epoch loop had zero failure handling: a NaN loss trained to garbage
 silently, a SIGTERM (TPU maintenance / spot preemption) lost everything since
 the last periodic checkpoint, a torn `.ckpt` crashed `--resume`, and a hung
-collective was only caught by the `tools/tpu_watchdog*.sh` scripts polling
-from OUTSIDE the process. This module brings all four recoveries in-process:
+collective was only caught by shell scripts polling from OUTSIDE the
+process. This module brings all four recoveries in-process:
 
 * **Divergence guard + rollback** — `run_training` checks the already-host-
   fetched loss every step (free: the loop fetched it for `res.losses` anyway)
@@ -76,8 +76,7 @@ from bnsgcn_tpu import obs as obs_mod
 from bnsgcn_tpu.config import ConfigError
 from bnsgcn_tpu.parallel.coord import CoordAbort
 
-# Distinct exit codes so a requeue wrapper (the tools/tpu_watchdog5.sh role,
-# now consolidated in-process) can tell retryable states apart:
+# Distinct exit codes so a requeue wrapper can tell retryable states apart:
 EXIT_PREEMPTED = 75   # EX_TEMPFAIL: resumable checkpoint written; relaunch
                       # with --resume continues bit-for-bit
 EXIT_DIVERGED = 76    # rollback retries exhausted; diagnostic report printed
@@ -482,7 +481,7 @@ class _Watchdog(threading.Thread):
             dump_path = ""
             if self.postmortem_dir:
                 # exit 77 must leave a post-mortem FILE a requeue wrapper
-                # can point triage at after the tunnel window closes —
+                # can point triage at after the machine is gone —
                 # stderr alone dies with the terminal scrollback. "" =
                 # write failed (disk full): no breadcrumb to a ghost file
                 dump_path = obs_mod.write_postmortem(

@@ -332,16 +332,20 @@ def run_training(cfg: Config, g: Optional[Graph] = None,
             # --tune-prior model: the graftperf roofline (analysis/perf)
             # predicts the comm fraction from the partition geometry +
             # calibration tables and picks the launch rung, skipping
-            # ladder rungs whose wire saving it prices as immaterial. A
-            # prediction failure must never kill a run: fall back to the
-            # default coarse start and say so.
+            # ladder rungs whose wire saving it prices as immaterial. The
+            # table is the one calibrated for THIS device kind; a device
+            # nobody calibrated has no prior — say so and start the ladder
+            # at its default rung.
+            from bnsgcn_tpu.analysis.perf import calibration as _pcal
+            from bnsgcn_tpu.analysis.perf import model as _pmod
             try:
-                import jax as _jax
-
-                from bnsgcn_tpu.analysis.perf import calibration as _pcal
-                from bnsgcn_tpu.analysis.perf import model as _pmod
                 _table = _pcal.backend_table(_pcal.load_calibration(),
-                                             _jax.default_backend())
+                                             jax.devices()[0].device_kind)
+            except KeyError as ex:
+                _table = None
+                log(f"[tune] model prior unavailable ({ex.args[0]}); "
+                    f"using ladder start")
+            if _table is not None:
                 _strat = (cfg.halo_exchange if cfg.halo_exchange in
                           ("padded", "shift", "ragged") else "padded")
                 _feat = _pmod.run_features(cfg, art, strategy=_strat)
@@ -351,9 +355,6 @@ def run_training(cfg: Config, g: Optional[Graph] = None,
                     f"{_prior['step_s'] * 1e3:.1f} ms, wire "
                     f"{_prior['wire_s'] * 1e3:.2f} ms "
                     f"(comm {_prior['comm_frac']:.1%})")
-            except Exception as ex:
-                log(f"[tune] model prior unavailable "
-                    f"({type(ex).__name__}: {ex}); using ladder start")
         _ch0, _why0 = tune_mod.startup_changes(cfg, prior=_prior)
         if _ch0:
             cfg = cfg.replace(**_ch0)
@@ -440,7 +441,7 @@ def run_training(cfg: Config, g: Optional[Graph] = None,
             blk["feat0_ext"] = out
         else:
             blk["feat"] = out
-    from bnsgcn_tpu.parallel.halo import wire_bytes
+    from bnsgcn_tpu.parallel.halo import ragged_native_ok, wire_bytes
     nb = 2 if cfg.dtype == "bfloat16" else 4
     # Comm column context: the halo label is the RESOLVED strategy (under
     # --halo-exchange auto the pick was logged by build_step_fns; 'auto->'
@@ -493,6 +494,16 @@ def run_training(cfg: Config, g: Optional[Graph] = None,
         + ("" if spec.use_pp or spec.model == "gat" else
            f" ({wire_bytes(hspec, _wire_w(max(cfg.n_feat, 1)), nb) / 1e6:.2f}"
            f" MB at layer-0 feature width {cfg.n_feat})"))
+
+    # what the step was BUILT with, as opposed to what the flags asked for:
+    # the dense-tile path that really runs (--use-pallas is the XLA twin
+    # off-TPU) and whether 'ragged' is the native collective or its
+    # all_to_all emulation — so a log always shows what ran
+    log(f"Step: spmm {fns.spmm_desc} | halo exchange {hspec.strategy}"
+        + ("" if hspec.strategy != "ragged" else
+           " (native ragged_all_to_all)" if ragged_native_ok() else
+           " (emulated over the padded all_to_all: no native lowering on "
+           f"{jax.default_backend()})"))
 
     # one machine-readable run header: everything the per-run log line above
     # says, plus the config the run is actually executing — the record
@@ -571,6 +582,15 @@ def run_training(cfg: Config, g: Optional[Graph] = None,
 
     # ---- mesh-distributed eval resources (--eval-device mesh) ----
     mesh_eval = cfg.eval and cfg.eval_device == "mesh"
+    host_dev = None
+    if (is_rank0 and not mesh_eval
+            and (cfg.eval or cfg.dump_embeddings)):
+        # --eval-device host: this run's full-graph forwards (eval, and the
+        # --dump-embeddings table) compute on the CPU backend, never on a
+        # training chip. Resolved now so a process without one fails before
+        # epoch 0.
+        from bnsgcn_tpu.evaluate import host_device
+        host_dev = host_device()
     eval_val = None                    # (fns, blk, tables_full_d, art)
 
     def _eval_resources(graph, name_suffix):
@@ -582,7 +602,7 @@ def run_training(cfg: Config, g: Optional[Graph] = None,
             if multi_host:
                 b["feat"] = place_blocks_local(raw, mesh)["feat"]
             else:
-                b["feat"] = jax.device_put(jnp.asarray(raw["feat"]),
+                b["feat"] = jax.device_put(raw["feat"],
                                            blk["inner_mask"].sharding)
             return fns, b, tables_full_d, art
         base = cfg.graph_name or cfg.derive_graph_name()
@@ -1009,9 +1029,10 @@ def run_training(cfg: Config, g: Optional[Graph] = None,
     prof_stop = min(prof_start + 3, cfg.n_epochs - 1)
     tracing = False
     # The Comm(s) microbench overstates the real in-step collective cost by
-    # 1.5-26x (hardware cross-check, hw_logs/trace_comm_table.log: host
-    # dispatch dominates for small quantized payloads — the int8 wire's
-    # microbench reads 26x its traced in-step exchange). The reference's
+    # 1.5-26x (2026-07-30 cross-check on an 8-device host-platform mesh,
+    # hw_logs/trace_comm_table.log: host dispatch dominates for small
+    # quantized payloads — the int8 wire's microbench reads 26x its traced
+    # in-step exchange). The reference's
     # column is a direct in-step measurement (helper/timer/comm_timer.py:
     # 21-25), so ours must be too: trace a short window (the user's
     # --profile-dir if given, else an auto temp dir on rank 0) and derive
@@ -1032,23 +1053,11 @@ def run_training(cfg: Config, g: Optional[Graph] = None,
         trace_dir = auto_trace_dir
     comm_traced = reduce_traced = None
 
-    def _eval_job(e, thunk):
-        """Async host eval wrapper: a raise inside the thread must NOT kill
-        training a full log_every later when .result() re-raises — label the
-        failure with the epoch it belongs to and let the consumer log it and
-        keep training (best-acc tracking just skips that sample)."""
-        try:
-            return e, thunk(), None
-        except Exception as ex:     # noqa: BLE001 — every eval failure is soft
-            return e, None, ex
-
     def _drain_eval(fut):
-        """(params, acc) from a finished eval future, or None on failure."""
-        e, out, err = fut.result()
-        if err is not None:
-            log(f"[resilience] host eval for epoch {e} failed "
-                f"({type(err).__name__}: {err}); continuing training")
-            return None
+        """(params, acc) from a finished host-eval future. A raise inside
+        the eval thread re-raises here and fails the run: a run whose eval
+        phase failed must not go on to print a result."""
+        e, out = fut.result()
         if obs is not None:
             obs.emit("eval", epoch=e, val_acc=round(float(out[1]), 6))
         return out
@@ -1531,39 +1540,40 @@ def run_training(cfg: Config, g: Optional[Graph] = None,
                 if cfg.profile_dir:
                     log(f"profiler trace written to {cfg.profile_dir}")
                 # load the trace ONCE; both the Comm/Reduce attribution and
-                # the overlap report parse the same event list
-                try:
-                    trace_events, _ = traceparse.load_trace_events(trace_dir)
-                except Exception:
-                    trace_events = None
-                parsed = (traceparse.step_comm_from_events(trace_events)
-                          if trace_events is not None else None)
-                if parsed is not None:
-                    comm_traced, reduce_traced = parsed[0], parsed[1]
-                    if obs is not None:
-                        # the comm-vs-compute split obs_report renders:
-                        # trace-derived in-step collective seconds per epoch
-                        obs.emit("trace", epoch=epoch,
-                                 comm_s=round(comm_traced, 6),
-                                 reduce_s=round(reduce_traced, 6),
-                                 trace_dir=cfg.profile_dir or None)
-                    # drop the microbench samples recorded so far so the
-                    # printed means are purely the traced in-step numbers;
-                    # seed one sample immediately — the window-closing epoch
-                    # itself is excluded from record(), and a log line firing
-                    # on it would otherwise print an empty (0.0) mean
-                    timer.comm_dur.clear()
-                    timer.reduce_dur.clear()
-                    timer.comm_dur.append(comm_traced)
-                    timer.reduce_dur.append(reduce_traced)
+                # the overlap report parse the same event list. A window
+                # that cannot be read or attributed raises (TraceError names
+                # why): the run asked for traced columns, and carrying on
+                # under the [sampled] tag would hide that it has none.
+                # --no-comm-trace is the way to run without the window.
+                trace_events, _ = traceparse.load_trace_events(trace_dir)
+                # a 1-part program, or grad-only, exchanges nothing: no
+                # exchange span is then the truth (0 s), not a lost trace
+                exchanges = cfg.n_partitions > 1 and not grad_only
+                comm_traced, reduce_traced, _ = (
+                    traceparse.step_comm_from_events(trace_events, exchanges))
+                if obs is not None:
+                    # the comm-vs-compute split obs_report renders:
+                    # trace-derived in-step collective seconds per epoch
+                    # (`exchanges` lets an offline re-parse of the same
+                    # trace apply the same rule)
+                    obs.emit("trace", epoch=epoch,
+                             comm_s=round(comm_traced, 6),
+                             reduce_s=round(reduce_traced, 6),
+                             exchanges=exchanges,
+                             trace_dir=cfg.profile_dir or None)
+                # drop the microbench samples recorded so far so the
+                # printed means are purely the traced in-step numbers;
+                # seed one sample immediately — the window-closing epoch
+                # itself is excluded from record(), and a log line firing
+                # on it would otherwise print an empty (0.0) mean
+                timer.comm_dur.clear()
+                timer.reduce_dur.clear()
+                timer.comm_dur.append(comm_traced)
+                timer.reduce_dur.append(reduce_traced)
                 if fns.overlap == "split":
                     # --overlap split observability: per-step phase buckets +
                     # whether the collective ran under interior compute
-                    try:
-                        rep = (traceparse.overlap_from_events(trace_events)
-                               if trace_events is not None else None)
-                    except Exception:
-                        rep = None
+                    rep = traceparse.overlap_from_events(trace_events)
                     if rep is not None:
                         for k in ("exchange_ms", "interior_ms", "frontier_ms",
                                   "hidden_ms"):
@@ -1624,7 +1634,7 @@ def run_training(cfg: Config, g: Optional[Graph] = None,
             # epochs inside the trace window carry profiler-collection
             # overhead in dt — exclude them from the reported means like
             # warmup epochs (same rule as bench.py, whose traced runs are
-            # tagged profiled-diagnostic and never update best_known)
+            # tagged profiled-diagnostic)
             # retune epochs compile the rebuilt step programs inside dt —
             # excluded from the reported means exactly like warmup epochs
             clean_step = (not (trace_dir and prof_start <= epoch <= prof_stop)
@@ -1720,7 +1730,7 @@ def run_training(cfg: Config, g: Optional[Graph] = None,
             elif cfg.eval and is_rank0 and (epoch + 1) % cfg.log_every == 0:
                 if pending is not None:
                     done = _drain_eval(pending)
-                    if done is not None and done[1] > best_acc:
+                    if done[1] > best_acc:
                         best_acc, best_params = done[1], done[0]
                 p_host = jax.device_get(params)
                 s_host = jax.device_get(state)
@@ -1730,16 +1740,16 @@ def run_training(cfg: Config, g: Optional[Graph] = None,
                 # in a log_every=10 run)
                 if cfg.inductive:
                     pending = pool.submit(
-                        _eval_job, epoch,
-                        lambda p=p_host, s=s_host, e=epoch: (p, evaluate_induc(
-                            "Epoch %05d" % e, p, s, spec, val_g, "val",
-                            result_file)))
+                        lambda p=p_host, s=s_host, e=epoch: (e, (
+                            p, evaluate_induc(
+                                "Epoch %05d" % e, p, s, spec, val_g, "val",
+                                result_file, device=host_dev))))
                 else:
                     pending = pool.submit(
-                        _eval_job, epoch,
-                        lambda p=p_host, s=s_host, e=epoch: (p, evaluate_trans(
-                            "Epoch %05d" % e, p, s, spec, val_g,
-                            result_file)[0]))
+                        lambda p=p_host, s=s_host, e=epoch: (e, (
+                            p, evaluate_trans(
+                                "Epoch %05d" % e, p, s, spec, val_g,
+                                result_file, device=host_dev)[0])))
 
             if resil is not None and (epoch + 1) % cfg.log_every == 0:
                 if wrote_ckpt:
@@ -1830,7 +1840,7 @@ def run_training(cfg: Config, g: Optional[Graph] = None,
             pool.shutdown(wait=False, cancel_futures=True)
     if pending is not None:
         done = _drain_eval(pending)
-        if done is not None and done[1] > best_acc:
+        if done[1] > best_acc:
             best_acc, best_params = done[1], done[0]
     pool.shutdown(wait=True)
 
@@ -1870,7 +1880,7 @@ def run_training(cfg: Config, g: Optional[Graph] = None,
         elif is_rank0:
             res.test_acc = evaluate_induc("Test Result", best_params,
                                           jax.device_get(state), spec, test_g,
-                                          "test")
+                                          "test", device=host_dev)
 
     # ---- embedding-table export (--dump-embeddings): the all-node
     # penultimate activations + final-layer logits, written under the
@@ -1901,7 +1911,7 @@ def run_training(cfg: Config, g: Optional[Graph] = None,
             graph = test_g if test_g is not None else g
             hidden, logits = full_graph_embeddings(
                 dump_params, jax.device_get(state), spec, graph,
-                cfg.edge_chunk)
+                cfg.edge_chunk, device=host_dev)
         else:
             log("[serve] --dump-embeddings skipped: no eval graph loaded "
                 "(run with --eval, or --eval-device mesh transductive)")

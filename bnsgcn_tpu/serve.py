@@ -1290,9 +1290,11 @@ def serve_main(argv=None) -> int:
                 log(f"[serve] background refresh failed: "
                     f"{type(ex).__name__}: {ex}")
 
+    refresher = None
     if cfg.serve_refresh_s > 0:
-        threading.Thread(target=_refresher, name="bnsgcn-serve-refresh",
-                         daemon=True).start()
+        refresher = threading.Thread(target=_refresher,
+                                     name="bnsgcn-serve-refresh", daemon=True)
+        refresher.start()
 
     log(f"[serve] ready on port {server.port}: tier A table lookup + tier B "
         f"{core.hops}-hop re-aggregation (max batch {cfg.serve_max_batch}), "
@@ -1308,6 +1310,11 @@ def serve_main(argv=None) -> int:
                 break
     finally:
         stop_refresh.set()
+        if refresher is not None:
+            # a refresh still inside XLA when the interpreter tears down
+            # aborts the process (SIGABRT, seen as exit -6 after a clean
+            # shutdown op): wait it out, as serve_backend does
+            refresher.join(timeout=120.0)
         server.drain()
         core.close()
         path = core.flush_delta_log(serve_dir)

@@ -42,7 +42,7 @@ from bnsgcn_tpu.parallel.halo import (HaloSpec, full_rate_spec, halo_apply,
                                       make_halo_spec, make_refresh_spec,
                                       precompute_exchange, refresh_row_mask)
 from bnsgcn_tpu.parallel.mesh import (make_parts_mesh, parts_sharding,
-                                       replicated_sharding, shard_map)
+                                       replicated_sharding)
 from bnsgcn_tpu.parallel import feat as feat_mod
 from bnsgcn_tpu.parallel.reducer import grad_reduce_axes
 from bnsgcn_tpu.parallel.replicas import (dedup_replica0, stacked_spec,
@@ -88,6 +88,22 @@ def bce_sum(logits, labels, mask):
     return jnp.sum(jnp.where(mask[:, None], per, 0.0))
 
 
+def _psum_loss(ls, axes):
+    """The ONE fused loss psum over `axes` (reducer.grad_reduce_axes). The
+    per-device loss is invariant over a mesh axis it never read an index of
+    (always 'feat': each layer already psummed its partials), and psum
+    refuses an operand that is varying over some of its axes and invariant
+    over others — so the invariant ones are cast to varying first. The
+    n-fold sum this adds over such an axis is what loss_denom's
+    n_rep * n_fe factor already divides out."""
+    if isinstance(axes, tuple):
+        have = jax.typeof(ls).vma
+        missing = tuple(a for a in axes if a not in have)
+        if missing:
+            ls = jax.lax.pcast(ls, missing, to="varying")
+    return jax.lax.psum(ls, axes)
+
+
 # ----------------------------------------------------------------------------
 # device data
 # ----------------------------------------------------------------------------
@@ -113,8 +129,12 @@ def build_block_arrays(art: PartitionArtifacts, model: str,
 
 
 def place_blocks(blk: dict, mesh: Mesh) -> dict:
+    """Host [P, ...] arrays -> parts-sharded device arrays. device_put takes
+    the host array itself, so each part's rows go to their own device and
+    nowhere else (through jnp.asarray the whole stack would sit on device 0
+    first and be re-sharded from there)."""
     sh = parts_sharding(mesh)
-    return {k: jax.device_put(jnp.asarray(v), sh) for k, v in blk.items()}
+    return {k: jax.device_put(v, sh) for k, v in blk.items()}
 
 
 def place_replicated(tree, mesh: Mesh):
@@ -124,7 +144,7 @@ def place_replicated(tree, mesh: Mesh):
         return jax.tree.map(
             lambda v: jax.make_array_from_process_local_data(sh, np.asarray(v)),
             tree)
-    return jax.tree.map(lambda v: jax.device_put(jnp.asarray(v), sh), tree)
+    return jax.tree.map(lambda v: jax.device_put(v, sh), tree)
 
 
 def local_part_ids(mesh: Mesh) -> list[int]:
@@ -210,6 +230,11 @@ class StepFns:
                               # controller's lever baseline; run.py/bench.py
                               # label from it without re-deriving the auto
                               # selection
+    spmm_desc: str = ""       # what aggregation the step was BUILT with, for
+                              # the run header: resolved spmm kind and, for
+                              # hybrid, the dense-tile count, their edge
+                              # share and the dense path that really runs
+                              # (block_spmm.dense_path: pallas | xla)
 
 
 def _local_env(spec: ModelSpec, hspec: HaloSpec, blk: dict, plan,
@@ -328,6 +353,35 @@ def _cluster_perms(art: PartitionArtifacts, cfg: Config):
         perms_i.append(pi)
         perms_e.append(pe)
     return np.stack(perms_i), np.stack(perms_e)
+
+
+def _hybrid_desc(cfg: Config, art: PartitionArtifacts, arrays: dict,
+                 spec_pairs: dict) -> str:
+    """Run-header text for a built hybrid layout. `spec_pairs` maps each
+    array-key prefix ('' fused; 'int_'/'fro_' --overlap split) to its
+    (fwd, bwd) BlockSpecs."""
+    from bnsgcn_tpu.ops.block_spmm import dense_edge_count, dense_path
+    n_local = art.feat.shape[0]
+    tiles = 0
+    for pre, (f, _) in spec_pairs.items():
+        rb = arrays.get(pre + "blk_rowb_fwd")
+        if rb is not None:                # pad slots carry rowb == n_row_blocks
+            tiles += int((np.asarray(rb) < f.n_row_blocks).sum())
+    dense = sum(dense_edge_count(arrays, p) for p in range(n_local))
+    edges = max(int((art.dst < art.pad_inner).sum()), 1)
+    paths = sorted({dense_path(d, cfg.use_pallas, cfg.spmm_dense)
+                    for pair in spec_pairs.values() for d in pair})
+    via = "+".join(paths)
+    if cfg.use_pallas and jax.default_backend() != "tpu":
+        via += (f" (--use-pallas needs the tpu backend; this is "
+                f"{jax.default_backend()})")
+    elif cfg.use_pallas and paths != ["pallas"]:
+        via += " (int8 rows past the int32 accumulator bound stay on xla)"
+    return (f"hybrid, {tiles} dense {cfg.block_tile}x{cfg.block_tile} tiles "
+            f"on {n_local} local part(s) carry {dense / edges:.1%} of "
+            f"{edges} edges via {via}"
+            + (f" [{cfg.spmm_dense} slabs]" if cfg.spmm_dense != "native"
+               else "") + ", ell residual")
 
 
 def _compose_split(spmms, pad_inner: int):
@@ -467,6 +521,7 @@ def build_step_fns(cfg: Config, spec: ModelSpec, art: PartitionArtifacts,
     # process compiles the identical program from its local parts.
     ell_spmm, ell_keys, ell_arrays = None, (), {}
     ell_spmm_pre = None
+    spmm_desc = ""                      # hybrid builds fill it; else below
     spmm_kind = cfg.spmm
     auto_perms = None
     if spmm_kind == "auto":
@@ -585,6 +640,9 @@ def build_step_fns(cfg: Config, spec: ModelSpec, art: PartitionArtifacts,
         ell_spmm_pre = _compose_split(split_pre, art.pad_inner)
         ell_keys = tuple(ell_arrays.keys())
         split_kind = "hybrid"
+        spmm_desc = _hybrid_desc(cfg, art, ell_arrays,
+                                 {"int_": (int_f, int_b),
+                                  "fro_": (fro_f, fro_b)})
     elif want_hybrid:
         from bnsgcn_tpu.ops.block_spmm import (build_block_layouts,
                                                make_block_spmm)
@@ -638,6 +696,7 @@ def build_step_fns(cfg: Config, spec: ModelSpec, art: PartitionArtifacts,
                                        use_pallas=cfg.use_pallas,
                                        accum="reduce")
         ell_keys = tuple(ell_arrays.keys())
+        spmm_desc = _hybrid_desc(cfg, art, ell_arrays, {"": (fwd_b, bwd_b)})
     elif (spmm_kind == "ell" and spec.model in ("gcn", "graphsage")
           and overlap == "split"):
         from bnsgcn_tpu.ops.ell import build_split_layouts, make_ell_spmm
@@ -726,6 +785,10 @@ def build_step_fns(cfg: Config, spec: ModelSpec, art: PartitionArtifacts,
             ell_arrays.update(gat_arrays)
             gat_keys = tuple(gat_arrays.keys())
 
+    if not spmm_desc:
+        spmm_desc = ("ell gathers" if ell_spmm is not None
+                     else "gat ell-attention" if gat_spec is not None
+                     else "segment-sum over coo edges")
     if cfg.spmm_gather != "native" and ell_spmm is None and jax.process_index() == 0:
         print(f"spmm_gather={cfg.spmm_gather} has no effect for spmm={spmm_kind!r} / "
               f"model={spec.model!r} (only the ell/hybrid GCN/GraphSAGE "
@@ -863,10 +926,10 @@ def build_step_fns(cfg: Config, spec: ModelSpec, art: PartitionArtifacts,
         # rescaled by n_replicas — the AD transpose of the replicated params
         # therefore emits one gradient all-reduce over the whole mesh, whose
         # result is exactly mean-over-replicas of the per-replica gradients
-        loss = jax.lax.psum(ls / loss_denom, loss_axes)
+        loss = _psum_loss(ls / loss_denom, loss_axes)
         return loss, new_state
 
-    sharded_loss = shard_map(
+    sharded_loss = jax.shard_map(
         local_loss, mesh=mesh,
         in_specs=(param_spec, rep, blk_spec, rep, rep, rep, rep),
         out_specs=(rep, rep))
@@ -978,7 +1041,7 @@ def build_step_fns(cfg: Config, spec: ModelSpec, art: PartitionArtifacts,
                     ls = bce_sum(logits, blk["label"], blk["train_mask"])
                 else:
                     ls = ce_sum(logits, blk["label"], blk["train_mask"])
-                loss = jax.lax.psum(ls / loss_denom, loss_axes)
+                loss = _psum_loss(ls / loss_denom, loss_axes)
                 return loss, (new_state, cache_out)
 
             if cached:
@@ -991,11 +1054,11 @@ def build_step_fns(cfg: Config, spec: ModelSpec, art: PartitionArtifacts,
         # the cache travels as a stacked (per-(replica,part)-varying) pytree:
         # each mesh slot keeps its own blocks — replicas drew independent
         # samples, feat shards hold H/T-wide slices
-        sharded_full = shard_map(
+        sharded_full = jax.shard_map(
             _make_refresh_loss(False), mesh=mesh,
             in_specs=(param_spec, rep, blk_spec, rep, rep, rep, rep),
             out_specs=(rep, (rep, stacked)))
-        sharded_cached = shard_map(
+        sharded_cached = jax.shard_map(
             _make_refresh_loss(True), mesh=mesh,
             in_specs=(param_spec, rep, blk_spec, rep, stacked, rep, rep, rep),
             out_specs=(rep, (rep, stacked)))
@@ -1035,10 +1098,9 @@ def build_step_fns(cfg: Config, spec: ModelSpec, art: PartitionArtifacts,
         def exchange_only_refresh(blk, tables_r, epoch, sample_key, width):
             """Comm(s) microbench on the partial-refresh geometry — what a
             steady-state (cache-hit) epoch actually puts on the wire."""
-            f = shard_map(partial(local_exchange_only_refresh, width=width),
-                          mesh=mesh,
-                          in_specs=(blk_spec, rep, rep, rep),
-                          out_specs=stacked)
+            f = jax.shard_map(
+                partial(local_exchange_only_refresh, width=width), mesh=mesh,
+                in_specs=(blk_spec, rep, rep, rep), out_specs=stacked)
             return f(blk, tables_r, epoch, sample_key)
 
         refresh_fns = dict(
@@ -1070,7 +1132,7 @@ def build_step_fns(cfg: Config, spec: ModelSpec, art: PartitionArtifacts,
         """Training-mode forward (per-epoch sampling active), logits per part.
         Replica meshes de-duplicate the report to replica 0's draw so the
         host-side consumers keep the [P, pad_inner, C] shape."""
-        f = shard_map(
+        f = jax.shard_map(
             partial(local_forward),
             mesh=mesh,
             in_specs=(param_spec, rep, blk_spec, rep, rep, rep, rep),
@@ -1108,7 +1170,7 @@ def build_step_fns(cfg: Config, spec: ModelSpec, art: PartitionArtifacts,
     def eval_forward(params, state, blk, tables_full):
         # full-rate eval is deterministic, so every replica computes the
         # same logits; metrics de-duplicate to replica 0's copy
-        f = shard_map(local_eval, mesh=mesh,
+        f = jax.shard_map(local_eval, mesh=mesh,
                           in_specs=(param_spec, rep, blk_spec, rep),
                           out_specs=stacked)
         return dedup_replica0(f(params, state, blk, tables_full),
@@ -1116,7 +1178,7 @@ def build_step_fns(cfg: Config, spec: ModelSpec, art: PartitionArtifacts,
 
     @jax.jit
     def embed_forward(params, state, blk, tables_full):
-        f = shard_map(local_embed, mesh=mesh,
+        f = jax.shard_map(local_embed, mesh=mesh,
                           in_specs=(param_spec, rep, blk_spec, rep),
                           out_specs=(stacked, stacked))
         hid, lg = f(params, state, blk, tables_full)
@@ -1147,7 +1209,7 @@ def build_step_fns(cfg: Config, spec: ModelSpec, art: PartitionArtifacts,
         # one-time, full-rate, key-free — replicas compute identical copies;
         # de-dup to replica 0 so the result drops back into the P('parts')
         # block dict (re-replicated over the replica axis on placement)
-        f = shard_map(local_precompute, mesh=mesh,
+        f = jax.shard_map(local_precompute, mesh=mesh,
                           in_specs=(blk_spec, rep), out_specs=stacked)
         return dedup_replica0(f(blk, tables_full), mesh, hspec.n_parts)
 
@@ -1165,7 +1227,7 @@ def build_step_fns(cfg: Config, spec: ModelSpec, art: PartitionArtifacts,
     def exchange_only(blk, tables, epoch, sample_key, width):
         """Isolated halo exchange x n_graph_layers — the Comm(s) microbench.
         Per-replica sums differ (independent draws): stacked out spec."""
-        f = shard_map(partial(local_exchange_only, width=width),
+        f = jax.shard_map(partial(local_exchange_only, width=width),
                           mesh=mesh,
                           in_specs=(blk_spec, rep, rep, rep), out_specs=stacked)
         return f(blk, tables, epoch, sample_key)
@@ -1187,6 +1249,7 @@ def build_step_fns(cfg: Config, spec: ModelSpec, art: PartitionArtifacts,
                   halo_refresh=refresh_k,
                   halo_mode=halo_mode,
                   halo_strategy=halo_strategy,
+                  spmm_desc=spmm_desc,
                   **refresh_fns)
     return fns, hspec, tables, tables_full
 

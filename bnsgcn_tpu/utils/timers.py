@@ -77,11 +77,8 @@ class EpochTimer:
 def device_memory_stats() -> dict:
     """Peak/current HBM per device (reference print_memory equivalent)."""
     out = {}
-    for d in jax.devices():
-        try:
-            s = d.memory_stats()
-        except Exception:
-            s = None
+    for d in jax.local_devices():       # stats exist for addressable devices
+        s = d.memory_stats()            # None where the backend keeps none
         if s:
             out[str(d)] = {
                 "bytes_in_use": s.get("bytes_in_use", 0),

@@ -2,10 +2,10 @@
 
 The reference measures its Comm column as in-step wall-clock around each
 send/recv (``helper/timer/comm_timer.py:21-25``). Under XLA a wall-clock
-span inside a jitted step is meaningless, and the round-4 hardware
-cross-check (hw_logs/trace_comm_table.log) showed the exchange-only
-microbench overstates the real in-step collective cost by 1.5-26x — host
-dispatch dominates for small quantized payloads. The truthful equivalent
+span inside a jitted step is meaningless, and the 2026-07-30 cross-check
+on an 8-device host-platform mesh (hw_logs/trace_comm_table.log) showed the
+exchange-only microbench overstates the real in-step collective cost by
+1.5-26x — host dispatch dominates for small quantized payloads. The truthful equivalent
 of the reference's measurement is the profiler trace itself: every device
 collective span, attributed to the train_step that launched it, with a
 min-over-lanes estimate that strips rendezvous wait (lane i's span
@@ -27,9 +27,19 @@ import json
 import os
 import re
 
-EXCHANGE_PAT = re.compile(r"all-to-all|collective-permute", re.I)
-REDUCE_PAT = re.compile(r"all-reduce|reduce-scatter|all-gather", re.I)
+# Device collective spans by HLO instruction name. Both spellings occur: the
+# TPU names an instruction after the jax primitive that made it
+# (`all_to_all.32`, seen on a v5e under jax 0.9) or after the HLO opcode
+# (`all-reduce.7`; XLA:CPU uses the opcode for every collective).
+EXCHANGE_PAT = re.compile(r"all[-_]to[-_]all|collective[-_]permute", re.I)
+REDUCE_PAT = re.compile(r"all[-_]reduce|reduce[-_]scatter|all[-_]gather",
+                        re.I)
+# Host programs by name prefix: --halo-refresh K>1 (and the --tune K-anneal)
+# launch train_step_full / train_step_cached / exchange_only_refresh, which
+# are train steps and exchange sweeps like the K=1 programs.
 HOST_PROGRAMS = ("train_step", "exchange_only")
+_LAUNCH_PAT = re.compile(r"^(?:PjitFunction\((\w+)\)|jit_(\w+))$")
+
 # --overlap split phase scopes (trainer._split_agg_for wraps the interior /
 # frontier aggregations in jax.named_scope, which XLA threads into op
 # metadata; profiler events carry it in the name or an args value)
@@ -37,14 +47,36 @@ INTERIOR_PAT = re.compile(r"interior_agg", re.I)
 FRONTIER_PAT = re.compile(r"frontier_agg", re.I)
 
 
+def _host_program(name):
+    """The HOST_PROGRAMS bucket a host launch span belongs to, or None."""
+    m = _LAUNCH_PAT.match(name)
+    if m:
+        fn = m.group(1) or m.group(2)
+        for prog in HOST_PROGRAMS:
+            if fn.startswith(prog):
+                return prog
+    return None
+
+
+class TraceError(ValueError):
+    """A profiler window that cannot be read or attributed; the message
+    names why. Raised, never swallowed: a caller that wants to carry on
+    without traced numbers catches it and says so."""
+
+
 def load_trace_events(trace_dir):
     """Newest <host>.trace.json.gz under trace_dir (chrome trace format)."""
     paths = sorted(glob.glob(os.path.join(
         trace_dir, "plugins/profile/*/*.trace.json.gz")), key=os.path.getmtime)
     if not paths:
-        raise FileNotFoundError(f"no trace.json.gz under {trace_dir}")
-    with gzip.open(paths[-1], "rt") as f:
-        return json.load(f).get("traceEvents", []), paths[-1]
+        raise TraceError(
+            f"the profiler wrote no plugins/profile/*/*.trace.json.gz under "
+            f"{trace_dir}")
+    try:
+        with gzip.open(paths[-1], "rt") as f:
+            return json.load(f).get("traceEvents", []), paths[-1]
+    except (OSError, EOFError, ValueError) as ex:
+        raise TraceError(f"unreadable trace {paths[-1]}: {ex}") from ex
 
 
 def _thread_names(events):
@@ -73,10 +105,9 @@ def attribute(events):
     for ev in events:
         if ev.get("ph") != "X":
             continue
-        name = ev.get("name", "")
-        for prog in HOST_PROGRAMS:
-            if name == f"PjitFunction({prog})" or name == f"jit_{prog}":
-                raw_launches.append((float(ev["ts"]), prog))
+        prog = _host_program(ev.get("name", ""))
+        if prog is not None:
+            raw_launches.append((float(ev["ts"]), prog))
     raw_launches.sort()
     launches = []
     for ts, prog in raw_launches:
@@ -217,13 +248,10 @@ def overlap_from_events(events):
 
 
 def overlap_report(trace_dir):
-    """overlap_from_events over the newest trace in `trace_dir`; None on any
-    parse failure (callers log 'no overlap evidence', never crash)."""
-    try:
-        events, _ = load_trace_events(trace_dir)
-        return overlap_from_events(events)
-    except Exception:
-        return None
+    """overlap_from_events over the newest trace in `trace_dir` (None = the
+    trace holds no interior/frontier scopes; an unreadable trace raises
+    TraceError)."""
+    return overlap_from_events(load_trace_events(trace_dir)[0])
 
 
 def _replica_groups(ev):
@@ -344,38 +372,37 @@ def comm_by_axis(events, n_parts: int, n_replicas: int = 1, n_feat: int = 1):
     return out
 
 
-def step_comm_from_events(events):
+def step_comm_from_events(events, expect_exchange: bool):
     """Per-train_step in-step (exchange_s, reduce_s, n_steps) over already-
     loaded events — run.py loads the trace ONCE and feeds both this and
     overlap_from_events (a multi-epoch trace re-parse costs seconds of
-    host stall between epochs)."""
-    try:
-        attr = attribute(events)
-        steps = attr["train_step"]["launches"]
-        if steps < 1:
-            return None
-        _, ex_us, ex_n, _ = program_cost(attr["train_step"], "exchange")
-        _, rd_us, _, _ = program_cost(attr["train_step"], "reduce")
-        if ex_n == 0:
-            # every multi-part train step carries exchange collectives; a
-            # window with none means the profiler lost the device ops
-            # (e.g. the step compiled inside the window) — report failure,
-            # not a fabricated 0.0000 column
-            return None
-        return ex_us / steps / 1e6, rd_us / steps / 1e6, steps
-    except Exception:
-        return None
+    host stall between epochs).
+
+    Raises TraceError when the window cannot be attributed. The trace
+    alone cannot tell a program that exchanges nothing from a window that
+    lost its device ops, so the caller says which program ran
+    (`expect_exchange`; run.py records it as `exchanges` in the obs `trace`
+    event for offline readers). True (every multi-part step that exchanges
+    activations): a window without exchange spans means the profiler lost
+    the device ops (e.g. the step compiled inside the window) — reported as
+    that, never as a fabricated 0.0000 column. False (a 1-part program, or
+    --halo-mode grad-only): no exchange span is the truth, 0 s."""
+    attr = attribute(events)
+    steps = attr["train_step"]["launches"]
+    if steps < 1:
+        raise TraceError("no train_step launch in the trace window")
+    _, ex_us, ex_n, _ = program_cost(attr["train_step"], "exchange")
+    _, rd_us, _, _ = program_cost(attr["train_step"], "reduce")
+    if ex_n == 0 and expect_exchange:
+        raise TraceError(
+            f"{steps} train_step launch(es) in the trace window but no "
+            f"device exchange span (all-to-all / collective-permute): the "
+            f"profiler lost the device ops")
+    return ex_us / steps / 1e6, rd_us / steps / 1e6, steps
 
 
-def step_comm_per_epoch(trace_dir):
-    """step_comm_from_events over the newest trace in `trace_dir`.
-
-    Returns None when the trace is missing/unreadable or holds no
-    train_step launch — callers fall back to the microbench column
-    (tagged [sampled]) rather than printing a fabricated number.
-    """
-    try:
-        events, _ = load_trace_events(trace_dir)
-    except Exception:
-        return None
-    return step_comm_from_events(events)
+def step_comm_per_epoch(trace_dir, expect_exchange: bool):
+    """step_comm_from_events over the newest trace in `trace_dir`; raises
+    TraceError when the trace is missing, unreadable or unattributable."""
+    return step_comm_from_events(load_trace_events(trace_dir)[0],
+                                 expect_exchange)

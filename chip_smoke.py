@@ -1,0 +1,413 @@
+"""The quickest proof that the training path still starts on the chip.
+
+    python chip_smoke.py        # on a machine with a TPU; ~2-3 min cold
+
+One process, no arguments, no JAX_PLATFORMS set here: it runs on whatever
+JAX finds and REFUSES (non-zero exit, no result line) unless that is a TPU.
+It drives the product's own entry point, `bnsgcn_tpu.main.main(argv)` ->
+`run_training`, at the flagship widths (GraphSAGE 602 -> 4 x 256 -> 41,
+scripts/reddit.sh) with the on-chip recipe (bf16, hybrid SpMM, the Pallas
+dense-tile kernel, BNS rate 0.1, use_pp), a dozen epochs, host eval every
+--log-every, checkpoints written. Widths are not cut; the graph is
+(`synth-reddit:0.1`: 23,296 nodes, 2.3M edges - large enough that the
+hybrid layout selects dense tiles, small enough that a cold run fits the
+chip tool's limit). Weights are random, from --fix-seed.
+
+Phases (every one runs; any failure fails the script):
+
+  kernel    ops/pallas_block.dense_apply_pallas against its XLA twin
+            ops/block_spmm._dense_apply and a float64 host reference, on the
+            chip, at tile 512 and 256, H=256, bf16 and int8 slabs.
+  P=4       the recipe at --n-partitions 4, when the host has >= 4 chips
+            (first, so each chip's peak memory is this run's alone).
+  P=1       the recipe at --n-partitions 1.
+
+Each training phase checks what the log shows (loss finite and falling,
+Time(s) > 0, the Comm(s) column traced, eval and test accuracy produced,
+checkpoints on disk, a device-reported memory peak) and what it cannot: the
+train step each chip EXECUTED holds the Mosaic custom call (the Pallas kernel
+really compiled, interpret=False) and, at P>1, the all-to-all and the
+all-reduce. That is read from the run's own profiler window (--profile-dir
+keeps the trace run_training parses for its Comm(s) column), so it is the
+program that ran, not a second build of it.
+
+Starts from a clean tree: its work directory (smoke_work/, git-ignored) and
+any built native library are removed first, and nothing else a checkout can
+hold (partition/, checkpoint/, bench_cache/) is read. The XLA compile cache
+is the one thing kept between runs, where utils/platform.place_compile_cache
+puts it - a second run reports its hits.
+
+Last stdout line on success, and only then:
+    {"ok": true, "device": {"platform": "tpu", "kind": "...", "count": N}}
+"""
+
+from __future__ import annotations
+
+import contextlib
+import glob
+import io
+import json
+import os
+import re
+import shutil
+import sys
+import time
+import traceback
+
+ROOT = os.path.dirname(os.path.abspath(__file__))
+WORK = os.path.join(ROOT, "smoke_work")
+
+RECIPE = ["--dataset", "synth-reddit:0.1", "--model", "graphsage",
+          "--n-layers", "4", "--n-hidden", "256", "--use-pp",
+          "--sampling-rate", "0.1", "--dtype", "bfloat16",
+          "--spmm", "hybrid", "--use-pallas",
+          "--n-epochs", "12", "--log-every", "4", "--fix-seed"]
+
+# kernel-agreement tolerances (phase `kernel`).
+# bf16 slabs: both paths accumulate bf16 x int8 products in f32 and round the
+# result to bf16 once; they differ by f32 summation order, so by at most one
+# bf16 ulp (2^-8 relative, 2^-7 across a power of two) plus an absolute term
+# for rows whose terms cancel.
+NATIVE_RTOL, NATIVE_ATOL = 2.0 ** -7, 2e-2
+# int8 slabs: the two paths quantize differently BY DESIGN (one per-call
+# scale in the kernel, one per slab in XLA), so each is held to the
+# quantizer's own bounds against the exact result instead of to the other.
+# Hard: a row's error is at most (its dense edge count) x scale/2 with
+# scale = amax/127, plus the output's bf16 rounding. Statistical: rounding
+# errors are uniform in +-scale/2 and independent, multiplicities are <= 3,
+# so the rms error over all outputs is at most scale/2 x sqrt(mean dense
+# edges per row) (+ bf16 rounding); 1.5x that is allowed.
+INT8_SLACK, INT8_RMS_SLACK = 1.05, 1.5
+
+
+def fail(msg: str, code: int = 1):
+    print(f"chip_smoke: {msg}", file=sys.stderr)
+    sys.exit(code)
+
+
+class _Tee(io.TextIOBase):
+    def __init__(self, *streams):
+        self.streams = streams
+
+    def write(self, text):
+        for st in self.streams:
+            st.write(text)
+        return len(text)
+
+    def flush(self):
+        for st in self.streams:
+            st.flush()
+
+
+# ---------------------------------------------------------------------------
+# phase: kernel agreement
+# ---------------------------------------------------------------------------
+
+def kernel_phase(report: dict):
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from bnsgcn_tpu.ops.block_spmm import BlockSpec, _dense_apply
+    from bnsgcn_tpu.ops.pallas_block import dense_apply_pallas
+
+    H, n_rows, n_src = 256, 2048, 3072
+    out = {}
+    for tile in (512, 256):
+        rng = np.random.default_rng(tile)
+        n_rb, n_cb = n_rows // tile, n_src // tile
+        # every (row block, col block) pair except row block 1, which stays
+        # UNVISITED (the kernel never writes it: the caller's mask must),
+        # then two pad tiles (rowb == n_rb, all-zero) as stacked layouts have
+        rb, cb = np.meshgrid(np.arange(n_rb), np.arange(n_cb), indexing="ij")
+        keep = rb.ravel() != 1
+        rowb = np.concatenate([rb.ravel()[keep], [n_rb, n_rb]]).astype(np.int32)
+        colb = np.concatenate([cb.ravel()[keep], [0, 0]]).astype(np.int32)
+        B = len(rowb)
+        tiles = ((rng.random((B, tile, tile)) < 0.02)
+                 * rng.integers(1, 4, (B, tile, tile))).astype(np.int8)
+        tiles[-2:] = 0
+        perm_src = rng.permutation(n_src).astype(np.int32)
+        perm_out = rng.permutation(n_rows).astype(np.int32)
+        h = jnp.asarray(rng.normal(size=(n_src, H)), jnp.bfloat16)
+        row_deg = np.zeros((n_rb + 1) * tile)
+        np.add.at(row_deg.reshape(n_rb + 1, tile), rowb,
+                  tiles.sum(axis=2, dtype=np.int64))
+        spec = BlockSpec(n_rows=n_rows, n_src=n_src, row_tile=tile,
+                         col_tile=tile, n_blocks=B, n_row_blocks=n_rb,
+                         max_row_dense=int(row_deg.max()))
+
+        # float64 host reference of the same contraction
+        h64 = np.asarray(h.astype(jnp.float32), np.float64)
+        x_cl = np.zeros((n_cb * tile, H))
+        x_cl[perm_src] = h64
+        flat = np.zeros(((n_rb + 1) * tile, H))
+        for b in range(B):
+            flat[rowb[b] * tile:(rowb[b] + 1) * tile] += (
+                tiles[b].astype(np.float64)
+                @ x_cl[colb[b] * tile:(colb[b] + 1) * tile])
+        exact = flat[perm_out]
+        deg = row_deg[perm_out][:, None]
+        amax = float(np.abs(h64).max())
+
+        args = tuple(jnp.asarray(a) for a in
+                     (tiles, rowb, colb, perm_src, perm_out)) + (h,)
+        for dense in ("native", "int8"):
+            pal = jax.jit(lambda *a, d=dense: dense_apply_pallas(
+                spec, *a, dense_dtype=d))
+            xla = jax.jit(lambda *a, d=dense: _dense_apply(
+                spec, *a, dense_dtype=d))
+            if "tpu_custom_call" not in pal.lower(*args).compile().as_text():
+                raise AssertionError(
+                    f"t{tile}/{dense}: the Pallas path compiled without a "
+                    f"Mosaic custom call")
+            got_p = np.asarray(pal(*args).astype(jnp.float32), np.float64)
+            got_x = np.asarray(xla(*args).astype(jnp.float32), np.float64)
+            if not (np.isfinite(got_p).all() and np.isfinite(got_x).all()):
+                raise AssertionError(f"t{tile}/{dense}: non-finite output")
+            if np.abs(got_p[perm_out // tile == 1]).max() != 0:
+                raise AssertionError(
+                    f"t{tile}/{dense}: rows of the unvisited row block are "
+                    f"not zero (uninitialized kernel output leaked)")
+            if dense == "native":
+                bound_px = NATIVE_RTOL * np.abs(got_x) + NATIVE_ATOL
+                bound_ex = NATIVE_RTOL * np.abs(exact) + NATIVE_ATOL
+                errs = {"pallas_vs_xla": np.abs(got_p - got_x) - bound_px,
+                        "pallas_vs_exact": np.abs(got_p - exact) - bound_ex,
+                        "xla_vs_exact": np.abs(got_x - exact) - bound_ex}
+            else:
+                bound = (INT8_SLACK * deg * amax / 127.0 / 2.0
+                         + 2.0 ** -7 * np.abs(exact) + NATIVE_ATOL)
+                rms_bound = (INT8_RMS_SLACK * amax / 127.0 / 2.0
+                             * np.sqrt(deg.mean())
+                             + 2.0 ** -8 * np.sqrt((exact ** 2).mean()))
+                errs = {"pallas_vs_exact": np.abs(got_p - exact) - bound,
+                        "xla_vs_exact": np.abs(got_x - exact) - bound,
+                        "pallas_rms": np.sqrt(((got_p - exact) ** 2).mean())
+                        - rms_bound,
+                        "xla_rms": np.sqrt(((got_x - exact) ** 2).mean())
+                        - rms_bound}
+            worst = {k: float(np.max(v)) for k, v in errs.items()}
+            bad = {k: v for k, v in worst.items() if v > 0}
+            if bad:
+                raise AssertionError(
+                    f"t{tile}/{dense}: outside tolerance by {bad}")
+            row = out[f"t{tile}/{dense}"] = {
+                "max_abs_pallas_vs_xla": float(np.abs(got_p - got_x).max()),
+                "max_abs_pallas_vs_exact": float(np.abs(got_p - exact).max()),
+                "rms_pallas_vs_exact": float(
+                    np.sqrt(((got_p - exact) ** 2).mean())),
+                "out_rms": float(np.sqrt((exact ** 2).mean()))}
+            print(f"[smoke] kernel t{tile}/{dense}: pallas vs xla max "
+                  f"{row['max_abs_pallas_vs_xla']:.4g}, vs exact max "
+                  f"{row['max_abs_pallas_vs_exact']:.4g} "
+                  f"(output rms {row['out_rms']:.3g})")
+    report["kernel"] = out
+
+
+# ---------------------------------------------------------------------------
+# phase: the recipe through main.py
+# ---------------------------------------------------------------------------
+
+def executed_step_ops(trace_dir: str):
+    """What the run's own trace window shows its train step executing:
+    (train_step launches, {device: [HLO text of each Pallas kernel span]},
+    devices that ran an exchange collective, devices that ran a reduce). A
+    v5e trace lists the Mosaic custom call on each `/device:TPU:k` process
+    under the pallas_call's name (`bns_tile_matmul.N`) with the instruction
+    text in args.long_name (seen on four v5e chips, PR 22); an interpreted
+    kernel is plain XLA ops and leaves no such span."""
+    from bnsgcn_tpu.ops.pallas_block import KERNEL_NAME
+    from bnsgcn_tpu.utils import traceparse
+
+    events, _ = traceparse.load_trace_events(trace_dir)
+    attr = traceparse.attribute(events)["train_step"]
+    procs = {ev["pid"]: ev["args"].get("name", "") for ev in events
+             if ev.get("ph") == "M" and ev.get("name") == "process_name"}
+    kernel_pat = re.compile(re.escape(KERNEL_NAME) + r"(\.\d+)?$")
+    kernels = {}
+    for ev in events:
+        dev = procs.get(ev.get("pid"), "")
+        if (ev.get("ph") == "X" and dev.startswith("/device:")
+                and kernel_pat.match(ev.get("name", ""))):
+            kernels.setdefault(dev, []).append(
+                (ev.get("args") or {}).get("long_name", ""))
+    ex_devs, rd_devs = ({procs.get(pid, pid) for pid, _ in attr[cat]}
+                        for cat in ("exchange", "reduce"))
+    return attr["launches"], kernels, ex_devs, rd_devs
+
+
+def train_phase(n_parts: int, report: dict):
+    import numpy as np
+
+    from bnsgcn_tpu.main import main
+    from bnsgcn_tpu.utils.timers import device_memory_stats
+
+    tag = f"p{n_parts}"
+    base = os.path.join(WORK, tag)
+    argv = RECIPE + ["--n-partitions", str(n_parts),
+                     "--part-path", os.path.join(base, "parts"),
+                     "--ckpt-path", os.path.join(base, "ckpt"),
+                     "--results-path", os.path.join(base, "results"),
+                     "--profile-dir", os.path.join(base, "trace")]
+    print(f"[smoke] {tag}: python -m bnsgcn_tpu.main {' '.join(argv)}")
+    buf = io.StringIO()
+    t0 = time.time()
+    with contextlib.redirect_stdout(_Tee(sys.stdout, buf)):
+        res = main(argv)
+    wall = time.time() - t0
+    log = buf.getvalue()
+    checks = []
+
+    def check(ok, what):
+        checks.append((bool(ok), what))
+
+    losses = np.asarray(res.losses, np.float64)
+    check(len(losses) == 12 and np.isfinite(losses).all(),
+          f"12 finite losses (got {len(losses)}: {losses.round(3).tolist()})")
+    check(losses[-3:].mean() < losses[:3].mean(),
+          f"loss falling ({losses[:3].mean():.3f} -> {losses[-3:].mean():.3f})")
+    check(res.epoch_time > 0, f"Time(s) > 0 after warm-up ({res.epoch_time})")
+    procs = [ln for ln in log.splitlines() if ln.startswith("Process 000")]
+    check(procs and "[traced]" in procs[-1],
+          f"Comm(s) column traced in the last epoch line ({procs[-1:]})")
+    m = re.search(r"Step: spmm hybrid, (\d+) dense 512x512 tiles .* via "
+                  r"pallas,", log)
+    check(m and int(m.group(1)) > 0,
+          "run header: hybrid layout with > 0 dense tiles via pallas "
+          f"({[ln for ln in log.splitlines() if ln.startswith('Step:')]})")
+    check(res.best_val_acc > 0 and res.test_acc > 0
+          and log.count("Validation Accuracy") >= 3,
+          f"eval every --log-every + final test (val {res.best_val_acc:.3f}, "
+          f"test {res.test_acc:.3f})")
+    ckpts = sorted(os.listdir(os.path.join(base, "ckpt")))
+    check(len(ckpts) >= 2, f"periodic + final checkpoints written ({ckpts})")
+    check("RESULT final_loss=" in log, "main.py printed its RESULT line")
+
+    mem = device_memory_stats()
+    peaks = [v["peak_bytes_in_use"] for v in list(mem.values())[:n_parts]]
+    check(len(peaks) == n_parts and min(peaks, default=0) > 0,
+          f"device.memory_stats() reports a peak on each of the {n_parts} "
+          f"chip(s) used ({[round(p / 2**20) for p in peaks]} MB)")
+    if n_parts > 1:
+        # each chip holds its own part and nothing else: padded blocks are
+        # one shape per part, so the peaks are one size. Blocks staged
+        # through chip 0 would show there as a multiple.
+        lo, hi = min(peaks, default=0), max(peaks, default=0)
+        check(0 < hi <= 1.25 * lo,
+              f"per-chip peak memory of one size (max/min "
+              f"{hi / max(lo, 1):.3f})")
+
+    steps, kernels, ex_devs, rd_devs = executed_step_ops(
+        os.path.join(base, "trace"))
+    texts = [t for spans in kernels.values() for t in spans]
+    targets = set(re.findall(r'custom_call_target="([^"]+)"', " ".join(texts)))
+    check(steps >= 1 and len(kernels) == n_parts
+          and all(len(spans) >= steps for spans in kernels.values())
+          and all("custom-call(" in t for t in texts)
+          and targets <= {"tpu_custom_call"},
+          f"each of the {n_parts} chip(s) executed the Mosaic custom call in "
+          f"every traced train step ({steps} steps; kernel spans per device "
+          f"{ {d: len(v) for d, v in sorted(kernels.items())} }, targets "
+          f"{sorted(targets)}, e.g. {texts[0][:160] if texts else None!r})")
+    if n_parts > 1:
+        check(len(ex_devs) == n_parts,
+              f"the train step ran its all-to-all on each chip "
+              f"({sorted(ex_devs)})")
+        check(len(rd_devs) == n_parts,
+              f"the train step ran its all-reduce on each chip "
+              f"({sorted(rd_devs)})")
+
+    for ok, what in checks:
+        print(f"[smoke] {tag}: {'ok  ' if ok else 'FAIL'} {what}")
+    report[tag] = {"epoch_time_s": round(res.epoch_time, 5),
+                   "comm_time_s": round(res.comm_time, 6),
+                   "final_loss": round(res.final_loss, 4),
+                   "test_acc": round(res.test_acc, 4),
+                   "wall_s": round(wall, 1),
+                   "peak_mb": [round(p / 2**20, 1) for p in peaks]}
+    failed = [what for ok, what in checks if not ok]
+    if failed:
+        raise AssertionError(f"{tag}: " + "; ".join(failed))
+
+
+# ---------------------------------------------------------------------------
+
+def run():
+    try:
+        import bnsgcn_tpu
+    except ImportError as ex:
+        fail(f"the repository is not beside this script ({ex})", 2)
+    if os.path.dirname(os.path.dirname(os.path.abspath(
+            bnsgcn_tpu.__file__))) != ROOT:
+        fail(f"bnsgcn_tpu imports from {bnsgcn_tpu.__file__}, not from "
+             f"beside this script ({ROOT})", 2)
+
+    import jax
+    try:
+        backend = jax.default_backend()
+    except RuntimeError as ex:
+        fail(f"no chip: JAX could not start its backend ({ex})")
+    devs = jax.devices()
+    device = {"platform": devs[0].platform, "kind": devs[0].device_kind,
+              "count": len(devs)}
+    print(f"[smoke] jax {jax.__version__}, devices: {json.dumps(device)}, "
+          f"JAX_PLATFORMS={os.environ.get('JAX_PLATFORMS')!r}")
+    if backend != "tpu":
+        fail(f"no chip: JAX's default backend here is {backend!r} "
+             f"({device['kind']} x {device['count']}); this script proves "
+             f"the TPU path and prints no result without one")
+
+    # clean tree: nothing left over from an earlier run or another host
+    shutil.rmtree(WORK, ignore_errors=True)
+    for so in glob.glob(os.path.join(ROOT, "bnsgcn_tpu", "native", "*.so")):
+        os.remove(so)
+
+    from jax import monitoring
+
+    from bnsgcn_tpu.utils.platform import place_compile_cache
+    cache = {"dir": place_compile_cache(), "hits": 0, "misses": 0,
+             "compile_s": 0.0}
+
+    def on_event(event, **_):
+        if event == "/jax/compilation_cache/cache_hits":
+            cache["hits"] += 1
+        elif event == "/jax/compilation_cache/cache_misses":
+            cache["misses"] += 1
+
+    def on_duration(event, secs, **_):
+        if event == "/jax/core/compile/backend_compile_duration":
+            cache["compile_s"] += secs
+
+    monitoring.register_event_listener(on_event)
+    monitoring.register_event_duration_secs_listener(on_duration)
+
+    report = {"device": device}
+    phases = [("kernel", lambda: kernel_phase(report))]
+    if device["count"] >= 4:
+        phases.append(("p4", lambda: train_phase(4, report)))
+    phases.append(("p1", lambda: train_phase(1, report)))
+    failed = []
+    for name, phase in phases:
+        t0 = time.time()
+        try:
+            phase()
+            print(f"[smoke] phase {name}: passed in {time.time() - t0:.1f}s")
+        except (Exception, SystemExit):     # main() exits on config errors
+            failed.append(name)
+            traceback.print_exc()
+            print(f"[smoke] phase {name}: FAILED after "
+                  f"{time.time() - t0:.1f}s", file=sys.stderr)
+    cache["compile_s"] = round(cache["compile_s"], 1)
+    report["compile_cache"] = cache
+    if device["count"] < 4:
+        report["p4"] = f"not run: this host offers {device['count']} chip(s)"
+    print("[smoke] report " + json.dumps(report))
+    if failed:
+        fail(f"phase(s) failed: {failed}; no result")
+    shutil.rmtree(WORK, ignore_errors=True)
+    print(json.dumps({"ok": True, "device": device}))
+
+
+if __name__ == "__main__":
+    run()

@@ -2,7 +2,7 @@
 # Flagship Reddit recipe (reference scripts/reddit.sh): GraphSAGE 4x256,
 # P-partition BNS at rate 0.1, precompute, inductive. Requires the real
 # Reddit dataset (dgl) — use sbm_demo.sh for an offline smoke run.
-# TPU perf knobs (v5e-measured, BENCH_NOTES.md): append
+# TPU perf knobs (the recipe chip_smoke.py runs; see PERF.md): append
 #   --dtype bfloat16 --spmm auto --use-pallas --halo-wire int8
 # (auto picks the hybrid MXU-tile SpMM on clustered graphs; --block-tile
 #  256 / --spmm-gather int8 are the finer-tile / 1-byte-residual knobs).

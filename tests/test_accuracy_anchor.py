@@ -35,7 +35,8 @@ from bnsgcn_tpu.data.graph import reddit_like_graph, synthetic_graph
 from bnsgcn_tpu.data.partitioner import partition_graph
 from bnsgcn_tpu.ops.spmm import agg_sum
 from bnsgcn_tpu.parallel.halo import halo_apply, make_halo_plan, make_halo_spec
-from bnsgcn_tpu.parallel.mesh import make_parts_mesh, shard_map
+from jax import shard_map
+from bnsgcn_tpu.parallel.mesh import make_parts_mesh
 from bnsgcn_tpu.trainer import place_blocks, place_replicated
 from tools.anchor_harness import _biased_pair_sample, train_eval
 

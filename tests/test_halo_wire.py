@@ -8,7 +8,7 @@
     forward and backward, with fresh scales on the gradient hop;
   * wire_bytes tracks real skewed boundary sizes under 'shift'/'ragged' and
     the dtype compression factor, pinned to the hardware-probed 38%-of-padded
-    ratio on the logged skewed profile (hw_logs/hw_session_r4.log:399);
+    ratio on the skewed profile logged by the 2026-07-30 v5e probe;
   * `--halo-exchange auto` picks ragged on that profile, padded on balanced
     boundaries, and falls back per the documented hop-count tiebreak.
 
@@ -30,7 +30,8 @@ from bnsgcn_tpu.data.partitioner import partition_graph
 from bnsgcn_tpu.parallel.halo import (halo_apply, make_halo_plan,
                                       make_halo_spec, select_halo_strategy,
                                       wire_bytes)
-from bnsgcn_tpu.parallel.mesh import make_parts_mesh, shard_map
+from jax import shard_map
+from bnsgcn_tpu.parallel.mesh import make_parts_mesh
 
 
 def _skewed_graph():
@@ -111,7 +112,7 @@ def test_strategy_wire_matrix_matches_padded_native(skew8, strategy, wire):
 @pytest.mark.quickgate
 def test_wire_bytes_ragged_pins_hw_profile():
     """wire_bytes on the hardware-probed skewed profile (P=8, rate=0.1,
-    H=256 bf16 — hw_logs/hw_session_r4.log:399) must reproduce the logged
+    H=256 bf16 — the 2026-07-30 v5e probe) must reproduce the logged
     numbers: padded 20.5 MB, ragged exact 7.8 MB = 38% (<= 40%), and the
     auto selector must pick ragged there."""
     P_ = 8

@@ -5,10 +5,7 @@ import pytest
 
 from bnsgcn_tpu.data.graph import sbm_graph, synthetic_graph
 from bnsgcn_tpu.data.partitioner import edge_cut, random_partition
-from bnsgcn_tpu.native import native_available, native_partition
-
-pytestmark = pytest.mark.skipif(not native_available(),
-                                reason="no C++ toolchain to build native lib")
+from bnsgcn_tpu.native import native_partition
 
 
 @pytest.fixture(scope="module")

@@ -34,7 +34,8 @@ from bnsgcn_tpu.ops.ell import build_layouts, build_split_layouts, make_ell_spmm
 from bnsgcn_tpu.ops.spmm import frontier_mask
 from bnsgcn_tpu.parallel.halo import (halo_apply, halo_finish, halo_start,
                                       make_halo_plan, make_halo_spec)
-from bnsgcn_tpu.parallel.mesh import make_parts_mesh, shard_map
+from jax import shard_map
+from bnsgcn_tpu.parallel.mesh import make_parts_mesh
 from bnsgcn_tpu.trainer import (build_block_arrays, build_step_fns,
                                 init_training, place_blocks, place_replicated)
 

@@ -334,7 +334,13 @@ def test_e2e_servekill_failover_and_bitwise_rejoin(tmp_path, monkeypatch):
                                          f"{_dump(procs)}")
             try:
                 r = req({"op": "fleet"}, timeout_s=2.0)
-                if r.get("ok") and not r.get("missing_parts"):
+                # all four replicas up, not just one per part: the kill
+                # lands on p0.r0's third data request, and on a loaded host
+                # p0.r1 may still be starting then - no peer to fail over to
+                h = req({"op": "health"}, timeout_s=2.0)["health"]
+                if (r.get("ok") and not r.get("missing_parts")
+                        and all(h.get(f"p{p}.r{k}") == "up"
+                                for p in (0, 1) for k in (0, 1))):
                     break
             except Exception:
                 pass

@@ -10,6 +10,8 @@ min-over-lanes wait-stripping estimate.
 import os
 import sys
 
+import pytest
+
 sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "tools"))
 
@@ -81,6 +83,34 @@ def test_min_over_lanes_strips_waiter():
     assert est == 2 * 10
     _, mest, _, _ = program_cost(attr["exchange_only"], "exchange")
     assert mest == 2 * 5
+
+
+def test_refresh_programs_attribute_as_their_k1_twins():
+    """--halo-refresh K>1 (and the --tune K-anneal) launch train_step_full /
+    train_step_cached / exchange_only_refresh instead of the K=1 programs.
+    They are train steps and exchange sweeps: an exact-name match counted
+    no train step and the (fatal) trace window killed every K>1 run."""
+    from bnsgcn_tpu.utils.traceparse import step_comm_from_events
+    ev = []
+    for e in make_trace():
+        name = e.get("name", "")
+        if name == "PjitFunction(train_step)":
+            # first step a full refresh, second a cache hit
+            e = dict(e, name="PjitFunction(train_step_full)"
+                     if e["ts"] < 2000 else "jit_train_step_cached")
+        elif name == "PjitFunction(exchange_only)":
+            e = dict(e, name="PjitFunction(exchange_only_refresh)")
+        ev.append(e)
+    attr = attribute(ev)
+    assert attr["train_step"]["launches"] == 2
+    assert attr["exchange_only"]["launches"] == 2
+    assert attr["exchange_only"]["sweeps"] == 1
+    assert attr == attribute(make_trace())
+    # a program that merely ends in a known name is not one
+    assert attribute([_ev(1, 0, "PjitFunction(my_train_step)", 1.0, 1)])[
+        "train_step"]["launches"] == 0
+    ex_s, rd_s, steps = step_comm_from_events(ev, True)
+    assert steps == 2 and abs(ex_s - 10e-6) < 1e-9 and abs(rd_s - 7e-6) < 1e-9
 
 
 def test_host_lane_collectives_ignored():
@@ -229,15 +259,16 @@ def test_comm_by_axis_classifies_3_axis_groups():
     assert table["replicas x parts x feat"]["reduce"] == 9 + 4
 
 
-def test_step_comm_per_epoch_none_without_exchange_events(tmp_path):
+def test_step_comm_per_epoch_raises_without_exchange_events(tmp_path):
     """A trace window holding train_step launches but NO device exchange
     events (observed when the step compiles inside the window on XLA:CPU)
-    must report parse failure, not a fabricated 0.0 Comm column — run.py
-    then falls back to the [sampled] microbench (round-5 verify finding)."""
+    must raise a TraceError that names the cause, not fabricate a 0.0 Comm
+    column — unless the program exchanges nothing (1 part / grad-only),
+    where 0 s is the truth."""
     import gzip
     import json
 
-    from bnsgcn_tpu.utils.traceparse import step_comm_per_epoch
+    from bnsgcn_tpu.utils.traceparse import TraceError, step_comm_per_epoch
 
     def write_trace(events):
         d = tmp_path / "plugins" / "profile" / "run1"
@@ -245,20 +276,81 @@ def test_step_comm_per_epoch_none_without_exchange_events(tmp_path):
         with gzip.open(d / "host.trace.json.gz", "wt") as f:
             json.dump({"traceEvents": events}, f)
 
-    # launches but no collectives -> None
+    # launches but no collectives -> named error, or a true 0 s
     write_trace([_meta(1, 0, "python"), _meta(1, 10, "dev0"),
                  _ev(1, 0, "PjitFunction(train_step)", 1000.0, 300)])
-    assert step_comm_per_epoch(str(tmp_path)) is None
+    with pytest.raises(TraceError, match="no device exchange span"):
+        step_comm_per_epoch(str(tmp_path), True)
+    assert step_comm_per_epoch(str(tmp_path), False) == (0.0, 0.0, 1)
 
     # healthy window -> per-step seconds
     write_trace(make_trace())
-    parsed = step_comm_per_epoch(str(tmp_path))
-    assert parsed is not None
+    parsed = step_comm_per_epoch(str(tmp_path), True)
     ex_s, rd_s, steps = parsed
     assert steps == 2
     # min-over-lanes: 2 steps x last-arriver span 10 us -> 10us/step
     assert abs(ex_s - 10e-6) < 1e-9
     assert abs(rd_s - 7e-6) < 1e-9
 
-    # missing trace dir -> None, never a throw
-    assert step_comm_per_epoch(str(tmp_path / "nope")) is None
+    # missing trace dir -> named error
+    with pytest.raises(TraceError, match="wrote no"):
+        step_comm_per_epoch(str(tmp_path / "nope"), True)
+
+
+def test_obs_report_reparses_with_the_rule_the_run_used(tmp_path, capsys):
+    """The live run and tools/obs_report.py read the same trace by the same
+    rule: run.py records `exchanges` (False at 1 part / grad-only) in the obs
+    `trace` event and the report passes it to the parser. Without it the
+    report called a 1-part run's own trace 'lost' after the run had printed
+    [traced] 0.0000 for it."""
+    import gzip
+    import json
+
+    import obs_report
+    d = tmp_path / "prof" / "plugins" / "profile" / "run1"
+    d.mkdir(parents=True)
+    with gzip.open(d / "host.trace.json.gz", "wt") as f:
+        json.dump({"traceEvents": [
+            _meta(1, 0, "python"), _meta(1, 10, "dev0"),
+            _ev(1, 0, "PjitFunction(train_step)", 1000.0, 300)]}, f)
+
+    def report(exchanges):
+        log = tmp_path / f"obs_{exchanges}.jsonl"
+        log.write_text(json.dumps({
+            "kind": "trace", "epoch": 9, "comm_s": 0.0, "reduce_s": 0.0,
+            "exchanges": exchanges, "trace_dir": str(tmp_path / "prof")})
+            + "\n")
+        assert obs_report.main([str(log)]) == 0
+        return capsys.readouterr().out
+
+    assert "exchange 0.00 ms reduce 0.00 ms over 1 steps" in report(False)
+    assert "failed: 1 train_step launch(es)" in report(True)
+
+
+def test_step_comm_on_a_recorded_v5e_trace():
+    """The reduction runs on what a TPU really writes. tests/data/
+    v5e_p4_step_comm.trace.json.gz is a P=4 auto-trace window recorded on
+    four v5e chips (PR 22), cut down to launches + collective spans. The
+    chip names its all-to-all instructions `all_to_all.N` (underscores,
+    after the jax primitive) and its all-reduces `all-reduce.N`: a parser
+    that only knew the hyphenated opcode spelling found 4 train steps and
+    no exchange at all, and run.py printed [sampled] numbers for it."""
+    import gzip
+    import json
+
+    from bnsgcn_tpu.utils.traceparse import (attribute, comm_by_axis,
+                                             step_comm_from_events)
+    path = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data",
+                        "v5e_p4_step_comm.trace.json.gz")
+    with gzip.open(path, "rt") as f:
+        events = json.load(f)["traceEvents"]
+    attr = attribute(events)
+    assert attr["train_step"]["launches"] == 4
+    # 3 exchanging layers x (forward + transposed backward) per step, on
+    # each of the 4 device lanes; one fused gradient all-reduce family
+    lanes = attr["train_step"]["exchange"]
+    assert len(lanes) == 4 and {len(v) for v in lanes.values()} == {24}
+    assert {len(v) for v in attr["train_step"]["reduce"].values()} == {8}
+    ex_s, rd_s, steps = step_comm_from_events(events, True)
+    assert steps == 4 and 0 < rd_s < ex_s < 1e-3
+    assert set(comm_by_axis(events, 4)) == {"parts"}

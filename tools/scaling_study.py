@@ -57,7 +57,6 @@ def main():
 
     sys.path.insert(0, os.getcwd())
     from bench import _cached_graph
-    from bnsgcn_tpu.data.partitioner import partition_graph
     from bnsgcn_tpu.parallel.halo import make_halo_spec, wire_bytes
 
     n_nodes = max(int(232_965 * args.scale), 2000)
@@ -78,8 +77,6 @@ def main():
             from bnsgcn_tpu.native import native_partition
             pid = native_partition(g, P, obj="vol", seed=0,
                                    refine_passes=4, n_seeds=args.seeds)
-            if pid is None:
-                pid = partition_graph(g, P, method="random", seed=0)
         # boundary sizes n_b[p, j]
         src_o, dst_o = pid[g.src], pid[g.dst]
         cross = src_o != dst_o

@@ -8,8 +8,8 @@ every measured microsecond includes the socket round trip a production
 client would pay. Point --port/--addr at an already-running server to bench
 it instead.
 
-Reports, as driver-parsed JSON lines in bench.py's SERVE_METRICS vocabulary
-(so they land in future BENCH_*.json like the epoch-time metric):
+Reports, as JSON lines in the SERVE_METRICS vocabulary below (last line
+wins, like bench.py's epoch-time lines):
 
   serve_p50_ms / serve_p99_ms   per-request latency, per tier
                                 (A = table lookup, B = fresh L-hop
@@ -32,7 +32,11 @@ cross-check runs per backend against the router's aggregated `stats`, and
 a direct-at-the-backend tier-A pass measures the router's forwarding
 overhead (routed p50 / direct p50 — flagged when it exceeds 2x). --variant
 tags every emitted metric line (default: serve1 single-host, serve{N}p
-fleet) so bench.py can record both topologies side by side.
+fleet) so the two topologies are never compared as one.
+
+The serving tier is host numpy plus a one-shot table precompute: this tool
+is the serving bench's only entry point and runs wherever JAX_PLATFORMS
+points it. bench.py (training epoch time, TPU only) does not dispatch to it.
 
 Usage: python tools/serve_bench.py [--requests 400] [--concurrency 4]
            [--dataset synthetic] [--model graphsage] [--fleet 2]
@@ -53,17 +57,35 @@ import numpy as np
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 
-from bench import SERVE_METRICS, emit_serve_metric  # noqa: E402
-from bnsgcn_tpu.utils.platform import honor_platform_request  # noqa: E402
-
-honor_platform_request()
-
 import jax  # noqa: E402
 
 from bnsgcn_tpu import serve  # noqa: E402
 from bnsgcn_tpu.config import Config  # noqa: E402
 from bnsgcn_tpu.data.datasets import load_data  # noqa: E402
 from bnsgcn_tpu.models.gnn import init_params, spec_from_config  # noqa: E402
+
+
+# Metric vocabulary. Names are load-bearing: a rename silently orphans every
+# recorded line, so the emitter below refuses anything else.
+SERVE_METRICS = {
+    "serve_p50_ms": "ms",          # per-request latency median, per tier
+    "serve_p99_ms": "ms",          # per-request latency 99th pct, per tier
+    "serve_qps": "req/s/chip",     # sustained throughput per accelerator chip
+}
+
+
+def emit_serve_metric(name: str, value: float, tier: str | None = None,
+                      **extra):
+    """One JSON metric line on stdout (last line wins)."""
+    if name not in SERVE_METRICS:
+        raise ValueError(f"unknown serve metric {name!r} "
+                         f"(vocabulary: {sorted(SERVE_METRICS)})")
+    line = {"metric": name, "value": round(float(value), 4),
+            "unit": SERVE_METRICS[name]}
+    if tier is not None:
+        line["tier"] = tier
+    line.update(extra)
+    print(json.dumps(line), flush=True)
 
 
 def parse_args(argv=None):
@@ -443,7 +465,12 @@ def main(argv=None):
         return run_chaos(args, log)
     variant = args.variant or (f"serve{args.fleet}p" if args.fleet
                                else "serve1")
-    tags = {"variant": variant, "backends": args.fleet or 1}
+    dev = jax.devices()[0]
+    # every line says where it was taken: the unit reads req/s/chip on a CPU
+    # too, and only the stamp tells such a line from a chip number
+    tags = {"variant": variant, "backends": args.fleet or 1,
+            "platform": dev.platform, "device_kind": dev.device_kind,
+            "device_count": jax.device_count()}
     server = core = close_fleet = None
     owned: dict = {}
     if args.addr:
