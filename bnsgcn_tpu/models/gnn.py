@@ -36,6 +36,7 @@ import jax.numpy as jnp
 from bnsgcn_tpu.ops.spmm import agg_sum, segment_softmax
 from bnsgcn_tpu.config import Config
 from bnsgcn_tpu.parallel.feat import feat_shardable
+from bnsgcn_tpu.utils import traceparse as tp
 
 
 @dataclass(frozen=True)
@@ -152,7 +153,8 @@ def env_agg_exchange(env: "GraphEnv", i: int, h: jax.Array,
     frontier split so the collective overlaps interior compute."""
     if env.agg_exchange is not None:
         return env.agg_exchange(i, h, scale_out_norm)
-    h_ext, _ = env.exchange(i, h)
+    with jax.named_scope(tp.HALO_EXCHANGE):
+        h_ext, _ = env.exchange(i, h)
     if scale_out_norm:
         h_ext = (h_ext / env.out_norm[:, None]).astype(h_ext.dtype)
     return env_agg_sum(env, h_ext)
@@ -227,6 +229,7 @@ def init_params(key: jax.Array, spec: ModelSpec, dtype=jnp.float32):
 # building blocks
 # ----------------------------------------------------------------------------
 
+@jax.named_scope(tp.DROPOUT)
 def _dropout(h, rate, rng, training):
     if not training or rate <= 0.0 or rng is None:
         return h
@@ -235,6 +238,7 @@ def _dropout(h, rate, rng, training):
     return jnp.where(mask, h / keep, 0.0).astype(h.dtype)
 
 
+@jax.named_scope(tp.DROPOUT)
 def _dropout_heads(a, rate, rng, training, n_total, off):
     """Last-dim (head) dropout whose mask is drawn at the FULL width
     `n_total` and sliced at `off` — a feat-sharded GAT layer therefore
@@ -277,25 +281,31 @@ def _feat_layer(p, i, h, env: "GraphEnv", spec: "ModelSpec") -> jax.Array:
     is_graph = i < spec.n_graph_layers
     if not is_graph or (env.training and spec.use_pp and i == 0):
         # pure dense matmul: the linear tail and the precomputed layer 0
-        part = _feat_slice(env, h) @ p["w"]
-        return _feat_psum(env, part) + p["b"]
+        with jax.named_scope(tp.LINEAR):
+            part = _feat_slice(env, h) @ p["w"]
+            return _feat_psum(env, part) + p["b"]
     if spec.model == "gcn":
         s = env_agg_exchange(env, i, _feat_slice(env, h), scale_out_norm=True)
-        part = (s / env.in_norm[:, None]).astype(h.dtype) @ p["w"]
-        return _feat_psum(env, part) + p["b"]
+        with jax.named_scope(tp.LINEAR):
+            part = (s / env.in_norm[:, None]).astype(h.dtype) @ p["w"]
+            return _feat_psum(env, part) + p["b"]
     if (not env.training) and spec.use_pp and i == 0:
         # eval pp layer 0: cat(feat, mean) @ W — the concat consumes the
         # full-width mean, so only the linear shards (full-rate eval runs
         # once per log_every; the training exchange is what the axis thins)
         ah = env_agg_exchange(env, i, h) / env.in_norm[:, None]
-        part = _feat_slice(env, jnp.concatenate([h[:env.n_dst], ah], 1)) @ p["w"]
-        return _feat_psum(env, part) + p["b"]
+        with jax.named_scope(tp.LINEAR):
+            part = _feat_slice(
+                env, jnp.concatenate([h[:env.n_dst], ah], 1)) @ p["w"]
+            return _feat_psum(env, part) + p["b"]
     hs = _feat_slice(env, h)
     ah = (env_agg_exchange(env, i, hs) / env.in_norm[:, None]).astype(h.dtype)
-    part = hs[:env.n_dst] @ p["linear1"]["w"] + ah @ p["linear2"]["w"]
-    return _feat_psum(env, part) + p["linear1"]["b"] + p["linear2"]["b"]
+    with jax.named_scope(tp.LINEAR):
+        part = hs[:env.n_dst] @ p["linear1"]["w"] + ah @ p["linear2"]["w"]
+        return _feat_psum(env, part) + p["linear1"]["b"] + p["linear2"]["b"]
 
 
+@jax.named_scope(tp.NORM)
 def _layer_norm(p, h, eps=1e-5):
     # stats in f32 (bf16 activations would lose the variance), output in h.dtype
     hf = h.astype(jnp.float32)
@@ -305,6 +315,7 @@ def _layer_norm(p, h, eps=1e-5):
     return out.astype(h.dtype)
 
 
+@jax.named_scope(tp.NORM)
 def _sync_batch_norm(p, st, h, env: GraphEnv, whole_size, momentum=0.1, eps=1e-5):
     """module/sync_bn.py:10-28 — moments over all real rows of all parts,
     normalized by whole_size (= global n_train in the reference trainer)."""
@@ -349,6 +360,7 @@ def _sync_batch_norm(p, st, h, env: GraphEnv, whole_size, momentum=0.1, eps=1e-5
     return x_hat * p["scale"] + p["bias"], new_st
 
 
+@jax.named_scope(tp.LINEAR)
 def _linear(p, h):
     return h @ p["w"] + p["b"]
 
@@ -371,6 +383,7 @@ def _sage_layer(p, i, h, env: GraphEnv):
     return _linear(p["linear1"], h[:env.n_dst]) + _linear(p["linear2"], ah)
 
 
+@jax.named_scope(tp.ATTENTION)
 def _gat_layer(p, h_dst, h_ext, presence, env: GraphEnv, heads, out_feats,
                rng, dropout, training, negative_slope=0.2,
                total_heads=None, head_off=None):
@@ -455,13 +468,14 @@ def apply_model(params, state, spec: ModelSpec, feat, env: GraphEnv,
             hidden = h
         body = partial(_layer_forward, i=i, params=params, state=state,
                        spec=spec, env=env, rng=rngs[i])
-        if env.remat and env.training:
-            # rematerialize per layer: activations (incl. the halo-extended
-            # block) are recomputed in the backward instead of stored —
-            # HBM-for-FLOPs/comm, jax.checkpoint per TPU guidance
-            h, st_i = jax.checkpoint(body)(h)
-        else:
-            h, st_i = body(h)
+        with jax.named_scope(tp.layer_scope(i)):
+            if env.remat and env.training:
+                # rematerialize per layer: activations (incl. the halo-
+                # extended block) are recomputed in the backward instead of
+                # stored — HBM-for-FLOPs/comm, jax.checkpoint per TPU guidance
+                h, st_i = jax.checkpoint(body)(h)
+            else:
+                h, st_i = body(h)
         if st_i is not None:
             new_state[f"norm_{i}"] = st_i
 
@@ -518,12 +532,14 @@ def _layer_forward(h, *, i, params, state, spec: ModelSpec, env: GraphEnv, rng):
                     h_ext, presence = env.gat_feat0
                     h_d = h[:env.n_dst] if h.shape[0] > env.n_dst else h
                 else:
-                    h_ext, presence = env.exchange(i, h)
+                    with jax.named_scope(tp.HALO_EXCHANGE):
+                        h_ext, presence = env.exchange(i, h)
                     h_d = h
             else:
                 # eval: exchange is the identity on a single device and a
                 # full-rate halo exchange under mesh-distributed eval
-                h_ext, presence = env.exchange(i, h)
+                with jax.named_scope(tp.HALO_EXCHANGE):
+                    h_ext, presence = env.exchange(i, h)
                 h_d = h
             h = _gat_layer(p, h_d, h_ext, presence, env, heads_l, out_feats,
                            rng, spec.dropout, env.training,

@@ -46,7 +46,8 @@ from typing import Optional
 __all__ = [
     "Counter", "Gauge", "Histogram", "Registry", "EventLog", "Obs",
     "make_obs", "postmortem_dir", "write_postmortem", "load_events",
-    "rank_log_path", "EVENT_KINDS",
+    "rank_log_path", "EVENT_KINDS", "PHASES", "SETUP_SPANS", "FIRST_CALL",
+    "SPAN_PREFIX", "EPOCH_MARK", "Span", "NO_SPAN", "span",
 ]
 
 
@@ -59,6 +60,9 @@ EVENT_KINDS = (
     # training lifecycle (run.py)
     "run_header", "epoch", "epoch_ranks", "eval", "trace", "overlap",
     "halo_refresh", "reorder", "layout_build", "tune_decision", "run_end",
+    # host spans (`span` below): one event per set-up phase of run_training
+    # and per `first_call:<program>`: name, parent, t0 (wall clock), dur_s
+    "span",
     # resilience (resilience.py: injections, rollback consensus, exits;
     # 'resize' = the elastic shrink/grow verdict: old/new world, part->slot
     # map, trigger, resize nonce)
@@ -88,6 +92,26 @@ EVENT_KINDS = (
     # perf`)
     "perf_audit",
 )
+
+
+# Host phases of one epoch of run.run_training's loop, in loop order; each is
+# one `span`. `norm_probe` is the child of `guard`. The `epoch` event's
+# `boundary` is keyed by these names.
+PHASES = ("pre", "dispatch", "wait", "loss_fetch", "guard", "norm_probe",
+          "agree", "trace_io", "comm_bench", "obs_emit", "tune", "log",
+          "checkpoint", "eval")
+# Set-up phases of run_training, each emitted as one `span` event under the
+# root SETUP_SPANS[0]; `first_call:<program>` events join them from the loop.
+SETUP_SPANS = ("run_training_setup", "load_graph", "load_artifacts",
+               "prepare_partition", "layout_cache_load", "build_step_fns",
+               "place", "init_training", "resume", "pp_precompute",
+               "comm_bench_compile")
+FIRST_CALL = "first_call:"
+# every span is also a jax.profiler.TraceAnnotation under this prefix, so a
+# profiler window carries it on the Python thread's lane, on the device
+# lanes' clock; the epoch body runs under StepTraceAnnotation(EPOCH_MARK)
+SPAN_PREFIX = "bns:"
+EPOCH_MARK = SPAN_PREFIX + "epoch"
 
 
 # ----------------------------------------------------------------------------
@@ -388,6 +412,15 @@ class Obs:
         self.registry = Registry()
         self.log_path = path or ""
         self.events = EventLog(path, rank=rank) if path else None
+        # host spans: the main thread's open spans, the seconds each name
+        # took itself (children taken out) since the last take_phases(),
+        # the open epoch mark, the last rusage reading and the per-program
+        # call times `first_call:` spans are made from
+        self._spans: list = []
+        self.phase_s: dict = {}
+        self._epoch_mark = None
+        self._rusage = None
+        self._calls: dict = {}
 
     def emit(self, kind: str, **fields):
         if self.events is not None:
@@ -402,9 +435,151 @@ class Obs:
     def snapshot(self) -> dict:
         return self.registry.snapshot()
 
+    def take_phases(self) -> dict:
+        """{span name: seconds inside it, its children taken out} since the
+        last call; starts the next account."""
+        out = {k: round(v, 6) for k, v in self.phase_s.items()}
+        self.phase_s = {}
+        return out
+
+    def epoch_begin(self, epoch: int):
+        """Closes the previous epoch's StepTraceAnnotation and opens this
+        one's: the mark spans the whole loop body, `continue` paths too."""
+        self.epoch_end()
+        from jax.profiler import StepTraceAnnotation
+        self._epoch_mark = StepTraceAnnotation(EPOCH_MARK, step_num=int(epoch))
+        self._epoch_mark.__enter__()
+
+    def epoch_end(self):
+        if self._epoch_mark is not None:
+            self._epoch_mark.__exit__(None, None, None)
+            self._epoch_mark = None
+
+    def rusage_delta(self) -> dict:
+        """This process's CPU seconds (user + system), involuntary context
+        switches and major page faults since the last call: one getrusage.
+        A long `wait_s` with `nivcsw` up and `cpu_s` flat is a descheduled
+        host; with `cpu_s` up by as much it is the process's own threads."""
+        import resource
+        ru = resource.getrusage(resource.RUSAGE_SELF)
+        now = (ru.ru_utime + ru.ru_stime, ru.ru_nivcsw, ru.ru_majflt)
+        was = self._rusage or now
+        self._rusage = now
+        return {"cpu_s": round(now[0] - was[0], 6),
+                "nivcsw": int(now[1] - was[1]),
+                "majflt": int(now[2] - was[2])}
+
+    def note_call(self, program: str, seconds: float):
+        """One call of a jitted program of the loop took `seconds` (dispatch
+        to result). After the fourth, its `first_call:<program>` span says
+        what compiling or loading it cost: the first call less the median of
+        the next three."""
+        rec = self._calls.setdefault(program, [time.time() - seconds])
+        if rec is not None:
+            rec.append(float(seconds))
+            if len(rec) == 5:
+                self._emit_first_call(program)
+
+    def _emit_first_call(self, program: str):
+        t0, first, *later = self._calls[program]
+        self._calls[program] = None
+        if later:
+            later.sort()
+            self.emit("span", name=FIRST_CALL + program,
+                      parent=SETUP_SPANS[0], t0=round(t0, 6),
+                      dur_s=round(first - later[len(later) // 2], 6),
+                      calls=1 + len(later))
+
+    def flush_first_calls(self):
+        """At the loop's end: a run too short for four calls of a program
+        still says what its first call cost, against the calls it made."""
+        for program, rec in list(self._calls.items()):
+            if rec is not None:
+                self._emit_first_call(program)
+
     def close(self):
+        self.epoch_end()
         if self.events is not None:
             self.events.close()
+
+
+class _NullSpan:
+    """What `span` hands out under --obs off: enters, exits, begins and ends
+    nothing, and is one object for the life of the process."""
+    __slots__ = ()
+    dur_s = 0.0
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+    begin = __enter__
+
+    def end(self):
+        pass
+
+
+NO_SPAN = _NullSpan()
+
+
+class Span:
+    """One named stretch of host time on the main thread. While it is open a
+    jax.profiler.TraceAnnotation(SPAN_PREFIX + name) is too, so a profiler
+    window shows it on the device lanes' clock; when it closes, its seconds
+    (less its children's) join `obs.phase_s[name]`, and `emit=True` writes a
+    `span` event. `with span(...)`, or begin()/end() around code that cannot
+    be indented under one block."""
+
+    __slots__ = ("obs", "name", "emit", "parent", "t0_wall", "t0", "dur_s",
+                 "_mark", "_child_s")
+
+    def __init__(self, obs: Obs, name: str, emit: bool = False,
+                 parent: Optional[str] = None):
+        self.obs, self.name, self.emit, self.parent = obs, name, emit, parent
+        self.dur_s = 0.0
+        self._child_s = 0.0
+
+    def begin(self):
+        from jax.profiler import TraceAnnotation
+        stack = self.obs._spans
+        if self.parent is None and stack:
+            self.parent = stack[-1].name
+        stack.append(self)
+        self.t0_wall = time.time()
+        self._mark = TraceAnnotation(SPAN_PREFIX + self.name)
+        self._mark.__enter__()
+        self.t0 = time.perf_counter()
+        return self
+
+    def end(self):
+        self.dur_s = time.perf_counter() - self.t0
+        self._mark.__exit__(None, None, None)
+        stack = self.obs._spans
+        while stack and stack.pop() is not self:
+            pass                    # a span left open by a raise inside it
+        if stack:
+            stack[-1]._child_s += self.dur_s
+        acc = self.obs.phase_s
+        acc[self.name] = acc.get(self.name, 0.0) + self.dur_s - self._child_s
+        if self.emit:
+            self.obs.emit("span", name=self.name, parent=self.parent,
+                          t0=round(self.t0_wall, 6),
+                          dur_s=round(self.dur_s, 6))
+
+    __enter__ = begin
+
+    def __exit__(self, *exc):
+        self.end()
+        return False
+
+
+def span(obs: Optional[Obs], name: str, emit: bool = False,
+         parent: Optional[str] = None):
+    """The one host-span primitive (see Span). `obs=None` (--obs off) gets
+    the shared null span: nothing is constructed, timed or annotated."""
+    return NO_SPAN if obs is None else Span(obs, name, emit, parent)
 
 
 def rank_log_path(path: str, rank: int) -> str:

@@ -43,6 +43,7 @@ import numpy as np
 
 from bnsgcn_tpu.ops.ell import (ELL_SPLIT_CAP, GeoAccum, build_layouts,
                                 layout_fastpath, make_ell_spmm, run_parallel)
+from bnsgcn_tpu.utils import traceparse as tp
 
 TR = 512          # default dst rows per dense tile (square: transposes keep
 TC = 512          # shape, and per-edge slab/output overhead beats narrow
@@ -698,6 +699,7 @@ def make_block_spmm(fwd: BlockSpec, bwd: BlockSpec, ell_pair,
         return {k[len("res_"):]: v for k, v in arrays.items()
                 if k.startswith("res_")}
 
+    @jax.named_scope(tp.AGG_TILES)
     def _dense(spec_d, arrays, tiles_key, rowb_key, colb_key, perm_src_key,
                perm_out_key, h):
         if dense_path(spec_d, use_pallas, dense_dtype) == "pallas":

@@ -34,6 +34,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from bnsgcn_tpu.utils import traceparse as tp
+
 
 def build_workers(n_tasks: int, cap: int = 8) -> int:
     """Host-parallelism width for offline layout builds (ROADMAP open item:
@@ -542,6 +544,7 @@ def ell_combine(spec: EllSpec, outs, perm, chunk_pos=None, chunk_seg=None):
     return full[perm]
 
 
+@jax.named_scope(tp.AGG_RESIDUAL)
 def _ell_apply(spec: EllSpec, idx_list, perm, h, use_pallas: bool = False,
                chunk_pos=None, chunk_seg=None, gather_dtype: str = "native",
                accum: str = "auto"):

@@ -37,6 +37,7 @@ import numpy as np
 
 from bnsgcn_tpu.ops.ell import (ELL_SPLIT_CAP, EllSpec, build_ell_numpy,
                                 compute_geometry, ell_combine)
+from bnsgcn_tpu.utils import traceparse as tp
 
 
 @dataclass(frozen=True)
@@ -238,6 +239,7 @@ def _fwd_buckets(spec, arrays, zp, elp, erp, pres, drop, training,
     return outs, ms, ds
 
 
+@jax.named_scope(tp.ATTENTION)
 def _gat_fwd_impl(spec, arrays, z, el, er, presence, attn_rng, head_off,
                   attn_dropout, training, negative_slope):
     heads, fdim = z.shape[1], z.shape[2]
@@ -285,6 +287,7 @@ def _gat_fwd_rule(spec, arrays, z, el, er, presence, attn_rng, head_off,
     return out, (arrays, z, el, er, presence, head_off, m_v, denom_v, seeds)
 
 
+@jax.named_scope(tp.ATTENTION)
 def _gat_bwd_rule(spec, attn_dropout, training, negative_slope, res, g):
     arrays, z, el, er, presence, head_off, m_v, denom_v, seeds = res
     heads = z.shape[1]

@@ -18,6 +18,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from bnsgcn_tpu.utils import traceparse as tp
+
 
 # ----------------------------------------------------------------------------
 # interior / frontier row split (offline numpy) — the --overlap split
@@ -139,6 +141,7 @@ def split_coo(src_all: np.ndarray, dst_all: np.ndarray, n_dst: int
     return out
 
 
+@jax.named_scope(tp.AGG_COO)
 def gather_scatter_sum(h_src: jax.Array, src: jax.Array, dst: jax.Array,
                        n_dst: int, edge_chunk: int = 0) -> jax.Array:
     """sum_{e:(src_e -> dst_e)} h_src[src_e]  ->  [n_dst, H].

@@ -25,6 +25,7 @@ import tempfile
 import time
 from concurrent.futures import ThreadPoolExecutor
 from dataclasses import dataclass, field
+from functools import partial
 from typing import Any, Optional
 
 import jax
@@ -55,7 +56,7 @@ from bnsgcn_tpu.trainer import (LAST_BUILD_TIMINGS, build_block_arrays,
                                 place_blocks_local, place_replicated,
                                 warm_start_state)
 from bnsgcn_tpu.utils import traceparse
-from bnsgcn_tpu.utils.timers import EpochTimer, estimate_static_hbm, format_memory_stats
+from bnsgcn_tpu.utils.timers import EpochTimer, format_memory_stats
 
 
 def artifacts_dir(cfg: Config) -> str:
@@ -112,6 +113,11 @@ def prepare_partition(cfg: Config, g: Optional[Graph] = None,
 # best-params recovery contract: consolidated in checkpoint.py (PR 7) so the
 # serving loader shares the exact same selection/validation entry points
 _final_best_payload = ckpt.final_best_payload
+
+
+# the jitted program behind each strict-exec step variant (step_variants)
+STEP_PROGRAMS = {"step": "train_step", "full": "train_step_full",
+                 "cached": "train_step_cached"}
 
 
 def step_variants(fns) -> tuple:
@@ -229,6 +235,9 @@ def run_training(cfg: Config, g: Optional[Graph] = None,
     # metrics registry. None under --obs off — every emit below is guarded,
     # so off constructs nothing and stays bit-identical (pinned). ----
     obs = obs_mod.make_obs(cfg, rank=coord_rank, log=log)
+    span = partial(obs_mod.span, obs)           # loop phases (obs.PHASES)
+    setup_span = partial(span, emit=True)       # set-up: one `span` event each
+    setup_root = setup_span(obs_mod.SETUP_SPANS[0]).begin()
 
     # ---- data + eval graphs (train.py:313-319) ----
     # multi-host: only rank 0 ever needs the full undistributed graph (host
@@ -242,7 +251,8 @@ def run_training(cfg: Config, g: Optional[Graph] = None,
                        and (is_rank0 or not multi_host))
     need_graph_partition = art is None and not (multi_host or cfg.skip_partition)
     if g is None and (need_graph_eval or need_graph_partition):
-        g, _, _ = load_data(cfg)
+        with setup_span("load_graph"):
+            g, _, _ = load_data(cfg)
     if cfg.eval and g is not None:
         if cfg.inductive:
             _, val_g, test_g = inductive_split(g)
@@ -272,6 +282,9 @@ def run_training(cfg: Config, g: Optional[Graph] = None,
                 f"(load_artifacts(parts=local_part_ids(mesh))), got "
                 f"{art.feat.shape[0]} part rows")
     if art is None:
+        art_span = setup_span("load_artifacts" if multi_host
+                              or cfg.skip_partition
+                              else "prepare_partition").begin()
         if multi_host:
             # each process loads only the parts whose mesh slots it hosts
             # (main.py already partitioned on rank 0 behind a barrier)
@@ -302,6 +315,7 @@ def run_training(cfg: Config, g: Optional[Graph] = None,
                 art = prepare_partition(cfg, train_g)
         else:
             art = prepare_partition(cfg, train_g)
+        art_span.end()
     cfg = cfg.replace(n_feat=art.n_feat, n_class=art.n_class, n_train=art.n_train)
     if (multi_host and cfg.spmm in ("ell", "auto")
             and art.ell_geometry is None):
@@ -399,28 +413,31 @@ def run_training(cfg: Config, g: Optional[Graph] = None,
         if cfg.overlap == "split":
             keys |= {ell_layout_key(cfg), hybrid_layout_key(cfg)}
         layout_cache, lc_loaded = {}, {}
-        for key in sorted(keys):
-            obj = try_load(_lc_path(key), log)
-            if obj is not None:
-                layout_cache[key] = obj
-                lc_loaded[key] = id(obj)
+        with setup_span("layout_cache_load"):
+            for key in sorted(keys):
+                obj = try_load(_lc_path(key), log)
+                if obj is not None:
+                    layout_cache[key] = obj
+                    lc_loaded[key] = id(obj)
     elif cfg.tune != "off":
         # no disk cache, but the --tune controller may rebuild the step fns
         # mid-run: an in-memory layout cache makes those rebuilds hit the
         # already-built SpMM layouts (the layout keys do not depend on any
         # tuned lever), so a retune never pays the layout build twice
         layout_cache, lc_loaded = {}, {}
-    fns, hspec, tables, tables_full = build_step_fns(cfg, spec, art, mesh,
-                                                     layout_cache=layout_cache)
+    with setup_span("build_step_fns") as build_span:
+        fns, hspec, tables, tables_full = build_step_fns(
+            cfg, spec, art, mesh, layout_cache=layout_cache)
     if obs is not None:
         for _st in LAST_BUILD_TIMINGS:
-            obs.emit("layout_build", **_st)
+            obs.emit("layout_build", parent=build_span.name, **_st)
     if cfg.cache_dir and layout_cache is not None:
         for key, obj in layout_cache.items():
             # new or repaired-in-place entries (id changed) get persisted
             if lc_loaded.get(key) != id(obj):
                 atomic_dump(obj, _lc_path(key))
                 log(f"  layout cache: wrote {_lc_path(key)}")
+    place_span = setup_span("place").begin()
     np_dtype = np.float32  # norms/feat host dtype; bf16 cast happens on device
     blk_np = build_block_arrays(art, spec.model, dtype=np_dtype)
     blk_np.update(fns.extra_blk)        # ELL SpMM layouts, if enabled
@@ -433,10 +450,16 @@ def run_training(cfg: Config, g: Optional[Graph] = None,
     tables_full_d = place_replicated(tables_full, mesh)
     tables_refresh_d = (place_replicated(fns.tables_refresh, mesh)
                         if fns.tables_refresh is not None else None)
+    place_span.end()
     if spec.use_pp:
-        out = fns.precompute(blk, tables_full_d)
-        if cfg.dtype == "bfloat16":
-            out = out.astype(jnp.bfloat16)
+        # like `place`, the host's side of it: the transfers and the
+        # precompute run on while the host goes on (waiting here cost the
+        # flagship 3 s of set-up, PR 27); the first step's wait holds what
+        # is left of them
+        with setup_span("pp_precompute"):
+            out = fns.precompute(blk, tables_full_d)
+            if cfg.dtype == "bfloat16":
+                out = out.astype(jnp.bfloat16)
         if spec.model == "gat":
             blk["feat0_ext"] = out
         else:
@@ -544,6 +567,7 @@ def run_training(cfg: Config, g: Optional[Graph] = None,
             wire_mb_per_exchange=round(halo_wire_mb, 4),
             wire_mb_steady=round(steady_wire_mb, 4),
             halo_refresh=int(fns.halo_refresh), halo_mode=fns.halo_mode,
+            spmm=fns.spmm_counts,
             partition={"pad_inner": int(art.pad_inner),
                        "pad_boundary": int(art.pad_boundary),
                        "pad_send": int(hspec.pad_send),
@@ -664,7 +688,9 @@ def run_training(cfg: Config, g: Optional[Graph] = None,
     if cfg.elastic == "on" and coord_rank == 0:
         coordinator.publish_boot({"seed": seed})
     dtype = jnp.bfloat16 if cfg.dtype == "bfloat16" else jnp.float32
-    params, state, opt_state = init_training(cfg, spec, mesh, seed=seed, dtype=dtype)
+    with setup_span("init_training"):
+        params, state, opt_state = init_training(cfg, spec, mesh, seed=seed,
+                                                 dtype=dtype)
     # every resume/rollback below restores HOST trees back onto the mesh;
     # feat-sharded meshes re-place them under the captured template
     # shardings (weights + Adam moments sharded over 'feat' — checkpoints
@@ -699,6 +725,8 @@ def run_training(cfg: Config, g: Optional[Graph] = None,
                         # single-process; a multi-rank schedule run
                         # reconstructs the same history from the schedule
                         # text, which every rank already has)
+    resume_span = (setup_span("resume") if cfg.resume
+                   else obs_mod.NO_SPAN).begin()
     if cfg.resume and coordinator is not None and not joiner:
         # ---- rank-consistent recovery: rank 0 WALKS the chain, everyone
         # else loads exactly rank 0's choice. Two ranks walking
@@ -889,6 +917,7 @@ def run_training(cfg: Config, g: Optional[Graph] = None,
                 best_params = ckpt.restore_into(fp, jax.device_get(params))[0]
             elif best_acc > 0:
                 best_acc = 0.0      # no matching best params: restart tracking
+    resume_span.end()
 
     if cfg.warm_start:
         # continual-cycle fine-tune entry: params + BN state come from the
@@ -1015,8 +1044,9 @@ def run_training(cfg: Config, g: Optional[Graph] = None,
 
     # compile the comm microbenches outside the timed region
     epoch = 0
-    for w in set(exch_widths):
-        _comm_bench(w).block_until_ready()
+    with setup_span("comm_bench_compile"):
+        for w in set(exch_widths):
+            _comm_bench(w).block_until_ready()
 
     # profiler window (SURVEY §5.1 upgrade: the reference's wall-clock comm
     # spans are meaningless under XLA; named traces are the TPU equivalent),
@@ -1253,8 +1283,20 @@ def run_training(cfg: Config, g: Optional[Graph] = None,
     # to keep a fast CPU run alive long enough for a relaunched rank to pay
     # its startup cost and rejoin; 0 (default) sleeps nothing.
     epoch_throttle = float(os.environ.get("BNSGCN_EPOCH_THROTTLE_S", 0) or 0)
+    setup_root.end()
+    # host account of the loop (obs on): the wall between one epoch's loss
+    # ready and the next one's dispatch is `boundary_s`, its parts by phase
+    # are `boundary`; the process counters are deltas over the epoch
+    t_ready = None
+    trace_start_wall = None
+    if obs is not None:
+        obs.take_phases()
+        obs.rusage_delta()
     try:
         while epoch < cfg.n_epochs:
+            if obs is not None:
+                obs.epoch_begin(epoch)
+            pre_span = span("pre").begin()
             if epoch_throttle > 0:
                 time.sleep(epoch_throttle)
             if resil is not None:
@@ -1300,9 +1342,18 @@ def run_training(cfg: Config, g: Optional[Graph] = None,
             # DELAY the one-shot comm-trace start, never cancel it
             if (trace_dir and prof_start <= epoch < prof_stop
                     and not tracing and not trace_done and not usr1_tracing):
+                trace_start_wall = time.time()
                 jax.profiler.start_trace(trace_dir)
                 tracing = True
+            pre_span.end()
+            # step_s = dispatch_s + wait_s: `dispatch` holds the epoch_dev
+            # upload and the step call returning, `wait` the blocking wait
+            # for the loss
             t0 = time.perf_counter()
+            if obs is not None:
+                boundary = obs.take_phases()
+                boundary_s = None if t_ready is None else t0 - t_ready
+            dispatch_span = span("dispatch").begin()
             # --halo-refresh K: an invalidated cache (run start, resume,
             # rollback) forces one full-refresh epoch at peak wire cost;
             # every other epoch runs the ~1/K partial exchange against
@@ -1342,12 +1393,20 @@ def run_training(cfg: Config, g: Optional[Graph] = None,
                     params, state, opt_state, loss = fns.train_step(
                         params, state, opt_state, epoch_dev, blk, tables,
                         sample_key, drop_key)
-                loss.block_until_ready()
-            dt = time.perf_counter() - t0
+                dispatch_span.end()
+                t_dispatched = time.perf_counter()
+                with span("wait"):
+                    loss.block_until_ready()
+            t_ready = time.perf_counter()
+            dt = t_ready - t0
+            if obs is not None:
+                obs.phase_s.clear()     # dispatch, wait: reported as fields
+                obs.note_call(STEP_PROGRAMS[variant], dt)
             # identical float either way; under strict the fetch is the
             # audited explicit path (counted in the end-of-run summary)
-            loss_f = (float(strict.fetch(loss)) if strict is not None
-                      else float(loss))
+            with span("loss_fetch"):
+                loss_f = (float(strict.fetch(loss)) if strict is not None
+                          else float(loss))
             usr1_in_step = usr1_tracing     # profiler overhead rides dt
             if use_refresh and refresh_full:
                 # lifecycle marker: this epoch rebuilt the halo cache at peak
@@ -1369,7 +1428,10 @@ def run_training(cfg: Config, g: Optional[Graph] = None,
                                 # record — never an extra device op
             if (resil is not None and not bad
                     and (epoch + 1) % cfg.log_every == 0):
-                pnorm = float(param_global_norm(params))
+                with span("guard"), span("norm_probe") as probe_span:
+                    pnorm = float(param_global_norm(params))
+                if obs is not None:
+                    obs.note_call("param_global_norm", probe_span.dur_s)
                 bad = not math.isfinite(pnorm)
             if resil is not None and resil.coord is not None:
                 # ---- multi-host agreed verdict: every rank contributes
@@ -1386,9 +1448,10 @@ def run_training(cfg: Config, g: Optional[Graph] = None,
                 summary = ({"loss": round(loss_f, 6),
                             "step_ms": round(dt * 1e3, 3)}
                            if obs is not None else None)
-                decision = resil.agree_step(epoch, local, loss_f,
-                                            summary=summary,
-                                            final=epoch + 1 >= cfg.n_epochs)
+                with span("agree"):
+                    decision = resil.agree_step(
+                        epoch, local, loss_f, summary=summary,
+                        final=epoch + 1 >= cfg.n_epochs)
                 act = decision["decision"]
                 if act == "abort":
                     resil.raise_abort(decision)
@@ -1534,6 +1597,7 @@ def run_training(cfg: Config, g: Optional[Graph] = None,
                 continue
 
             if tracing and epoch >= prof_stop:
+                trace_span = span("trace_io").begin()
                 jax.profiler.stop_trace()
                 tracing = False
                 trace_done = True
@@ -1560,7 +1624,8 @@ def run_training(cfg: Config, g: Optional[Graph] = None,
                              comm_s=round(comm_traced, 6),
                              reduce_s=round(reduce_traced, 6),
                              exchanges=exchanges,
-                             trace_dir=cfg.profile_dir or None)
+                             trace_dir=cfg.profile_dir or None,
+                             start_wall=round(trace_start_wall, 6))
                 # drop the microbench samples recorded so far so the
                 # printed means are purely the traced in-step numbers;
                 # seed one sample immediately — the window-closing epoch
@@ -1599,6 +1664,7 @@ def run_training(cfg: Config, g: Optional[Graph] = None,
                             "gives the full report)")
                 if auto_trace_dir:
                     shutil.rmtree(auto_trace_dir, ignore_errors=True)
+                trace_span.end()
 
             # ---- SIGUSR1 bounded window closes here; training continues ----
             if usr1_tracing and epoch >= usr1_stop:
@@ -1627,10 +1693,11 @@ def run_training(cfg: Config, g: Optional[Graph] = None,
                 # geometry — the steady-state cost, matching what all but
                 # the 1-in-K full-refresh epochs put on the wire
                 comm_t = 0.0
-                for w in exch_widths:
-                    t1 = time.perf_counter()
-                    _comm_bench(w).block_until_ready()
-                    comm_t += (time.perf_counter() - t1) * 2
+                with span("comm_bench"):
+                    for w in exch_widths:
+                        t1 = time.perf_counter()
+                        _comm_bench(w).block_until_ready()
+                        comm_t += (time.perf_counter() - t1) * 2
             # epochs inside the trace window carry profiler-collection
             # overhead in dt — exclude them from the reported means like
             # warmup epochs (same rule as bench.py, whose traced runs are
@@ -1659,18 +1726,29 @@ def run_training(cfg: Config, g: Optional[Graph] = None,
                 # samples (the snapshot rides post-mortem dumps). Same
                 # exclusions as timer.record — compile/warmup and profiled
                 # epochs must not report as p99 step time
-                if clean_step and epoch >= timer.warmup:
-                    obs.registry.histogram("train/step_s").observe(dt)
-                rec = {"epoch": epoch, "loss": round(loss_f, 6),
-                       "step_s": round(dt, 6),
-                       "wire_mb": round(epoch_wire_mb, 4)}
-                if pnorm is not None:
-                    rec["param_norm"] = round(pnorm, 6)
-                if comm_t:
-                    rec["comm_s"] = round(comm_t, 6)
-                    rec["comm_tag"] = ("traced" if comm_traced is not None
-                                       else "sampled")
-                obs.emit("epoch", **rec)
+                with span("obs_emit"):
+                    if clean_step and epoch >= timer.warmup:
+                        obs.registry.histogram("train/step_s").observe(dt)
+                    rec = {"epoch": epoch, "loss": round(loss_f, 6),
+                           "step_s": round(dt, 6),
+                           "wire_mb": round(epoch_wire_mb, 4)}
+                    if pnorm is not None:
+                        rec["param_norm"] = round(pnorm, 6)
+                    if comm_t:
+                        rec["comm_s"] = round(comm_t, 6)
+                        rec["comm_tag"] = ("traced" if comm_traced is not None
+                                           else "sampled")
+                    # where the host's time went: the step's two halves, the
+                    # boundary before it (the previous epoch's phases after
+                    # its loss was ready, and this epoch's `pre`), and the
+                    # process counters over the epoch
+                    rec["dispatch_s"] = round(t_dispatched - t0, 6)
+                    rec["wait_s"] = round(t_ready - t_dispatched, 6)
+                    if boundary_s is not None:
+                        rec["boundary_s"] = round(boundary_s, 6)
+                        rec["boundary"] = boundary
+                    rec.update(obs.rusage_delta())
+                    obs.emit("epoch", **rec)
 
             # ---- --tune decision point: the epoch's measured metrics feed
             # the controller AFTER the epoch record lands on the bus; a
@@ -1678,15 +1756,17 @@ def run_training(cfg: Config, g: Optional[Graph] = None,
             # next epoch (the rebuild/compile happens here, at the boundary,
             # never inside a timed step) ----
             if tuner is not None:
-                _dec = tuner.on_epoch_end(epoch, {
-                    "loss": loss_f, "step_s": dt,
-                    "comm_s": comm_t if comm_t else None,
-                    "wire_mb": epoch_wire_mb})
-                if _dec is not None and _dec["changes"]:
-                    _apply_tune(_dec["changes"], _dec["reason"],
-                                _dec.get("trigger") or {}, epoch + 1)
+                with span("tune"):
+                    _dec = tuner.on_epoch_end(epoch, {
+                        "loss": loss_f, "step_s": dt,
+                        "comm_s": comm_t if comm_t else None,
+                        "wire_mb": epoch_wire_mb})
+                    if _dec is not None and _dec["changes"]:
+                        _apply_tune(_dec["changes"], _dec["reason"],
+                                    _dec.get("trigger") or {}, epoch + 1)
 
             if (epoch + 1) % cfg.log_every == 0:
+                log_span = span("log").begin()
                 mt, mc, mr = timer.means()
                 # [traced]: per-epoch in-step collective time attributed from
                 # the profiler window (the reference's comm_timer equivalent).
@@ -1698,6 +1778,7 @@ def run_training(cfg: Config, g: Optional[Graph] = None,
                 log("Process 000 | Epoch {:05d} | Time(s) {:.4f} | Comm(s) "
                     "{:.4f} {} | Reduce(s) {:.4f} | Loss {:.4f}".format(
                         epoch, mt, mc, tag, mr, loss_f))
+                log_span.end()
 
             wrote_ckpt = False
             if (epoch + 1) % cfg.log_every == 0 and is_rank0 and not bad:
@@ -1708,13 +1789,17 @@ def run_training(cfg: Config, g: Optional[Graph] = None,
                 # but load-bearing under $BNSGCN_COORD_AGREE_EVERY > 1:
                 # a latched-not-yet-agreed NaN state must never become
                 # the newest "last good" checkpoint
-                ckpt.save_checkpoint(ckpt.periodic_path(cfg, epoch),
-                                     params=params, opt_state=opt_state,
-                                     bn_state=state, epoch=epoch,
-                                     best_acc=best_acc, seed=seed,
-                                     extra=_ckpt_extra())
-                ckpt.prune_checkpoints(cfg, cfg.keep_ckpt)
+                with span("checkpoint"):
+                    ckpt.save_checkpoint(ckpt.periodic_path(cfg, epoch),
+                                         params=params, opt_state=opt_state,
+                                         bn_state=state, epoch=epoch,
+                                         best_acc=best_acc, seed=seed,
+                                         extra=_ckpt_extra())
+                    ckpt.prune_checkpoints(cfg, cfg.keep_ckpt)
                 wrote_ckpt = True
+            eval_span = (span("eval") if cfg.eval
+                         and (epoch + 1) % cfg.log_every == 0
+                         else obs_mod.NO_SPAN).begin()
             if mesh_eval and (epoch + 1) % cfg.log_every == 0:
                 fns_e, blk_e, tf_e, art_e = eval_val
                 modes = ("val",) if cfg.inductive else ("val", "test")
@@ -1750,6 +1835,7 @@ def run_training(cfg: Config, g: Optional[Graph] = None,
                             p, evaluate_trans(
                                 "Epoch %05d" % e, p, s, spec, val_g,
                                 result_file, device=host_dev)[0])))
+            eval_span.end()
 
             if resil is not None and (epoch + 1) % cfg.log_every == 0:
                 if wrote_ckpt:
@@ -1787,6 +1873,9 @@ def run_training(cfg: Config, g: Optional[Graph] = None,
                 raise resilience.PreemptedError(epoch, ppath)
             epoch += 1
     finally:
+        if obs is not None:
+            obs.epoch_end()
+            obs.flush_first_calls()
         # trace-window leak fix: a crash/preemption anywhere in the loop
         # (including the normal shorter-than-prof_stop ending) must not
         # leave a dangling profiler session or the auto temp dir behind
@@ -1849,13 +1938,6 @@ def run_training(cfg: Config, g: Optional[Graph] = None,
     res.final_loss = float(loss)
     res.memory = format_memory_stats()
     log(res.memory)
-    # transductive mesh eval shares the training blocks (only 'feat' is new);
-    # inductive keeps a separate val-graph block set resident
-    hbm_parts = [blk]
-    if mesh_eval:
-        hbm_parts.append(eval_val[1] if cfg.inductive else eval_val[1]["feat"])
-    log("static HBM/device ~{:.1f} MB (blocks + params + opt)".format(
-        estimate_static_hbm(hbm_parts, [params, opt_state, state], cfg.n_partitions)))
 
     if cfg.eval and best_params is not None:
         # checkpoint/log I/O is rank-0-only, but the mesh test eval is a

@@ -22,6 +22,7 @@ from __future__ import annotations
 
 from dataclasses import dataclass
 from functools import partial
+import re
 import sys
 import time
 from typing import Any, Callable, Optional
@@ -48,6 +49,7 @@ from bnsgcn_tpu.parallel.reducer import grad_reduce_axes
 from bnsgcn_tpu.parallel.replicas import (dedup_replica0, stacked_spec,
                                           n_replicas as mesh_n_replicas,
                                           replica_axis as mesh_replica_axis)
+from bnsgcn_tpu.utils import traceparse as tp
 
 # --spmm auto picks the dense-tile hybrid when at least this fraction of
 # edges would densify onto MXU tiles (v5e measured: hybrid wins at 78.5%
@@ -230,6 +232,9 @@ class StepFns:
                               # controller's lever baseline; run.py/bench.py
                               # label from it without re-deriving the auto
                               # selection
+    spmm_counts: dict = None  # counts where the aggregation's work is
+                              # defined (see spmm_counts): run.py writes them
+                              # into the obs run_header under `spmm`
     spmm_desc: str = ""       # what aggregation the step was BUILT with, for
                               # the run header: resolved spmm kind and, for
                               # hybrid, the dense-tile count, their edge
@@ -355,19 +360,64 @@ def _cluster_perms(art: PartitionArtifacts, cfg: Config):
     return np.stack(perms_i), np.stack(perms_e)
 
 
+# an ELL index table of a layout dict: [parts, rows, width], under the bare
+# name (--spmm ell), 'res_' (the hybrid's residual) and the --overlap split
+# prefixes
+_ELL_IDX_KEY = re.compile(r"^(?:int_|fro_)?(?:res_)?(fwd|bwd)_idx_\d+$")
+
+
+def agg_calls(spec: ModelSpec) -> tuple[int, int]:
+    """(forward, backward) sum-aggregations one train step runs: one per
+    GCN / GraphSAGE graph layer that aggregates (the precomputed layer 0 of
+    use_pp is a pure matmul), and in the backward one per such layer whose
+    input depends on a parameter (layer 0's never does). GAT aggregates
+    inside its attention."""
+    if spec.model not in ("gcn", "graphsage"):
+        return 0, 0
+    n = max(spec.n_graph_layers - (1 if spec.use_pp else 0), 0)
+    return n, n if spec.use_pp else max(n - 1, 0)
+
+
+def spmm_counts(kind: str, spec: ModelSpec, arrays: dict, n_local: int,
+                spec_pairs: Optional[dict] = None,
+                dense_per_part=()) -> dict:
+    """Counts at the boundaries where the aggregation's work is defined, per
+    part maxima: dense tiles and the edges they carry (hybrid;
+    `dense_per_part` from block_spmm.dense_edge_count), the slots the
+    residual ELL gathers (rows x width summed over buckets, padding included:
+    what ell._bucket_sum reads), and the aggregations per step. `spec_pairs`
+    as in _hybrid_desc."""
+    out = {"path": kind}
+    for d in ("fwd", "bwd"):
+        per_part = np.zeros(n_local, np.int64)
+        for pre, pair in (spec_pairs or {}).items():
+            rb = arrays.get(f"{pre}blk_rowb_{d}")
+            if rb is not None:            # pad slots carry rowb == n_row_blocks
+                per_part += (np.asarray(rb) < pair[d == "bwd"].n_row_blocks
+                             ).sum(axis=1)
+        out[f"tiles_{d}"] = int(per_part.max(initial=0))
+        out[f"residual_slots_{d}"] = int(sum(
+            v.shape[1] * v.shape[2] for k, v in arrays.items()
+            if (m := _ELL_IDX_KEY.match(k)) and m.group(1) == d))
+    out["dense_edges"] = int(max(dense_per_part, default=0))
+    fwd, bwd = agg_calls(spec)
+    out.update(agg_calls_fwd=fwd, agg_calls_bwd=bwd,
+               agg_calls_per_step=fwd + bwd)
+    return out
+
+
 def _hybrid_desc(cfg: Config, art: PartitionArtifacts, arrays: dict,
-                 spec_pairs: dict) -> str:
+                 spec_pairs: dict, dense: int) -> str:
     """Run-header text for a built hybrid layout. `spec_pairs` maps each
     array-key prefix ('' fused; 'int_'/'fro_' --overlap split) to its
-    (fwd, bwd) BlockSpecs."""
-    from bnsgcn_tpu.ops.block_spmm import dense_edge_count, dense_path
+    (fwd, bwd) BlockSpecs; `dense` is the edges the local tiles carry."""
+    from bnsgcn_tpu.ops.block_spmm import dense_path
     n_local = art.feat.shape[0]
     tiles = 0
     for pre, (f, _) in spec_pairs.items():
         rb = arrays.get(pre + "blk_rowb_fwd")
         if rb is not None:                # pad slots carry rowb == n_row_blocks
             tiles += int((np.asarray(rb) < f.n_row_blocks).sum())
-    dense = sum(dense_edge_count(arrays, p) for p in range(n_local))
     edges = max(int((art.dst < art.pad_inner).sum()), 1)
     paths = sorted({dense_path(d, cfg.use_pallas, cfg.spmm_dense)
                     for pair in spec_pairs.values() for d in pair})
@@ -522,6 +572,8 @@ def build_step_fns(cfg: Config, spec: ModelSpec, art: PartitionArtifacts,
     ell_spmm, ell_keys, ell_arrays = None, (), {}
     ell_spmm_pre = None
     spmm_desc = ""                      # hybrid builds fill it; else below
+    hybrid_pairs, dense_pp = None, ()   # hybrid builds: BlockSpec pairs by
+                                        # array-key prefix, dense edges a part
     spmm_kind = cfg.spmm
     auto_perms = None
     if spmm_kind == "auto":
@@ -640,9 +692,7 @@ def build_step_fns(cfg: Config, spec: ModelSpec, art: PartitionArtifacts,
         ell_spmm_pre = _compose_split(split_pre, art.pad_inner)
         ell_keys = tuple(ell_arrays.keys())
         split_kind = "hybrid"
-        spmm_desc = _hybrid_desc(cfg, art, ell_arrays,
-                                 {"int_": (int_f, int_b),
-                                  "fro_": (fro_f, fro_b)})
+        hybrid_pairs = {"int_": (int_f, int_b), "fro_": (fro_f, fro_b)}
     elif want_hybrid:
         from bnsgcn_tpu.ops.block_spmm import (build_block_layouts,
                                                make_block_spmm)
@@ -696,7 +746,7 @@ def build_step_fns(cfg: Config, spec: ModelSpec, art: PartitionArtifacts,
                                        use_pallas=cfg.use_pallas,
                                        accum="reduce")
         ell_keys = tuple(ell_arrays.keys())
-        spmm_desc = _hybrid_desc(cfg, art, ell_arrays, {"": (fwd_b, bwd_b)})
+        hybrid_pairs = {"": (fwd_b, bwd_b)}
     elif (spmm_kind == "ell" and spec.model in ("gcn", "graphsage")
           and overlap == "split"):
         from bnsgcn_tpu.ops.ell import build_split_layouts, make_ell_spmm
@@ -785,6 +835,12 @@ def build_step_fns(cfg: Config, spec: ModelSpec, art: PartitionArtifacts,
             ell_arrays.update(gat_arrays)
             gat_keys = tuple(gat_arrays.keys())
 
+    if hybrid_pairs is not None:
+        from bnsgcn_tpu.ops.block_spmm import dense_edge_count
+        dense_pp = [dense_edge_count(ell_arrays, p)
+                    for p in range(art.feat.shape[0])]
+        spmm_desc = _hybrid_desc(cfg, art, ell_arrays, hybrid_pairs,
+                                 sum(dense_pp))
     if not spmm_desc:
         spmm_desc = ("ell gathers" if ell_spmm is not None
                      else "gat ell-attention" if gat_spec is not None
@@ -838,18 +894,18 @@ def build_step_fns(cfg: Config, spec: ModelSpec, art: PartitionArtifacts,
 
         if split_kind == "segment":
             def agg(i, h, scale_out_norm):
-                with jax.named_scope("halo_start"):
+                with jax.named_scope(tp.HALO_START):
                     recv = halo_start(spec_h, plan, h)
                 h_in = scale(h, out_norm[:ni]) if scale_out_norm else h
-                with jax.named_scope("interior_agg"):
+                with jax.named_scope(tp.INTERIOR_AGG):
                     o_i = agg_sum(h_in, blk["seg_int_src"],
                                   blk["seg_int_dst"], ni, cfg.edge_chunk)
-                with jax.named_scope("halo_finish"):
+                with jax.named_scope(tp.HALO_FINISH):
                     buf = halo_finish(spec_h, plan, recv, h)
                 if combine is not None:
                     buf = combine(i, buf)
                 h_halo = scale(buf, out_norm[ni:]) if scale_out_norm else buf
-                with jax.named_scope("frontier_agg"):
+                with jax.named_scope(tp.FRONTIER_AGG):
                     o_f = agg_sum(jnp.concatenate([h_in, h_halo], 0),
                                   blk["seg_fro_src"], blk["seg_fro_dst"],
                                   ni, cfg.edge_chunk)
@@ -862,17 +918,17 @@ def build_step_fns(cfg: Config, spec: ModelSpec, art: PartitionArtifacts,
         mp = blk["merge_perm"]
 
         def agg(i, h, scale_out_norm):
-            with jax.named_scope("halo_start"):
+            with jax.named_scope(tp.HALO_START):
                 recv = halo_start(spec_h, plan, h)
             h_in = scale(h, out_norm[:ni]) if scale_out_norm else h
-            with jax.named_scope("interior_agg"):
+            with jax.named_scope(tp.INTERIOR_AGG):
                 o_i = int_spmm(a_i, h_in)
-            with jax.named_scope("halo_finish"):
+            with jax.named_scope(tp.HALO_FINISH):
                 buf = halo_finish(spec_h, plan, recv, h)
             if combine is not None:
                 buf = combine(i, buf)
             h_halo = scale(buf, out_norm[ni:]) if scale_out_norm else buf
-            with jax.named_scope("frontier_agg"):
+            with jax.named_scope(tp.FRONTIER_AGG):
                 o_f = fro_spmm(a_f, jnp.concatenate([h_in, h_halo], 0))
             return jnp.concatenate([o_i, o_f], 0)[mp]
         return agg
@@ -905,9 +961,22 @@ def build_step_fns(cfg: Config, spec: ModelSpec, art: PartitionArtifacts,
             return jnp.concatenate([h, pad], 0), presence
         return exchange, presence
 
+    @jax.named_scope(tp.LOSS)
+    def _train_loss(logits, blk):
+        if multilabel:
+            ls = bce_sum(logits, blk["label"], blk["train_mask"])
+        else:
+            ls = ce_sum(logits, blk["label"], blk["train_mask"])
+        # the cross-replica mean is FUSED here: one psum over both mesh axes,
+        # rescaled by n_replicas — the AD transpose of the replicated params
+        # therefore emits one gradient all-reduce over the whole mesh, whose
+        # result is exactly mean-over-replicas of the per-replica gradients
+        return _psum_loss(ls / loss_denom, loss_axes)
+
     def local_loss(params, state, blk, tables, epoch, sample_key, drop_key):
         blk = {k: v[0] for k, v in blk.items()}
-        plan = make_halo_plan(hspec, tables, blk["bnd"], epoch, sample_key)
+        with jax.named_scope(tp.BNS_SAMPLE):
+            plan = make_halo_plan(hspec, tables, blk["bnd"], epoch, sample_key)
         me = jax.lax.axis_index(axis)
         rng = jax.random.fold_in(
             jax.random.fold_in(_replica_fold(drop_key), epoch), me)
@@ -918,16 +987,7 @@ def build_step_fns(cfg: Config, spec: ModelSpec, art: PartitionArtifacts,
                          n_replicas=n_rep, feat_axis=fe_axis, n_feat=n_fe,
                          exchange=exch, presence=pres)
         logits, new_state = apply_model(params, state, spec, blk["feat"], env)
-        if multilabel:
-            ls = bce_sum(logits, blk["label"], blk["train_mask"])
-        else:
-            ls = ce_sum(logits, blk["label"], blk["train_mask"])
-        # the cross-replica mean is FUSED here: one psum over both mesh axes,
-        # rescaled by n_replicas — the AD transpose of the replicated params
-        # therefore emits one gradient all-reduce over the whole mesh, whose
-        # result is exactly mean-over-replicas of the per-replica gradients
-        loss = _psum_loss(ls / loss_denom, loss_axes)
-        return loss, new_state
+        return _train_loss(logits, blk), new_state
 
     sharded_loss = jax.shard_map(
         local_loss, mesh=mesh,
@@ -939,12 +999,16 @@ def build_step_fns(cfg: Config, spec: ModelSpec, art: PartitionArtifacts,
 
     tx = make_tx(cfg)
 
+    @jax.named_scope(tp.OPTIMIZER)
+    def apply_grads(grads, opt_state, params):
+        updates, opt_state = tx.update(grads, opt_state, params)
+        return optax.apply_updates(params, updates), opt_state
+
     @partial(jax.jit, donate_argnums=(0, 1, 2))
     def train_step(params, state, opt_state, epoch, blk, tables, sample_key, drop_key):
         (loss, new_state), grads = jax.value_and_grad(global_loss, has_aux=True)(
             params, state, blk, tables, epoch, sample_key, drop_key)
-        updates, opt_state = tx.update(grads, opt_state, params)
-        params = optax.apply_updates(params, updates)
+        params, opt_state = apply_grads(grads, opt_state, params)
         return params, new_state, opt_state, loss
 
     @jax.jit
@@ -992,9 +1056,10 @@ def build_step_fns(cfg: Config, spec: ModelSpec, art: PartitionArtifacts,
                 ni = hspec.pad_inner
                 if cached:
                     cache_l = {k: v[0] for k, v in cache.items()}
-                    plan = make_halo_plan_refresh(
-                        spec_h, tables_, blk["bnd"], epoch, sample_key,
-                        refresh_k)
+                    with jax.named_scope(tp.BNS_SAMPLE):
+                        plan = make_halo_plan_refresh(
+                            spec_h, tables_, blk["bnd"], epoch, sample_key,
+                            refresh_k)
                     mask = refresh_row_mask(spec_h, refresh_k, epoch)
                     # a refreshed chunk's presence replaces its stored bits;
                     # stale chunks keep the presence of the epoch that last
@@ -1002,8 +1067,9 @@ def build_step_fns(cfg: Config, spec: ModelSpec, art: PartitionArtifacts,
                     presence_h = jnp.where(mask, plan.presence[ni:],
                                            cache_l["presence"])
                 else:
-                    plan = make_halo_plan(hspec, tables_, blk["bnd"], epoch,
-                                          sample_key)
+                    with jax.named_scope(tp.BNS_SAMPLE):
+                        plan = make_halo_plan(hspec, tables_, blk["bnd"],
+                                              epoch, sample_key)
                     mask = None
                     presence_h = plan.presence[ni:]
                 presence = jnp.concatenate(
@@ -1037,12 +1103,7 @@ def build_step_fns(cfg: Config, spec: ModelSpec, art: PartitionArtifacts,
                     exchange=exchange, presence=presence)
                 logits, new_state = apply_model(params, state, spec,
                                                 blk["feat"], env)
-                if multilabel:
-                    ls = bce_sum(logits, blk["label"], blk["train_mask"])
-                else:
-                    ls = ce_sum(logits, blk["label"], blk["train_mask"])
-                loss = _psum_loss(ls / loss_denom, loss_axes)
-                return loss, (new_state, cache_out)
+                return _train_loss(logits, blk), (new_state, cache_out)
 
             if cached:
                 return body
@@ -1069,8 +1130,7 @@ def build_step_fns(cfg: Config, spec: ModelSpec, art: PartitionArtifacts,
             (loss, (new_state, cache)), grads = jax.value_and_grad(
                 sharded_full, has_aux=True)(
                     params, state, blk, tables, epoch, sample_key, drop_key)
-            updates, opt_state = tx.update(grads, opt_state, params)
-            params = optax.apply_updates(params, updates)
+            params, opt_state = apply_grads(grads, opt_state, params)
             return params, new_state, opt_state, loss, cache
 
         @partial(jax.jit, donate_argnums=(0, 1, 2, 6))
@@ -1080,8 +1140,7 @@ def build_step_fns(cfg: Config, spec: ModelSpec, art: PartitionArtifacts,
                 sharded_cached, has_aux=True)(
                     params, state, blk, tables_r, cache, epoch, sample_key,
                     drop_key)
-            updates, opt_state = tx.update(grads, opt_state, params)
-            params = optax.apply_updates(params, updates)
+            params, opt_state = apply_grads(grads, opt_state, params)
             return params, new_state, opt_state, loss, new_cache
 
         def local_exchange_only_refresh(blk, tables_r, epoch, sample_key,
@@ -1185,6 +1244,7 @@ def build_step_fns(cfg: Config, spec: ModelSpec, art: PartitionArtifacts,
         return (dedup_replica0(hid, mesh, hspec.n_parts),
                 dedup_replica0(lg, mesh, hspec.n_parts))
 
+    @jax.named_scope(tp.PP_PRECOMPUTE)
     def local_precompute(blk, tables_full):
         blk = {k: v[0] for k, v in blk.items()}
         agg = _aggregate_pre_for(blk) or (lambda h: agg_sum(
@@ -1249,6 +1309,12 @@ def build_step_fns(cfg: Config, spec: ModelSpec, art: PartitionArtifacts,
                   halo_refresh=refresh_k,
                   halo_mode=halo_mode,
                   halo_strategy=halo_strategy,
+                  spmm_counts=spmm_counts(
+                      "hybrid" if hybrid_pairs is not None
+                      else "ell" if ell_spmm is not None
+                      else "gat-ell" if gat_spec is not None else "segment",
+                      spec, ell_arrays, art.feat.shape[0], hybrid_pairs,
+                      dense_pp),
                   spmm_desc=spmm_desc,
                   **refresh_fns)
     return fns, hspec, tables, tables_full

@@ -5,7 +5,6 @@ it)."""
 _EXPORTS = {
     "calc_acc": "bnsgcn_tpu.utils.metrics",
     "micro_f1": "bnsgcn_tpu.utils.metrics",
-    "CommTimer": "bnsgcn_tpu.utils.timers",
     "EpochTimer": "bnsgcn_tpu.utils.timers",
     "device_memory_stats": "bnsgcn_tpu.utils.timers",
 }

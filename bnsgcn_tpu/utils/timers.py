@@ -1,45 +1,18 @@
 """Timing + device-memory observability.
 
-The reference's CommTimer (helper/timer/comm_timer.py) wraps wall-clock spans
-around every transfer. Under XLA a span inside a jitted step is meaningless;
-instead the trainer measures (a) whole-epoch wall time after block_until_ready
-and (b) communication time by executing a compiled exchange-only program on
-identical inputs in profiling rounds. This module provides the bookkeeping
-plus peak-HBM reporting equivalent to print_memory (helper/utils.py:244-250).
+The reference wraps wall-clock spans around every transfer
+(helper/timer/comm_timer.py). Under XLA a span inside a jitted step is
+meaningless; instead the trainer measures (a) whole-epoch wall time after
+block_until_ready and (b) communication time from the profiler window's
+device collective spans (utils/traceparse.py). This module provides the
+per-epoch bookkeeping plus peak-HBM reporting equivalent to print_memory
+(helper/utils.py:244-250). Host spans of the loop live in obs.span.
 """
 
 from __future__ import annotations
 
-import time
-from contextlib import contextmanager
-
 import jax
 import numpy as np
-
-
-class CommTimer:
-    """Named non-reentrant spans, summed per epoch (helper/timer/comm_timer.py)."""
-
-    def __init__(self):
-        self._time: dict[str, float] = {}
-        self._start: dict[str, float] = {}
-
-    @contextmanager
-    def timer(self, name: str):
-        if name in self._start:
-            raise RuntimeError(f"span {name!r} already running")
-        self._start[name] = time.perf_counter()
-        try:
-            yield
-        finally:
-            self._time[name] = self._time.get(name, 0.0) + time.perf_counter() - self._start.pop(name)
-
-    def tot_time(self) -> float:
-        return sum(self._time.values())
-
-    def clear(self):
-        self._time.clear()
-        self._start.clear()
 
 
 class EpochTimer:
@@ -96,25 +69,3 @@ def format_memory_stats() -> str:
             f"peak {s['peak_bytes_in_use'] / 2**20:.2f} MB, "
             f"limit {s['bytes_limit'] / 2**20:.2f} MB")
     return "\n".join(lines) if lines else "(no device memory stats available)"
-
-
-def estimate_static_hbm(per_part_trees, replicated_trees=(),
-                        n_parts: int = 1) -> float:
-    """Static per-device HBM estimate in MB: one part's slice of the sharded
-    arrays plus every replicated tree. Used where the runtime can't report
-    peak memory (some PJRT transports return None from memory_stats); real
-    peak adds the transient activations on top."""
-    import jax
-
-    def nbytes(tree):
-        total = 0
-        for leaf in jax.tree.leaves(tree):
-            if hasattr(leaf, "nbytes"):
-                total += int(leaf.nbytes)
-            elif hasattr(leaf, "size") and hasattr(leaf, "dtype"):
-                total += int(leaf.size) * leaf.dtype.itemsize
-        return total
-
-    per_part = sum(nbytes(t) for t in per_part_trees) / max(n_parts, 1)
-    repl = sum(nbytes(t) for t in replicated_trees)
-    return (per_part + repl) / 2**20

@@ -46,6 +46,62 @@ _LAUNCH_PAT = re.compile(r"^(?:PjitFunction\((\w+)\)|jit_(\w+))$")
 INTERIOR_PAT = re.compile(r"interior_agg", re.I)
 FRONTIER_PAT = re.compile(r"frontier_agg", re.I)
 
+# Device scopes of the train step: one `jax.named_scope` per layer boundary,
+# entered where the work happens (models/gnn.py, ops/, trainer.py) through
+# these constants, never a retyped literal. XLA threads the scope path into
+# each instruction's `op_name` metadata ("jit(train_step)/.../layer_1/linear/
+# dot_general"; backward ops carry JAX's "transpose(jvp(linear))" wrapper),
+# and the profiler writes it beside every device event, so a reader finds an
+# operation's layer by `innermost_scope` and not by a compiler-chosen name
+# (`while`, `fusion.N`). The gradient all-reduce is emitted by AD's transpose
+# outside any scope and stays found by opcode (REDUCE_PAT). What the compiler
+# does to the names (v5e, PR 27): a loop it rebuilds (`while`) loses its
+# op_name while the fusions of its body keep theirs; a fusion across two
+# scopes carries the one name of its root; its own copies carry none.
+BNS_SAMPLE = "bns_sample"          # sampling + halo: make_halo_plan*
+HALO_EXCHANGE = "halo_exchange"    # sampling + halo: the fused exchange
+HALO_START = "halo_start"          # --overlap split keeps its four phases
+HALO_FINISH = "halo_finish"
+INTERIOR_AGG = "interior_agg"
+FRONTIER_AGG = "frontier_agg"
+AGG_TILES = "agg_tiles"            # dense tiles: block_spmm._dense, fwd + bwd
+AGG_RESIDUAL = "agg_residual"      # residual gather: ell._ell_apply, fwd + bwd
+AGG_COO = "agg_coo"                # residual gather: spmm.gather_scatter_sum
+ATTENTION = "attention"            # model (GAT)
+LINEAR = "linear"                  # model
+NORM = "norm"
+DROPOUT = "dropout"
+LOSS = "loss"
+OPTIMIZER = "optimizer"            # step: tx.update + apply_updates
+PP_PRECOMPUTE = "pp_precompute"    # set-up: trainer.local_precompute
+LAYER = "layer"                    # model: `layer_<i>`, parent of the above
+
+SCOPES = (BNS_SAMPLE, HALO_EXCHANGE, HALO_START, HALO_FINISH, INTERIOR_AGG,
+          FRONTIER_AGG, AGG_TILES, AGG_RESIDUAL, AGG_COO, ATTENTION, LINEAR,
+          NORM, DROPOUT, LOSS, OPTIMIZER, PP_PRECOMPUTE, LAYER)
+
+
+def layer_scope(i: int) -> str:
+    return f"{LAYER}_{i}"
+
+
+# one path component of an op_name that is a table scope, bare or inside
+# transform wrappers (`transpose(jvp(agg_tiles))`); `jit(norm)` is a jitted
+# function of that name, not a scope
+_SCOPE_PART = re.compile(
+    r"^(?:(?!p?jit\()\w+\()*(?:(" + "|".join(s for s in SCOPES if s != LAYER)
+    + r")|" + LAYER + r"_\d+)\)*$")
+
+
+def innermost_scope(op_name: str):
+    """The innermost table scope on an `op_name` path (`layer_3` reads as
+    LAYER), or None where the path holds none."""
+    for part in reversed(op_name.split("/")):
+        m = _SCOPE_PART.match(part)
+        if m:
+            return m.group(1) or LAYER
+    return None
+
 
 def _host_program(name):
     """The HOST_PROGRAMS bucket a host launch span belongs to, or None."""
@@ -160,9 +216,10 @@ def program_cost(bucket, cat="exchange"):
 
 
 def _ev_matches(ev, pat):
-    """Scope match against the event name OR any string arg value (TPU
-    traces carry the HLO op_name metadata — where named_scope lands — in
-    args like 'long_name'/'tf_op' rather than the instruction name)."""
+    """Scope match against the event name OR any string arg value: a v5e
+    trace carries the HLO op_name metadata, where named_scope lands, in
+    `args.tf_op` ("jit(train_step)/jvp()/layer_1/agg_residual/gather:",
+    checked on the chip at PR 27), not in the instruction name."""
     if pat.search(ev.get("name", "")):
         return True
     args = ev.get("args") or {}

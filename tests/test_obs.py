@@ -322,6 +322,182 @@ def test_cli_obs_flags_parse():
 
 
 # ----------------------------------------------------------------------------
+# host spans: obs.span
+# ----------------------------------------------------------------------------
+
+def test_span_nesting_parent_and_exclusive_accumulator(tmp_path):
+    import time
+    ob = obs_mod.Obs(str(tmp_path / "o.jsonl"))
+    with obs_mod.span(ob, "guard", emit=True) as outer:
+        time.sleep(0.01)
+        with obs_mod.span(ob, "norm_probe", emit=True) as inner:
+            time.sleep(0.02)
+        assert inner.parent == "guard" and ob._spans == [outer]
+    with obs_mod.span(ob, "guard"):
+        time.sleep(0.005)
+    assert ob._spans == [] and outer.parent is None
+    assert inner.dur_s >= 0.02 and outer.dur_s >= inner.dur_s + 0.01
+    acc = ob.take_phases()
+    # a name's seconds are its own: the child's are taken out, and two
+    # spans of one name add up
+    assert set(acc) == {"guard", "norm_probe"}
+    assert abs(acc["norm_probe"] - inner.dur_s) < 1e-5
+    assert 0.015 <= acc["guard"] < outer.dur_s - inner.dur_s + 0.02
+    assert ob.take_phases() == {}                  # the account starts anew
+    ob.close()
+    ev = [e for e in obs_mod.load_events(str(tmp_path / "o.jsonl"))]
+    assert [(e["kind"], e["name"], e["parent"]) for e in ev] == [
+        ("span", "norm_probe", "guard"), ("span", "guard", None)]
+    assert ev[1]["t0"] <= ev[0]["t0"] and ev[1]["dur_s"] >= ev[0]["dur_s"]
+
+
+def test_span_begin_end_and_a_span_left_open_by_a_raise(tmp_path):
+    ob = obs_mod.Obs("")
+    sp = obs_mod.span(ob, "pre").begin()
+    with pytest.raises(RuntimeError):
+        with obs_mod.span(ob, "trace_io"):
+            obs_mod.span(ob, "log").begin()         # never ended
+            raise RuntimeError("inside")
+    assert ob._spans == [sp]                        # the stack healed
+    sp.end()
+    assert ob._spans == [] and set(ob.take_phases()) == {"pre", "trace_io"}
+
+
+def test_span_under_obs_off_constructs_nothing():
+    off = obs_mod.span(None, "pre")
+    assert off is obs_mod.span(None, "wait", emit=True) is obs_mod.NO_SPAN
+    assert not isinstance(off, obs_mod.Span)
+    with off as sp:
+        assert sp.begin() is sp and sp.end() is None and sp.dur_s == 0.0
+
+
+def test_first_call_span_and_rusage_deltas(tmp_path):
+    ob = obs_mod.Obs(str(tmp_path / "o.jsonl"))
+    for s in (1.0, 0.1, 0.3, 0.2, 0.25):
+        ob.note_call("train_step", s)               # emitted at the fourth
+    ob.note_call("param_global_norm", 0.5)
+    ob.note_call("param_global_norm", 0.1)
+    ob.note_call("once", 0.7)                       # nothing to hold it against
+    ob.flush_first_calls()
+    ob.flush_first_calls()                          # idempotent
+    d = ob.rusage_delta()
+    assert d == {"cpu_s": 0.0, "nivcsw": 0, "majflt": 0}    # the baseline
+    sum(i * i for i in range(200000))
+    d = ob.rusage_delta()
+    assert d["cpu_s"] > 0 and d["nivcsw"] >= 0 and d["majflt"] >= 0
+    ob.close()
+    ev = {e["name"]: e for e in obs_mod.load_events(str(tmp_path / "o.jsonl"))}
+    assert set(ev) == {"first_call:train_step", "first_call:param_global_norm"}
+    assert ev["first_call:train_step"]["dur_s"] == 0.8      # 1.0 - median
+    assert ev["first_call:train_step"]["calls"] == 4
+    assert ev["first_call:param_global_norm"]["dur_s"] == 0.4
+    assert all(e["parent"] == obs_mod.SETUP_SPANS[0] for e in ev.values())
+
+
+def test_spans_land_in_a_profiler_window_on_the_python_thread(tmp_path):
+    """While a window is open every span is a TraceAnnotation under the
+    program's prefix, and the epoch mark carries the epoch number."""
+    import jax
+    from bnsgcn_tpu.utils import traceparse
+    ob = obs_mod.Obs("")
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        for epoch in (3, 4):
+            ob.epoch_begin(epoch)
+            with obs_mod.span(ob, "guard"), obs_mod.span(ob, "norm_probe"):
+                jax.numpy.ones(8).sum().block_until_ready()
+        ob.epoch_end()
+    finally:
+        jax.profiler.stop_trace()
+    events, _ = traceparse.load_trace_events(str(tmp_path))
+    tnames = traceparse._thread_names(events)
+
+    def full(e):    # the writer shows "guard" and keeps "bns:guard" here
+        return (e.get("args") or {}).get("long_name", "")
+
+    mine = [e for e in events if e.get("ph") == "X"
+            and full(e).startswith(obs_mod.SPAN_PREFIX)]
+    assert sorted(full(e) for e in mine) == sorted(
+        2 * ["bns:epoch", "bns:guard", "bns:norm_probe"])
+    assert {tnames.get((e["pid"], e["tid"])) for e in mine} == {"python"}
+    marks = [e for e in mine if full(e) == obs_mod.EPOCH_MARK]
+    assert sorted(int(e["args"]["step_num"]) for e in marks) == [3, 4]
+    for e in mine:
+        if full(e) == "bns:norm_probe":             # inside its guard's span
+            g = next(x for x in mine if full(x) == "bns:guard"
+                     and x["ts"] <= e["ts"] <= x["ts"] + x["dur"])
+            assert e["ts"] + e["dur"] <= g["ts"] + g["dur"] + 1e-3
+
+
+def test_obs_report_setup_tree_host_columns_and_stalls(tmp_path):
+    """tools/obs_report.py renders the `span` events as a tree with shares,
+    the epoch records' dispatch / wait / boundary, and the epochs whose wait
+    stands out with the counters that say what the host did."""
+    sys.path.insert(0, os.path.join(REPO, "tools"))
+    import obs_report
+    log = obs_mod.EventLog(str(tmp_path / "o.jsonl"))
+    log.emit("span", name="place", parent="run_training_setup", t0=1.0,
+             dur_s=0.5)
+    log.emit("span", name="build_step_fns", parent="run_training_setup",
+             t0=0.2, dur_s=0.25)
+    log.emit("span", name="run_training_setup", parent=None, t0=0.0,
+             dur_s=2.0)
+    log.emit("span", name="first_call:train_step",
+             parent="run_training_setup", t0=2.0, dur_s=30.0, calls=4)
+    for e in range(8):
+        stalled = e == 5
+        log.emit("epoch", epoch=e, loss=1.0, step_s=0.7 if stalled else 0.6,
+                 dispatch_s=0.001, wait_s=0.699 if stalled else 0.599,
+                 boundary_s=0.003, boundary={"pre": 0.001},
+                 cpu_s=0.01, nivcsw=9 if stalled else 0, majflt=0)
+    log.close()
+    out = []
+    obs_report.render(obs_report.summarize(
+        obs_report.load_run([str(tmp_path / "o.jsonl")])), write=out.append)
+    text = "\n".join(out)
+    tree = text[text.index("set-up (span events):"):].splitlines()
+    assert [ln.split()[0] for ln in tree[2:6]] == [
+        "run_training_setup", "build_step_fns", "place",
+        "first_call:train_step"]
+    assert "25.0%" in tree[4] and "12.5%" in tree[3]
+    assert "(4 calls)" in tree[5] and "%" not in tree[5]
+    assert "disp_ms   wait_ms    bnd_ms" in text
+    stalls = text[text.index("stalls (wait over the median"):].splitlines()
+    assert len([ln for ln in stalls[2:] if ln.strip()
+                and ln.split()[0].isdigit()]) == 1
+    assert stalls[2].split()[:4] == ["5", "699.00", "100.00", "9"]
+
+
+def test_obs_report_spmm_counts_and_trace_clock():
+    """The header's `spmm` counts get their line, and a `trace` event's
+    `start_wall` lays the traced epochs' `epoch` events on the trace's clock
+    (an epoch written before the window opened is left out)."""
+    sys.path.insert(0, os.path.join(REPO, "tools"))
+    import obs_report
+    events = [{"ts": 90.0, "kind": "run_header", "rank": 0, "config": {},
+               "spmm": {"path": "hybrid", "tiles_fwd": 190, "tiles_bwd": 188,
+                        "dense_edges": 669167, "residual_slots_fwd": 220512,
+                        "residual_slots_bwd": 219424, "agg_calls_fwd": 3,
+                        "agg_calls_bwd": 3, "agg_calls_per_step": 6}}]
+    events += [{"ts": 100.0 + 0.5 * e, "kind": "epoch", "rank": 0, "epoch": e,
+                "loss": 1.0, "step_s": 0.5} for e in range(5, 11)]
+    events.append({"ts": 104.6, "kind": "trace", "rank": 0, "epoch": 9,
+                   "comm_s": 0.0, "reduce_s": 0.0, "exchanges": False,
+                   "trace_dir": None, "start_wall": 102.75})
+    out = []
+    obs_report.render(obs_report.summarize(events), write=out.append)
+    spmm = next(ln for ln in out if ln.startswith("spmm: "))
+    assert spmm == ("spmm: hybrid | dense tiles 190 fwd / 188 bwd carry "
+                    "669167 edges | residual slots 220512 fwd / 219424 bwd a "
+                    "call | 6 aggregations a step (3 fwd + 3 bwd)")
+    laid = out[out.index(next(ln for ln in out
+                              if ln.startswith("trace @E9"))) + 1]
+    assert laid.strip() == ("window opened at 102.75 (wall clock); epoch "
+                            "events at E6 +0.250s E7 +0.750s E8 +1.250s "
+                            "E9 +1.750s")
+
+
+# ----------------------------------------------------------------------------
 # --obs off == on, bitwise (the bus must never touch training math)
 # ----------------------------------------------------------------------------
 

@@ -11,13 +11,23 @@ after the machine that ran it is gone:
   python tools/obs_report.py RUN.jsonl --json       # summary as one JSON line
 
 Sections (each rendered only when the log carries its events):
-  * run header — config, RxPxT mesh, halo strategy/wire, partition stats
+  * run header — config, RxPxT mesh, halo strategy/wire, partition stats,
+    the aggregation's counts (`spmm`: tiles, dense edges, residual slots,
+    calls a step)
+  * set-up — the `span` events as a tree under `run_training_setup`:
+    seconds and share of the root, `first_call:<program>` (what compiling
+    or loading each jitted program of the loop cost) among them
   * per-epoch table — loss, step ms, comm ms ([traced]/[sampled]), param
     norm, eval accuracy joined on epoch; multi-rank logs merge per rank
-    (rank files `PATH.r<N>` are auto-discovered next to PATH)
+    (rank files `PATH.r<N>` are auto-discovered next to PATH). Where the
+    records carry the loop's host account: dispatch / wait / boundary ms,
+    and a stalls list (epochs whose wait exceeds the median by 10%, with
+    the process counters that say what the host did)
   * comm-vs-compute split — per-epoch means from the epoch records; when a
     `trace`/`profile` event names a still-existing trace dir, the split is
-    re-derived from the device spans via utils/traceparse (the ground truth)
+    re-derived from the device spans via utils/traceparse (the ground truth);
+    the `trace` event's `start_wall` lays the traced epochs' `epoch` events
+    on the trace's clock (seconds after the window opened)
   * lifecycle — rollbacks, preemptions, injections, watchdog fires,
     coordinator decisions, post-mortem dump paths (exits 75/76/77/78)
   * cross-rank epochs — rank 0's merged `epoch_ranks` records (the
@@ -50,7 +60,7 @@ import sys
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 
-from bnsgcn_tpu.obs import EVENT_KINDS, load_events  # noqa: E402
+from bnsgcn_tpu.obs import EVENT_KINDS, FIRST_CALL, load_events  # noqa: E402
 
 LIFECYCLE_KINDS = ("inject", "rollback", "preempt", "watchdog_fire",
                    "divergence_abort", "coord_decision", "profile_request",
@@ -100,7 +110,7 @@ def summarize(events: list[dict]) -> dict:
                  "epoch_ranks": [], "serve": None, "serve_header": None,
                  "serve_drains": [], "serve_fleet": None,
                  "run_end": None, "traces": [], "bench": [], "audits": [],
-                 "continual": [], "unknown_kinds": {}}
+                 "continual": [], "spans": [], "unknown_kinds": {}}
     for ev in events:
         k = ev.get("kind")
         if k is not None and k not in EVENT_KINDS:
@@ -137,6 +147,8 @@ def summarize(events: list[dict]) -> dict:
             out["run_end"] = ev
         elif k == "trace":
             out["traces"].append(ev)
+        elif k == "span" and int(ev.get("rank", 0)) == 0:
+            out["spans"].append(ev)
         elif k == "bench_variant":
             out["bench"].append(ev)
     return out
@@ -199,6 +211,48 @@ def _resize_verdicts(s: dict) -> list[dict]:
     return out
 
 
+def _render_setup(spans: list[dict], write):
+    """The set-up `span` events as a tree: seconds, share of the root."""
+    kids: dict = {}
+    for ev in spans:
+        kids.setdefault(ev.get("parent"), []).append(ev)
+    names = {ev.get("name") for ev in spans}
+    roots = [ev for ev in spans if ev.get("parent") not in names]
+    total = max((_num(ev.get("dur_s")) for ev in roots), default=0.0)
+    write("")
+    write("set-up (span events):")
+    write("  phase                                  seconds   share")
+
+    def walk(ev, depth):
+        d = _num(ev.get("dur_s"))
+        # a first call runs in the loop, after the root has closed
+        share = (f"{d / total:6.1%}" if total > 0
+                 and not str(ev.get("name")).startswith(FIRST_CALL)
+                 else "     -")
+        calls = f"  ({ev['calls']} calls)" if "calls" in ev else ""
+        write(f"  {'  ' * depth + str(ev.get('name')):<36}  {d:8.3f}  "
+              f"{share}{calls}")
+        for kid in sorted(kids.get(ev.get("name"), []),
+                          key=lambda k: _num(k.get("t0"))):
+            walk(kid, depth + 1)
+
+    for ev in sorted(roots, key=lambda k: _num(k.get("t0"))):
+        walk(ev, 0)
+
+
+def _stalls(epochs: dict) -> list[dict]:
+    """Rank 0's epochs whose blocking wait exceeds the run's median by 10%
+    (the first epoch, which compiles, left out)."""
+    evs = [by_r[0] for e, by_r in sorted(epochs.items())
+           if 0 in by_r and "wait_s" in by_r[0]][1:]
+    if len(evs) < 3:
+        return []
+    waits = sorted(_num(ev["wait_s"]) for ev in evs)
+    med = waits[len(waits) // 2]
+    return [dict(ev, over_s=_num(ev["wait_s"]) - med) for ev in evs
+            if _num(ev["wait_s"]) > 1.1 * med]
+
+
 def render(s: dict, write=print):
     if s.get("unknown_kinds"):
         write("WARNING: event kinds outside obs.EVENT_KINDS (build skew?): "
@@ -226,6 +280,19 @@ def render(s: dict, write=print):
         if part:
             write("partition: " + " ".join(f"{k}={v}"
                                            for k, v in sorted(part.items())))
+        sp = hdr.get("spmm") or {}
+        if sp:
+            # counts where the aggregation's work is defined (per part
+            # maxima): what a traced second under `agg_tiles` /
+            # `agg_residual` is a rate of
+            write(f"spmm: {sp.get('path')} | dense tiles "
+                  f"{sp.get('tiles_fwd')} fwd / {sp.get('tiles_bwd')} bwd "
+                  f"carry {sp.get('dense_edges')} edges | residual slots "
+                  f"{sp.get('residual_slots_fwd')} fwd / "
+                  f"{sp.get('residual_slots_bwd')} bwd a call | "
+                  f"{sp.get('agg_calls_per_step')} aggregations a step "
+                  f"({sp.get('agg_calls_fwd')} fwd + "
+                  f"{sp.get('agg_calls_bwd')} bwd)")
     # reorder + layout-build get dedicated lines (and are dropped from the
     # generic lifecycle dump below — one record each, better as a summary)
     ro = next((ev for ev in s["lifecycle"] if ev["kind"] == "reorder"), None)
@@ -243,6 +310,8 @@ def render(s: dict, write=print):
             + (" (cached)" if ev.get("cached") else "") for ev in lb)
         write(f"layout build: {stages} | total "
               f"{sum(_num(ev.get('ms')) for ev in lb):.1f} ms")
+    if s.get("spans"):
+        _render_setup(s["spans"], write)
     # --tune decision trail as a schedule table (also dropped from the
     # generic lifecycle dump): WHEN each comm lever moved, WHY, and the
     # trigger metrics the controller read — the per-run audit of the
@@ -314,9 +383,14 @@ def render(s: dict, write=print):
         peak_mb = _num((hdr or {}).get("wire_mb_per_exchange"))
         has_wire = any("wire_mb" in ev for by_r in epochs.values()
                        for ev in by_r.values())
+        # the loop's host account (obs.span): the step's two halves and the
+        # host wall between the previous loss and this dispatch
+        has_host = any("dispatch_s" in ev for by_r in epochs.values()
+                       for ev in by_r.values())
         cols = ("  epoch   loss        step_ms   comm_ms[t=traced,"
                 "s=sampled]  param_norm  eval")
         write(cols + ("      wire_mb(saved)" if has_wire else "")
+              + ("   disp_ms   wait_ms    bnd_ms" if has_host else "")
               + ("  rank" if multi else ""))
         rows = []
         for e in sorted(epochs):
@@ -345,12 +419,28 @@ def render(s: dict, write=print):
                     + f"  {ev.get('param_norm', ''):<10}  "
                     + (f"{_num(acc):.4f}" if acc is not None else "-")
                     + wire
+                    + ("".join(
+                        f"  {_num(ev[k]) * 1e3:8.3f}" if k in ev
+                        else f"  {'-':>8}"
+                        for k in ("dispatch_s", "wait_s", "boundary_s"))
+                       if has_host else "")
                     + (f"     r{r}" if multi else ""))
         rows, elided = _elide(rows)
         for row in rows:
             write(row)
         if elided:
             write(f"  ... ({len(epochs)} epochs total; middle elided)")
+        stalls = _stalls(epochs)
+        if stalls:
+            write("")
+            write(f"stalls (wait over the median by 10%: {len(stalls)} "
+                  f"epoch(s); nivcsw up with cpu_s flat = a descheduled "
+                  f"host):")
+            write("  epoch   wait_ms   over_ms   nivcsw  majflt    cpu_s")
+            for ev in stalls[:20]:
+                write(f"  {int(ev['epoch']):5d}  {_num(ev['wait_s']) * 1e3:8.2f}"
+                      f"  {ev['over_s'] * 1e3:8.2f}  {ev.get('nivcsw', '-'):>7}"
+                      f"  {ev.get('majflt', '-'):>6}  {ev.get('cpu_s', '-'):>7}")
         # comm vs compute (the first recorded epoch carries the XLA compile
         # and would dominate a raw mean — drop it when there is more data)
         es = sorted(epochs)
@@ -388,6 +478,19 @@ def render(s: dict, write=print):
             except traceparse.TraceError as ex:
                 line += f" | re-parse of {td} failed: {ex}"
         write(line)
+        t_open = tr.get("start_wall")
+        if t_open is not None and epochs:
+            # the obs events of the traced epochs on the trace's clock:
+            # seconds after start_trace at which each `epoch` event was
+            # written (the window closes inside the epoch that emits `trace`;
+            # `ts` is rounded to the millisecond, so the epoch before the
+            # window can read a hair after its opening)
+            laid = [(e, _num(by_r[0].get("ts")) - _num(t_open))
+                    for e, by_r in sorted(epochs.items()) if 0 in by_r
+                    and _num(by_r[0].get("ts")) - _num(t_open) >= 1e-3
+                    and e <= int(_num(tr.get("epoch")))]
+            write(f"  window opened at {t_open} (wall clock); epoch events "
+                  f"at " + " ".join(f"E{e} +{dt:.3f}s" for e, dt in laid))
     life = [ev for ev in s["lifecycle"]
             if ev["kind"] not in ("reorder", "layout_build",
                                   "tune_decision", "resize")]
