@@ -6,8 +6,9 @@ the same aggregation (reference DGL SpMM, module/layer.py:35-37,88-90) as
 dense, scatter-free work:
 
   * offline (numpy, per part): group destination rows by in-degree into
-    power-of-two buckets; within a bucket store src indices as a dense
-    [rows, width] ELL table padded with a dummy index;
+    the buckets of one width ladder (`LADDER`: 4, 8, 16, then steps of 16);
+    within a bucket store src indices as a dense [rows, width] ELL table
+    padded with a dummy index;
   * on device: per bucket, `h[idx]` (a batched row gather — fast on TPU) and
     a dense sum over the width axis; results land via one unique-index
     row permutation (a gather, not a scatter);
@@ -15,8 +16,10 @@ dense, scatter-free work:
     by out-degree) through `jax.custom_vjp`, so the gradient is the same
     scatter-free shape: d_h[u] = sum over out-edges of g[dst].
 
-Bucket widths are powers of two, so ELL padding wastes < 2x gathers; rows
-with degree 0 (structural padding) are skipped entirely.
+A padded slot gathers a row like any other (v5e, PR 28: an all-padding
+bucket runs at the rate of a random one), so the ladder is as fine as the
+device code's unrolled 16-column chains allow; rows with degree 0
+(structural padding) are skipped entirely.
 
 Layouts stack across partition parts (shared bucket shapes = max over parts)
 and ride through shard_map as ordinary sharded int arrays.
@@ -63,6 +66,23 @@ def run_parallel(fns):
 
 
 ELL_SPLIT_CAP = 128   # rows with degree > cap are split into cap-wide chunks
+ELL_BLOCK = 16        # columns one unrolled chain of _bucket_sum sums; every
+                      # ladder width over it is a multiple, so no bucket
+                      # leaves the unroll path
+
+
+def _ladder(top: int) -> np.ndarray:
+    """Bucket widths 4, 8, 16, 32, 48, 64, ... up to the first one >= top:
+    steps of ELL_BLOCK, which grow to an eighth of the width past 256 so an
+    uncapped ladder (GAT's forward) stays a few dozen widths long while a
+    row is still padded by under an eighth."""
+    ws = [4, 8, ELL_BLOCK]
+    while ws[-1] < top:
+        ws.append(ws[-1] + max(ELL_BLOCK, 1 << (ws[-1].bit_length() - 4)))
+    return np.asarray(ws, dtype=np.int64)
+
+
+LADDER = _ladder(2 ** 31)   # every width a bucket can have (202 of them)
 
 
 def layout_fastpath() -> bool:
@@ -92,22 +112,54 @@ def grouped_order(keys: np.ndarray, n_keys: int) -> np.ndarray:
 @dataclass(frozen=True)
 class EllSpec:
     """Static bucket geometry (identical across parts)."""
-    widths: tuple[int, ...]            # bucket ELL widths, ascending powers of 2
+    widths: tuple[int, ...]            # bucket ELL widths, a prefix of LADDER
     rows: tuple[int, ...]              # padded row count per bucket
     n_rows: int                        # output rows (n_dst for fwd, n_src_ext for bwd)
     n_src: int                         # gatherable rows (n_src_ext for fwd, n_dst for bwd)
     n_split: int = 0                   # padded count of split (degree > cap) rows
-    n_chunks: int = 0                  # padded count of their cap-wide chunks
+    n_chunks: int = 0                  # padded count of their chunks (tails too)
 
 
 def _bucketize(deg: np.ndarray, widths: Sequence[int]) -> np.ndarray:
-    """bucket index per row; deg 0 -> -1 (skipped)."""
-    b = np.full(deg.shape, -1, dtype=np.int32)
-    lo = 0
-    for k, w in enumerate(widths):
-        b[(deg > lo) & (deg <= w)] = k
-        lo = w
+    """Index of the narrowest bucket that holds each row; deg 0 -> -1
+    (skipped). The one place a degree meets the ladder: the builder, the
+    offline geometry and the accumulated geometry all bucket through it."""
+    b = np.searchsorted(np.asarray(widths), deg, side="left").astype(np.int32)
+    b[deg <= 0] = -1
     return b
+
+
+def _split_rows(deg: np.ndarray, cap: int | None):
+    """Rows over the cap and how each is laid down, per row of `deg`: (is
+    split, its cap-wide chunks, the length of its tail chunk), 0 where not
+    split."""
+    mask = (deg > cap) if cap else np.zeros(deg.shape, dtype=bool)
+    d = np.where(mask, deg, 0).astype(np.int64)
+    return mask, d // (cap or 1), d % (cap or 1)
+
+
+def _run_ranks(counts: np.ndarray) -> np.ndarray:
+    """0..c-1 for each run length c in `counts`, laid end to end."""
+    return np.arange(int(counts.sum())) - np.repeat(
+        np.cumsum(counts) - counts, counts)
+
+
+def _pad8(r: int) -> int:
+    return (int(r) + 7) // 8 * 8
+
+
+_STALE = ("the stored ELL geometry does not hold this graph under the width "
+          "ladder of this version ({what}): re-partition (or delete "
+          "`ell_geometry` from meta.json on a single host)")
+
+
+def check_geometry(g: dict):
+    """A geometry entry (compute_geometry schema, possibly from meta.json)
+    must name this version's ladder: one computed under another ladder lays
+    split rows down differently and cannot be built from."""
+    w = [int(x) for x in g["widths"]]
+    if w != LADDER[:len(w)].tolist():
+        raise ValueError(_STALE.format(what=f"widths {w}"))
 
 
 def build_ell_numpy(src: np.ndarray, dst: np.ndarray, n_rows: int, n_src: int,
@@ -118,154 +170,135 @@ def build_ell_numpy(src: np.ndarray, dst: np.ndarray, n_rows: int, n_src: int,
     """Build one part's ELL tables for `out[r] = sum_{e: dst_e == r} h[src_e]`.
 
     Padded edges must already point at dst == n_rows (they are dropped).
-    Returns (widths, rows_per_bucket, idx_arrays, perm, chunk_pos, chunk_seg).
+    Returns (widths, rows_per_bucket, idx_arrays, perm, chunk_pos, chunk_seg,
+    row_of).
 
-    Split-row scheme (`cap`): rows with degree > cap become ceil(deg/cap)
-    cap-wide pseudo-rows appended to the cap bucket (cutting the power-law
-    padding waste from ~1.5x to ~1.15x of E); their partial sums are combined
-    by a tiny sorted segment-sum over `chunk_pos`/`chunk_seg`. Table layout:
-    [bucket rows 0..T-1 ; combine results T..T+split_pad-1 ; zero row].
-    `perm[r]` points a normal row at its bucket position, a split row at its
-    combine slot, and a degree-0 row at the zero row.
+    Split-row scheme (`cap`): a row of degree d > cap becomes d // cap
+    cap-wide pseudo-rows in the cap bucket and, where d % cap > 0, one
+    pseudo-row of that length in the ladder bucket that fits it; their
+    partial sums are combined by a tiny sorted segment-sum over
+    `chunk_pos`/`chunk_seg`. With the 16-step ladder this lays down 1.08
+    slots an edge on the benchmark's residual (13,998,063 edges, PR 28) where
+    power-of-two widths with the last chunk padded to the cap laid 1.379.
+    Table layout: [bucket rows 0..T-1 ; zero row T ; combine results
+    T+1..T+split_pad]. `perm[r]` points a normal row at its bucket position,
+    a split row at its combine slot, and a degree-0 row at the zero row;
+    `chunk_pos` addresses rows 0..T of the same table (pad -> the zero row).
     """
     if cap is not None and (cap < 4 or cap & (cap - 1)):
         raise ValueError(f"split cap must be a power of two >= 4, got {cap}")
     real = dst < n_rows
     src, dst = src[real], dst[real]
     deg = np.bincount(dst, minlength=n_rows)
-    split_mask = (deg > cap) if cap else np.zeros(n_rows, dtype=bool)
-    deg_b = np.where(split_mask, 0, deg)
+    split_mask, full, tail = _split_rows(deg, cap)
     if widths is None:
         # ladder from the FULL degree distribution so it reaches cap whenever
-        # any row splits (deg_b alone would stop short of cap)
-        widths = _choose_widths(deg, cap=cap)
-    if cap and split_mask.any() and widths[-1] != cap:
-        raise ValueError(f"width ladder {widths} must end at cap={cap} "
-                         f"when split rows exist")
-    bucket = _bucketize(deg_b, widths)
-
-    order = grouped_order(dst, n_rows)
-    src_sorted = src[order]
-    dst_sorted = dst[order]
-    indptr = np.zeros(n_rows + 1, dtype=np.int64)
-    np.cumsum(deg, out=indptr[1:])
-
-    # split bookkeeping: pseudo-row base per split row, chunk segments
+        # any row splits (the unsplit rows alone would stop short of cap)
+        widths = _choose_widths(int(deg.max(initial=0)), cap=cap)
+    widths = tuple(int(w) for w in widths)
+    K = len(widths)
     split_rows = np.nonzero(split_mask)[0]
     n_split = len(split_rows)
-    chunks_per = np.ceil(deg[split_rows] / cap).astype(np.int64) if n_split else         np.zeros(0, np.int64)
-    n_pseudo = int(chunks_per.sum())
-    assert n_split <= max(split_pad, 0) or split_pad == 0
-    pseudo_base = np.zeros(n_rows, dtype=np.int64)
-    if n_split:
-        pseudo_base[split_rows] = np.concatenate([[0], np.cumsum(chunks_per)[:-1]])
+    if n_split and widths[-1] != cap:
+        raise ValueError(f"width ladder {widths} must end at cap={cap} "
+                         f"when split rows exist")
 
-    # fully vectorized fill: for each edge, its (bucket, row-within-bucket,
-    # slot-within-row) — no per-row python loop (matters at 100M edges)
-    rpos = np.zeros(n_rows, dtype=np.int64)
-    within = np.arange(len(dst_sorted), dtype=np.int64) - indptr[dst_sorted]
-    e_bucket = bucket[dst_sorted]
-    e_split = split_mask[dst_sorted]
+    # the table rows in edge order (edges sorted by row, a split row's chunks
+    # one after the other, its tail last): owner row, chunk number, length
+    per_row = np.where(split_mask, full + (tail > 0), deg > 0)
+    owner = np.repeat(np.arange(n_rows), per_row)
+    chunk = _run_ranks(per_row)
+    pseudo = split_mask[owner]
+    n_pseudo = int(pseudo.sum())
+    length = np.where(pseudo, np.where(chunk < full[owner], cap or 0,
+                                       tail[owner]), deg[owner])
+    # one bucketing for whole rows, cap-wide chunks and tails alike; within a
+    # bucket, rows keep the edge order
+    t_bucket = _bucketize(length, widths)
+    used_k = np.bincount(t_bucket, minlength=K)
+    t_row = np.empty(len(owner), dtype=np.int64)
+    t_row[np.argsort(t_bucket, kind="stable")] = _run_ranks(used_k)
 
-    rows_per_bucket = []
-    perm = np.zeros(n_rows, dtype=np.int32)
-    offset = 0
-    cap_k = len(widths) - 1
-    # bucket geometry in one cheap row-level pass, shared by both fill paths
-    flat_base = np.zeros(len(widths) + 1, dtype=np.int64)
-    cap_offset = cap_normal = 0
-    for k, w in enumerate(widths):
-        rows_k = np.nonzero(bucket == k)[0]
-        n_k = len(rows_k)
-        extra = n_pseudo if (cap and k == cap_k) else 0
-        pad_rows = row_pad[k] if row_pad is not None else n_k + extra
-        assert pad_rows >= n_k + extra
-        rpos[rows_k] = np.arange(n_k)
-        perm[rows_k] = offset + np.arange(n_k, dtype=np.int32)
-        if cap and k == cap_k:
-            cap_offset, cap_normal = offset, n_k
-        rows_per_bucket.append(pad_rows)
-        offset += pad_rows
-        flat_base[k + 1] = flat_base[k] + pad_rows * w
-    total = offset                                 # table rows T
+    sp = split_pad or _pad8(n_split)
+    cp = chunk_pad or _pad8(n_pseudo)
+    rows_per_bucket = (tuple(int(r) for r in row_pad) if row_pad is not None
+                       else tuple(int(r) for r in used_k))
+    if (len(rows_per_bucket) != K or (used_k > rows_per_bucket).any()
+            or n_split > sp or n_pseudo > cp):
+        raise ValueError(_STALE.format(
+            what=f"rows {list(rows_per_bucket)} / split {sp} / chunks {cp} "
+                 f"for {used_k.tolist()} / {n_split} / {n_pseudo}"))
+    offset = np.zeros(K + 1, dtype=np.int64)            # table row of bucket k
+    np.cumsum(rows_per_bucket, out=offset[1:])
+    total = int(offset[-1])                             # table rows T
+    w_arr = np.asarray(widths, dtype=np.int64)
+    flat_base = np.zeros(K + 1, dtype=np.int64)
+    np.cumsum(np.asarray(rows_per_bucket, np.int64) * w_arr, out=flat_base[1:])
+    pos = offset[t_bucket] + t_row                      # table row of each
 
+    # the fill, vectorized over edges (matters at 100M edges): edges sorted
+    # by row are the table rows' slots laid end to end, so an edge's slot in
+    # the flat table is its rank plus its table row's shift
+    order = grouped_order(dst, n_rows)
+    src_sorted = src[order]
+    shift = (flat_base[t_bucket] + t_row * w_arr[t_bucket]
+             - (np.cumsum(length) - length))
+    flat = np.repeat(shift, length)
+    flat += np.arange(len(flat), dtype=np.int64)
     if layout_fastpath():
         # one flat table + one collision-free scatter for ALL buckets —
         # each edge owns a distinct (row, slot), so a single fancy-index
         # write replaces the per-bucket O(E x buckets) full-edge masks
         idx_flat = np.full(int(flat_base[-1]), n_src, dtype=np.int32)
-        w_arr = np.asarray(widths, dtype=np.int64)
-        ns = ~e_split
-        eb = e_bucket[ns]
-        idx_flat[flat_base[eb] + rpos[dst_sorted[ns]] * w_arr[eb]
-                 + within[ns]] = src_sorted[ns]
-        if n_pseudo:
-            es = e_split
-            pr = cap_normal + pseudo_base[dst_sorted[es]] + within[es] // cap
-            idx_flat[flat_base[cap_k] + pr * w_arr[cap_k]
-                     + within[es] % cap] = src_sorted[es]
+        idx_flat[flat] = src_sorted
         idx_arrays = [idx_flat[flat_base[k]:flat_base[k + 1]]
                       .reshape(rows_per_bucket[k], w)
                       for k, w in enumerate(widths)]
     else:
+        e_bucket = np.repeat(t_bucket, length)
         idx_arrays = []
         for k, w in enumerate(widths):
             idx = np.full((rows_per_bucket[k] * w,), n_src, dtype=np.int32)
-            sel = (e_bucket == k) & ~e_split
-            idx[rpos[dst_sorted[sel]] * w + within[sel]] = src_sorted[sel]
-            if cap and k == cap_k and n_pseudo:
-                sel = e_split
-                pr = (cap_normal + pseudo_base[dst_sorted[sel]]
-                      + within[sel] // cap)
-                idx[pr * w + within[sel] % cap] = src_sorted[sel]
+            sel = e_bucket == k
+            idx[flat[sel] - flat_base[k]] = src_sorted[sel]
             idx_arrays.append(idx.reshape(rows_per_bucket[k], w))
 
-    sp = split_pad if split_pad else ((n_split + 7) // 8 * 8 if n_split else 0)
-    cp = chunk_pad if chunk_pad else ((n_pseudo + 7) // 8 * 8 if n_pseudo else 0)
-    # chunk_pos indexes the CAP BUCKET's rows (plus one appended zero row at
-    # rows_per_bucket[-1]) — not the whole table — so the combine gathers from
-    # the cap bucket output directly without re-materializing the table
-    cap_rows = rows_per_bucket[-1] if rows_per_bucket else 0
-    chunk_pos = np.full(cp, cap_rows, dtype=np.int32)   # pad -> appended zero row
-    chunk_seg = np.full(cp, sp, dtype=np.int32)         # pad -> dropped segment
     # row_of[table_pos] = the output row this table row computes (split
-    # pseudo-rows map to their split source; padding -> n_rows). Consumers
-    # that need per-table-row context (GAT attention broadcasts el/z by row)
-    # index with this.
+    # pseudo-rows, tails among them, map to their split row; padding ->
+    # n_rows). Consumers that need per-table-row context (GAT attention
+    # broadcasts el/z by row) index with this.
     row_of = np.full(total, n_rows, dtype=np.int32)
-    normal = (bucket >= 0)
-    rws = np.nonzero(normal)[0]
-    row_of[perm[rws]] = rws
-    if n_split:
-        chunk_pos[:n_pseudo] = cap_normal + np.arange(n_pseudo)
-        chunk_seg[:n_pseudo] = np.repeat(np.arange(n_split), chunks_per)
-        perm[split_rows] = total + np.arange(n_split, dtype=np.int32)
-        row_of[cap_offset + cap_normal + np.arange(n_pseudo)] = \
-            np.repeat(split_rows, chunks_per)
-    perm[(bucket == -1) & ~split_mask] = total + sp     # zero row
-    return (tuple(widths), tuple(rows_per_bucket), idx_arrays, perm,
-            chunk_pos, chunk_seg, row_of)
+    row_of[pos] = owner
+    perm = np.full(n_rows, total, dtype=np.int32)       # degree 0: zero row
+    perm[owner[~pseudo]] = pos[~pseudo]
+    perm[split_rows] = total + 1 + np.arange(n_split, dtype=np.int32)
+    chunk_pos = np.full(cp, total, dtype=np.int32)      # pad -> the zero row
+    chunk_seg = np.full(cp, sp, dtype=np.int32)         # pad -> dropped segment
+    chunk_pos[:n_pseudo] = pos[pseudo]
+    chunk_seg[:n_pseudo] = np.cumsum(split_mask)[owner[pseudo]] - 1
+    return (widths, rows_per_bucket, idx_arrays, perm, chunk_pos, chunk_seg,
+            row_of)
 
 
-def _choose_widths(deg: np.ndarray, cap: int | None = None) -> tuple[int, ...]:
-    """Power-of-2 bucket-width ladder from 4 up to min(max degree, cap).
+def _choose_widths(max_deg: int, cap: int | None = None) -> tuple[int, ...]:
+    """The ladder's widths from 4 up to the first that holds
+    min(max degree, cap): the cap itself when rows split.
 
-    (An edge-mass-quantile scheme was tried and measured *slower* on a v5e
-    despite ~25% fewer padded gathers — wide low-row-count buckets hurt the
-    gather/reduce pipeline more than padding does. Keep the ladder; the
-    split-row cap handles the power-law tail instead.)
+    What the ladder rests on, measured on a v5e (`tools/ell_bucket_probe.py`,
+    bf16 rows of 256, unroll path; PR 28): a padded slot costs what an edge
+    costs (a 117,728 x 128 bucket takes 65.2 ms with random indices, 65.0 ms
+    with half of each row padding, 65.0 ms with nothing but padding), and
+    seconds follow slots down to the buckets a 16-step ladder makes: the
+    benchmark's residual as 10 buckets (rows 1,576 to 42,824) runs 15.12M
+    slots in 60.9 ms, as the 4 occupied buckets of a power-of-two ladder
+    19.31M in 82.1 ms (248 against 235 Mslot/s in one program). An earlier
+    note here said an edge-mass-quantile ladder had measured slower; no record
+    of that run survives, and its widths were no multiples of the block, so
+    they left the unroll path for the materialising reduce.
     """
-    deg = deg[deg > 0]
-    max_deg = int(deg.max()) if deg.size else 1
-    if cap:
-        max_deg = min(max_deg, cap)
-    widths, w = [], 4
-    while True:
-        widths.append(w)
-        if w >= max(max_deg, 1):
-            break
-        w *= 2
-    return tuple(widths)
+    top = max(min(max_deg, cap) if cap else max_deg, 1)
+    return tuple(int(w) for w in
+                 LADDER[:int(np.searchsorted(LADDER, top, side="left")) + 1])
 
 
 def _part_edges(src, dst, n_dst, direction):
@@ -283,37 +316,16 @@ def compute_geometry(src_all: np.ndarray, dst_all: np.ndarray, n_dst: int,
     directions — a pure graph property needing the FULL set of parts.
     JSON-serializable so the offline partitioner can store it in meta.json,
     letting multi-host processes build their ELL tables from local parts
-    alone (data/artifacts.py)."""
-    P = src_all.shape[0]
+    alone (data/artifacts.py). The same GeoAccum that streams over parts
+    there counts here, so the two cannot drift."""
     geo = {}
     for direction in directions:
         n_rows = n_dst if direction == "fwd" else n_src_ext
-        degs = []
-        for p in range(P):
+        acc = GeoAccum(cap)
+        for p in range(src_all.shape[0]):
             _, d = _part_edges(src_all[p], dst_all[p], n_dst, direction)
-            degs.append(np.bincount(d, minlength=n_rows))
-        all_deg = np.concatenate(degs)
-        widths = _choose_widths(all_deg, cap=cap)
-        eff_cap = cap if (cap and all_deg.max() > cap) else None
-        rows_max = [0] * len(widths)
-        split_max = chunk_max = 0
-        for d in degs:
-            split = (d > eff_cap) if eff_cap else np.zeros_like(d, dtype=bool)
-            b = _bucketize(np.where(split, 0, d), widths)
-            for k in range(len(widths)):
-                rows_max[k] = max(rows_max[k], int(np.sum(b == k)))
-            if eff_cap:
-                split_max = max(split_max, int(split.sum()))
-                chunk_max = max(chunk_max, int(np.ceil(d[split] / eff_cap).sum()))
-        if eff_cap:
-            rows_max[-1] += chunk_max          # pseudo-rows live in the cap bucket
-        pad8 = lambda r: ((r + 7) // 8) * 8 if r else 0
-        geo[direction] = {
-            "widths": [int(w) for w in widths],
-            "rows": [pad8(r) for r in rows_max],
-            "split": pad8(split_max), "chunks": pad8(chunk_max),
-            "cap": eff_cap,
-        }
+            acc.add_part(np.bincount(d, minlength=n_rows))
+        geo[direction] = acc.finish()
     return geo
 
 
@@ -337,6 +349,7 @@ def build_layouts(src_all: np.ndarray, dst_all: np.ndarray, n_dst: int,
         n_rows = n_dst if direction == "fwd" else n_src_ext
         n_src = n_src_ext if direction == "fwd" else n_dst
         g = geometry[direction]
+        check_geometry(g)
         widths = tuple(g["widths"])
         rows_max = tuple(g["rows"])
         split_max, chunk_max, eff_cap = g["split"], g["chunks"], g["cap"]
@@ -410,6 +423,40 @@ def build_split_layouts(src_all: np.ndarray, dst_all: np.ndarray, n_dst: int,
     return (int_f, int_b), (fro_f, fro_b), arrays, n_int_pad, n_fro_pad
 
 
+@jax.jit
+def _unroll_sum(hp, idx):
+    """_bucket_sum's unroll path. Jitted so that the step traces a bucket
+    shape once and not once a call: jnp's indexing is slow to trace (about
+    11 ms a gather on the v5e's host, PR 28), a step holds every bucket's
+    chains 3 + 3 times, and the 16-step ladder has ten buckets where the
+    power-of-two one had six. XLA inlines the calls: the program is the one
+    the inline code gave, each copy under its caller's scope names."""
+    (r, w), h_dim, BS = idx.shape, hp.shape[1], ELL_BLOCK
+    # int8 rows accumulate in int32 (exact, like the reduce path's
+    # int32 sums — the caller's one per-call scale multiplies back
+    # after the combine); native rows in f32 chains
+    acc_dt = jnp.int32 if hp.dtype == jnp.int8 else jnp.float32
+    out_dt = jnp.int32 if hp.dtype == jnp.int8 else hp.dtype
+
+    def chain(cb, n):
+        a = hp[cb[0]].astype(acc_dt)
+        for j in range(1, n):
+            a = a + hp[cb[j]].astype(acc_dt)
+        return a
+
+    if w <= BS:
+        return chain(idx.T, w).astype(out_dt)
+    cols = idx.T.reshape(w // BS, BS, r)
+    # derive the init from the input so the carry has the same varying
+    # manual axes as the body output under shard_map (same contract as
+    # block_spmm._dense_apply's acc0); the empty slice reads no data
+    acc0 = jnp.zeros((r, h_dim), acc_dt) \
+        + jnp.sum(hp[:0]).astype(acc_dt)
+    out, _ = jax.lax.scan(lambda acc, cb: (acc + chain(cb, BS), None),
+                          acc0, cols)
+    return out.astype(out_dt)
+
+
 def _bucket_sum(hp, idx, w, chunk_gathers: int = 4_000_000,
                 use_pallas: bool = False, accum: str = "auto"):
     """sum over ELL width for one bucket.
@@ -457,35 +504,12 @@ def _bucket_sum(hp, idx, w, chunk_gathers: int = 4_000_000,
         from bnsgcn_tpu.utils.platform import tpu_codepaths
         accum = ("unroll" if hp.dtype != jnp.float8_e4m3fn
                  and tpu_codepaths() else "reduce")
-    BS = 16
     if accum == "unroll" and hp.dtype == jnp.float8_e4m3fn:
         raise ValueError("accum='unroll' supports native and int8 rows; "
                          "fp8 gathers take accum='reduce'")
     if (accum == "unroll" and r > 0 and w > 1
-            and (w <= BS or w % BS == 0)):
-        # int8 rows accumulate in int32 (exact, like the reduce path's
-        # int32 sums — the caller's one per-call scale multiplies back
-        # after the combine); native rows in f32 chains
-        acc_dt = jnp.int32 if hp.dtype == jnp.int8 else jnp.float32
-        out_dt = jnp.int32 if hp.dtype == jnp.int8 else hp.dtype
-
-        def chain(cb, n):
-            a = hp[cb[0]].astype(acc_dt)
-            for j in range(1, n):
-                a = a + hp[cb[j]].astype(acc_dt)
-            return a
-
-        if w <= BS:
-            return chain(idx.T, w).astype(out_dt)
-        cols = idx.T.reshape(w // BS, BS, r)
-        # derive the init from the input so the carry has the same varying
-        # manual axes as the body output under shard_map (same contract as
-        # block_spmm._dense_apply's acc0); the empty slice reads no data
-        acc0 = jnp.zeros((r, h_dim), acc_dt) \
-            + jnp.sum(hp[:0]).astype(acc_dt)
-        out, _ = jax.lax.scan(lambda acc, cb: (acc + chain(cb, BS), None),
-                              acc0, cols)
-        return out.astype(out_dt)
+            and (w <= ELL_BLOCK or w % ELL_BLOCK == 0)):
+        return _unroll_sum(hp, idx)
     rows_per_chunk = max(1, chunk_gathers // max(w, 1))
     # (round 5) pallas_bucket_reduce is no longer dispatched here: the
     # unrolled chains beat it end-to-end on the v5e (it fuses only the
@@ -529,18 +553,18 @@ def ell_combine(spec: EllSpec, outs, perm, chunk_pos=None, chunk_seg=None):
     combine (tiny sorted segment-sum) + one permutation gather. Shared by the
     SpMM and any other bucketed row computation (GAT attention backward)."""
     trailing = outs[0].shape[1:]
-    zero = jnp.zeros((1,) + trailing, outs[0].dtype)
+    # [bucket rows ; zero row ; combine slots]: one table serves the chunk
+    # gather (a split row's chunks sit in the cap bucket, its tail in the
+    # bucket that fits it; chunk_pos pads point at the zero row) and, with
+    # the combined rows written into its last slots, the permutation
+    tail = jnp.zeros((1 + spec.n_split,) + trailing, outs[0].dtype)
+    full = jnp.concatenate(list(outs) + [tail], axis=0)
     if spec.n_split:
-        # combine split-row chunks straight from the cap bucket's output
-        # (chunk_pos is cap-bucket-relative; its pad points at the zero row)
-        cap_z = jnp.concatenate([outs[-1], zero], axis=0)
-        gathered = cap_z[chunk_pos]                    # [n_chunks, ...]
-        comb = jax.ops.segment_sum(gathered, chunk_seg,
+        comb = jax.ops.segment_sum(full[chunk_pos], chunk_seg,
                                    num_segments=spec.n_split + 1,
                                    indices_are_sorted=True)[:spec.n_split]
-        full = jnp.concatenate(list(outs) + [comb, zero], axis=0)
-    else:
-        full = jnp.concatenate(list(outs) + [zero], axis=0)
+        full = jax.lax.dynamic_update_slice_in_dim(
+            full, comb, sum(spec.rows) + 1, axis=0)
     return full[perm]
 
 
@@ -613,22 +637,15 @@ def make_ell_spmm(fwd_spec: EllSpec, bwd_spec: EllSpec, n_buckets_fwd: int,
     return spmm
 
 
-def _pow2_bucket(deg: np.ndarray) -> np.ndarray:
-    """Ladder bucket index of each positive degree for widths (4, 8, 16, ...):
-    deg in (0,4] -> 0, (4,8] -> 1, (2^j, 2^(j+1)] -> j-1 (matches
-    ops/ell._bucketize against ops/ell._choose_widths ladders exactly)."""
-    d = np.maximum(deg, 1)
-    return np.maximum(np.ceil(np.log2(d)).astype(np.int64), 2) - 2
-
-
 class GeoAccum:
     """Accumulates per-part degree statistics into the compute_geometry dict
-    without holding any stacked arrays: per-part pow2-bucket counts (below the
-    cap), split-row counts and chunk sums (above it), and the global max."""
+    without holding any stacked arrays: per-part counts of the table rows
+    each ladder bucket receives (whole rows, the cap-wide chunks of split
+    rows, their tails), split-row and chunk counts, and the global max."""
 
     def __init__(self, cap):
         self.cap = cap
-        self.rows_max = np.zeros(64, dtype=np.int64)
+        self.rows_max = np.zeros(len(LADDER), dtype=np.int64)
         self.split_max = 0
         self.chunk_max = 0
         self.max_deg = 0
@@ -638,44 +655,40 @@ class GeoAccum:
         if deg.size == 0:
             return
         self.max_deg = max(self.max_deg, int(deg.max()))
-        if self.cap:
-            over = deg > self.cap
-            n_split = int(over.sum())
-            if n_split:
-                self.split_max = max(self.split_max, n_split)
-                self.chunk_max = max(self.chunk_max, int(
-                    np.ceil(deg[over] / self.cap).sum()))
-                deg = deg[~over]
-        if deg.size:
-            b = np.bincount(_pow2_bucket(deg), minlength=64)
-            self.rows_max = np.maximum(self.rows_max, b)
+        over, full, tail = _split_rows(deg, self.cap)
+        tail = tail[tail > 0]
+        rows = np.bincount(
+            _bucketize(np.concatenate([deg[~over], tail]), LADDER),
+            minlength=len(LADDER))
+        if over.any():
+            rows[np.searchsorted(LADDER, self.cap)] += full.sum()
+            self.split_max = max(self.split_max, int(over.sum()))
+            self.chunk_max = max(self.chunk_max,
+                                 int(full.sum()) + int(tail.size))
+        self.rows_max = np.maximum(self.rows_max, rows)
 
     def state(self) -> "np.ndarray":
         """Fixed-size mergeable stats vector (for cross-host agreement):
-        [rows_max[64], split_max, chunk_max, max_deg]."""
+        [rows_max[len(LADDER)], split_max, chunk_max, max_deg]."""
         return np.concatenate([self.rows_max,
                                [self.split_max, self.chunk_max, self.max_deg]]
                               ).astype(np.int64)
 
     def merge_state(self, state: "np.ndarray"):
         """Elementwise-max another accumulator's state() into this one."""
-        self.rows_max = np.maximum(self.rows_max, state[:64])
-        self.split_max = max(self.split_max, int(state[64]))
-        self.chunk_max = max(self.chunk_max, int(state[65]))
-        self.max_deg = max(self.max_deg, int(state[66]))
+        n = len(LADDER)
+        self.rows_max = np.maximum(self.rows_max, state[:n])
+        self.split_max = max(self.split_max, int(state[n]))
+        self.chunk_max = max(self.chunk_max, int(state[n + 1]))
+        self.max_deg = max(self.max_deg, int(state[n + 2]))
 
     def finish(self) -> dict:
         if self.max_deg == 0:
             return {"widths": [4], "rows": [0], "split": 0, "chunks": 0,
                     "cap": None}
-        fake = np.asarray([self.max_deg])
-        widths = _choose_widths(fake, cap=self.cap)
+        widths = _choose_widths(self.max_deg, cap=self.cap)
         eff_cap = self.cap if (self.cap and self.max_deg > self.cap) else None
-        rows = [int(r) for r in self.rows_max[:len(widths)]]
-        pad8 = lambda r: ((r + 7) // 8) * 8 if r else 0
-        split = chunks = 0
-        if eff_cap:
-            split, chunks = pad8(self.split_max), pad8(self.chunk_max)
-            rows[-1] += self.chunk_max
-        return {"widths": [int(w) for w in widths], "rows": [pad8(r) for r in rows],
-                "split": split, "chunks": chunks, "cap": eff_cap}
+        return {"widths": list(widths),
+                "rows": [_pad8(r) for r in self.rows_max[:len(widths)]],
+                "split": _pad8(self.split_max), "chunks": _pad8(self.chunk_max),
+                "cap": eff_cap}

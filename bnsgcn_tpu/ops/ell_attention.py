@@ -36,7 +36,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from bnsgcn_tpu.ops.ell import (ELL_SPLIT_CAP, EllSpec, build_ell_numpy,
-                                compute_geometry, ell_combine)
+                                check_geometry, compute_geometry,
+                                ell_combine)
 from bnsgcn_tpu.utils import traceparse as tp
 
 
@@ -76,6 +77,8 @@ def build_gat_layouts(src_all: np.ndarray, dst_all: np.ndarray, n_dst: int,
         geometry_bwd = compute_geometry(src_all, dst_all, n_dst, n_src_ext,
                                         cap=ELL_SPLIT_CAP,
                                         directions=("bwd",))["bwd"]
+    check_geometry(geometry)
+    check_geometry(geometry_bwd)
     widths = tuple(geometry["widths"])
     rows_max = tuple(geometry["rows"])
 

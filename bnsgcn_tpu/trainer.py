@@ -362,8 +362,8 @@ def _cluster_perms(art: PartitionArtifacts, cfg: Config):
 
 # an ELL index table of a layout dict: [parts, rows, width], under the bare
 # name (--spmm ell), 'res_' (the hybrid's residual) and the --overlap split
-# prefixes
-_ELL_IDX_KEY = re.compile(r"^(?:int_|fro_)?(?:res_)?(fwd|bwd)_idx_\d+$")
+# prefixes; groups: the prefix, the direction
+_ELL_IDX_KEY = re.compile(r"^((?:int_|fro_)?(?:res_)?)(fwd|bwd)_idx_\d+$")
 
 
 def agg_calls(spec: ModelSpec) -> tuple[int, int]:
@@ -385,10 +385,11 @@ def spmm_counts(kind: str, spec: ModelSpec, arrays: dict, n_local: int,
     part maxima: dense tiles and the edges they carry (hybrid;
     `dense_per_part` from block_spmm.dense_edge_count), the slots the
     residual ELL gathers (rows x width summed over buckets, padding included:
-    what ell._bucket_sum reads), and the aggregations per step. `spec_pairs`
-    as in _hybrid_desc."""
+    what ell._bucket_sum reads) and the real edges among them (slots over
+    edges is what the bucket geometry costs), and the aggregations per step.
+    `spec_pairs` as in _hybrid_desc."""
     out = {"path": kind}
-    for d in ("fwd", "bwd"):
+    for d, other in (("fwd", "bwd"), ("bwd", "fwd")):
         per_part = np.zeros(n_local, np.int64)
         for pre, pair in (spec_pairs or {}).items():
             rb = arrays.get(f"{pre}blk_rowb_{d}")
@@ -396,9 +397,18 @@ def spmm_counts(kind: str, spec: ModelSpec, arrays: dict, n_local: int,
                 per_part += (np.asarray(rb) < pair[d == "bwd"].n_row_blocks
                              ).sum(axis=1)
         out[f"tiles_{d}"] = int(per_part.max(initial=0))
-        out[f"residual_slots_{d}"] = int(sum(
-            v.shape[1] * v.shape[2] for k, v in arrays.items()
-            if (m := _ELL_IDX_KEY.match(k)) and m.group(1) == d))
+        # a padded slot holds the index of the appended zero row: the rows
+        # the table gathers from, which the other direction's perm counts
+        slots, edges = 0, np.zeros(n_local, np.int64)
+        for k, v in arrays.items():
+            m = _ELL_IDX_KEY.match(k)
+            if m and m.group(2) == d:
+                v = np.asarray(v)
+                slots += v.shape[1] * v.shape[2]
+                n_src = arrays[f"{m.group(1)}{other}_perm"].shape[1]
+                edges += (v < n_src).sum(axis=(1, 2))
+        out[f"residual_slots_{d}"] = int(slots)
+        out[f"residual_edges_{d}"] = int(edges.max(initial=0))
     out["dense_edges"] = int(max(dense_per_part, default=0))
     fwd, bwd = agg_calls(spec)
     out.update(agg_calls_fwd=fwd, agg_calls_bwd=bwd,

@@ -14,7 +14,9 @@ import os
 import pickle
 import time
 
-CACHE_VER = 1               # bump when artifact/layout formats change
+CACHE_VER = 2               # bump when artifact/layout formats change
+                            # (2: the ELL width ladder and split-row tails,
+                            # whose tables the combine of 1 cannot read)
 
 
 def try_load(path: str, log=print):

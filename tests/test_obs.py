@@ -477,7 +477,9 @@ def test_obs_report_spmm_counts_and_trace_clock():
     events = [{"ts": 90.0, "kind": "run_header", "rank": 0, "config": {},
                "spmm": {"path": "hybrid", "tiles_fwd": 190, "tiles_bwd": 188,
                         "dense_edges": 669167, "residual_slots_fwd": 220512,
-                        "residual_slots_bwd": 219424, "agg_calls_fwd": 3,
+                        "residual_slots_bwd": 219424,
+                        "residual_edges_fwd": 200000,
+                        "residual_edges_bwd": 200000, "agg_calls_fwd": 3,
                         "agg_calls_bwd": 3, "agg_calls_per_step": 6}}]
     events += [{"ts": 100.0 + 0.5 * e, "kind": "epoch", "rank": 0, "epoch": e,
                 "loss": 1.0, "step_s": 0.5} for e in range(5, 11)]
@@ -489,7 +491,15 @@ def test_obs_report_spmm_counts_and_trace_clock():
     spmm = next(ln for ln in out if ln.startswith("spmm: "))
     assert spmm == ("spmm: hybrid | dense tiles 190 fwd / 188 bwd carry "
                     "669167 edges | residual slots 220512 fwd / 219424 bwd a "
-                    "call | 6 aggregations a step (3 fwd + 3 bwd)")
+                    "call for 200000 / 200000 edges (1.103 / 1.097 slots an "
+                    "edge) | 6 aggregations a step (3 fwd + 3 bwd)")
+    # a header written before the edges were counted keeps its old line
+    for d in ("fwd", "bwd"):
+        del events[0]["spmm"][f"residual_edges_{d}"]
+    out = []
+    obs_report.render(obs_report.summarize(events), write=out.append)
+    assert "slots 220512 fwd / 219424 bwd a call | 6 aggregations" in next(
+        ln for ln in out if ln.startswith("spmm: "))
     laid = out[out.index(next(ln for ln in out
                               if ln.startswith("trace @E9"))) + 1]
     assert laid.strip() == ("window opened at 102.75 (wall clock); epoch "

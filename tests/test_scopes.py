@@ -78,9 +78,10 @@ def test_lowered_step_carries_every_scope(tiny_art, monkeypatch, model, spmm):
     elif model == "graphsage":  # use_pp: [feat, mean_nbr]
         a["blk"]["feat"] = jax.ShapeDtypeStruct(
             feat.shape[:2] + (2 * feat.shape[2],), feat.dtype)
-    text = fns.train_step.lower(
+    lowered = fns.train_step.lower(
         a["params"], a["state"], a["opt_state"], a["epoch"], a["blk"],
-        a["tables"], a["key"], a["key"]).as_text(debug_info=True)
+        a["tables"], a["key"], a["key"])
+    text = lowered.as_text(debug_info=True)
     names = re.findall(r'loc\("(jit\(train_step\)[^"]*)"', text)
     found = {tp.innermost_scope(n) for n in names}
     want = ALWAYS | ({tp.ATTENTION} if model == "gat"
@@ -93,7 +94,12 @@ def test_lowered_step_carries_every_scope(tiny_art, monkeypatch, model, spmm):
         paths = [n for n in names if tp.innermost_scope(n) == scope]
         assert any("transpose(" in n for n in paths), scope
         assert any("transpose(" not in n for n in paths), scope
-    loops = [n for n in names if n.endswith("/while")]
+    # and the loops of a jitted helper (ell._unroll_sum, traced once for all
+    # its calls and lowered apart) as the compiled program names them: XLA
+    # inlines the helper under each caller's path
+    loops = [n for n in names if n.endswith("/while")] + re.findall(
+        r' while\(.*op_name="(jit\(train_step\)[^"]*'
+        r'jit\(_unroll_sum\)/while)"', lowered.compile().as_text())
     if spmm == "ell" and model != "gat":
         # ell._bucket_sum's scan over column blocks
         assert any(tp.innermost_scope(n) == tp.AGG_RESIDUAL for n in loops)
@@ -214,10 +220,18 @@ def test_run_header_counts_equal_the_layout_arrays(tiny_run):
     events, fns, _ = tiny_run
     head = next(e for e in events if e["kind"] == "run_header")["spmm"]
     assert head["path"] == "ell" and head["tiles_fwd"] == 0
+    # a padded slot holds the index of the zero row appended to what the
+    # table gathers from: the extended rows forward, the owned rows backward
+    zero_row = {"fwd": fns.extra_blk["bwd_perm"].shape[1],
+                "bwd": fns.extra_blk["fwd_perm"].shape[1]}
     for d in ("fwd", "bwd"):
-        slots = sum(int(np.prod(v.shape[1:]))
-                    for k, v in fns.extra_blk.items()
-                    if re.fullmatch(rf"{d}_idx_\d+", k))
+        tables = [np.asarray(v) for k, v in fns.extra_blk.items()
+                  if re.fullmatch(rf"{d}_idx_\d+", k)]
+        slots = sum(int(np.prod(v.shape[1:])) for v in tables)
         assert slots > 0 and head[f"residual_slots_{d}"] == slots
+        edges = sum(int((v < zero_row[d]).sum()) for v in tables)
+        assert 0 < edges <= slots and head[f"residual_edges_{d}"] == edges
+    # --spmm ell on one part: both directions carry the graph's every edge
+    assert head["residual_edges_fwd"] == head["residual_edges_bwd"]
     assert (head["agg_calls_fwd"], head["agg_calls_bwd"],
             head["agg_calls_per_step"]) == (2, 2, 4)

@@ -253,6 +253,18 @@ def _stalls(epochs: dict) -> list[dict]:
             if _num(ev["wait_s"]) > 1.1 * med]
 
 
+def _slots_per_edge(sp: dict) -> str:
+    """The residual's real edges and what the bucket geometry lays down for
+    each (padding included); empty for a header written before the count."""
+    slots = [sp.get(f"residual_slots_{d}") for d in ("fwd", "bwd")]
+    edges = [sp.get(f"residual_edges_{d}") for d in ("fwd", "bwd")]
+    if None in edges or not all(edges):
+        return ""
+    return (f" for {edges[0]} / {edges[1]} edges "
+            f"({slots[0] / edges[0]:.3f} / {slots[1] / edges[1]:.3f} slots "
+            f"an edge)")
+
+
 def render(s: dict, write=print):
     if s.get("unknown_kinds"):
         write("WARNING: event kinds outside obs.EVENT_KINDS (build skew?): "
@@ -289,7 +301,8 @@ def render(s: dict, write=print):
                   f"{sp.get('tiles_fwd')} fwd / {sp.get('tiles_bwd')} bwd "
                   f"carry {sp.get('dense_edges')} edges | residual slots "
                   f"{sp.get('residual_slots_fwd')} fwd / "
-                  f"{sp.get('residual_slots_bwd')} bwd a call | "
+                  f"{sp.get('residual_slots_bwd')} bwd a call"
+                  + _slots_per_edge(sp) + " | "
                   f"{sp.get('agg_calls_per_step')} aggregations a step "
                   f"({sp.get('agg_calls_fwd')} fwd + "
                   f"{sp.get('agg_calls_bwd')} bwd)")
