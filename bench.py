@@ -70,72 +70,71 @@ def _metric_name(args) -> str:
 
 
 def _vhalo(v):
-    """Halo-exchange strategy of a variant tuple. Variants grew a 6th field
-    for the ragged exchange; 5-tuples (every pre-existing name) mean
+    """Halo-exchange strategy of a variant tuple. Variants grew a 5th field
+    for the ragged exchange; 4-tuples (every pre-existing name) mean
     'padded', so every older name stays valid."""
-    return v[5] if len(v) > 5 else "padded"
+    return v[4] if len(v) > 4 else "padded"
 
 
 def _vovl(v):
-    """Overlap mode of a variant tuple (7th field, PR 2's interior/frontier
+    """Overlap mode of a variant tuple (6th field, PR 2's interior/frontier
     split aggregation); shorter tuples mean 'off' — pre-existing names
     stay valid."""
-    return v[6] if len(v) > 6 else "off"
+    return v[5] if len(v) > 5 else "off"
 
 
 def _vrep(v):
-    """Replica-axis size of a variant tuple (8th field: the 2-D
+    """Replica-axis size of a variant tuple (7th field: the 2-D
     ('replicas','parts') mesh of parallel/replicas.py — N independently-
     BNS-sampled graph replicas, fused cross-replica gradient mean); shorter
     tuples mean 1 — pre-existing names stay valid."""
-    return v[7] if len(v) > 7 else 1
+    return v[6] if len(v) > 6 else 1
 
 
 def _vfeat(v):
-    """Feat-axis size of a variant tuple (9th field: parallel/feat.py's
+    """Feat-axis size of a variant tuple (8th field: parallel/feat.py's
     tensor axis — hidden dimensions sharded T-ways, H/T halo payloads, one
     feat psum per layer); shorter tuples mean 1 — pre-existing names
     stay valid."""
-    return v[8] if len(v) > 8 else 1
+    return v[7] if len(v) > 7 else 1
 
 
 def _vhr(v):
-    """Halo-refresh period K of a variant tuple (10th field: the staleness-
+    """Halo-refresh period K of a variant tuple (9th field: the staleness-
     bounded cached-halo reuse of parallel/halo.py — epoch 0 pays the full
     exchange, steady-state epochs redraw only chunk epoch%K, ~1/K the wire
     bytes); shorter tuples mean 1 — pre-existing names stay
     valid."""
-    return v[9] if len(v) > 9 else 1
+    return v[8] if len(v) > 8 else 1
 
 
 def _vro(v):
-    """Reorder mode of a variant tuple (11th field: the data/reorder
+    """Reorder mode of a variant tuple (10th field: the data/reorder
     LPA+FFD artifact permutation, --reorder; 'cluster' bakes the
     tile-coverage-maximizing row order into the artifact before layouts
     build); shorter tuples mean 'off' — pre-existing names
     stay valid."""
-    return v[10] if len(v) > 10 else "off"
+    return v[9] if len(v) > 9 else "off"
 
 
 def _vat(v):
-    """Auto-tune flag of a variant tuple (12th field: 'sched' runs the
+    """Auto-tune flag of a variant tuple (11th field: 'sched' runs the
     fixed coarse->fine staleness anneal of tune.bench_schedule — K=4 from
     epoch 0, K=2 at 40%, K=1 at 70% — with each retune's rebuild + compile
     epochs excluded from the mean, the bench twin of run.py's `--tune`);
     shorter tuples mean 'off' — pre-existing names stay
     valid."""
-    return v[11] if len(v) > 11 else "off"
+    return v[10] if len(v) > 10 else "off"
 
 
 def _vname(v):
-    """Candidate display/CLI name for a (spmm, use_pallas, gather_dtype,
-    dense_dtype, tile[, halo[, overlap[, replicas[, feat[, refresh[,
+    """Candidate display/CLI name for a (spmm, gather_dtype, dense_dtype,
+    tile[, halo[, overlap[, replicas[, feat[, refresh[,
     reorder[, autotune]]]]]]]) variant tuple — the vocabulary --candidates
     is written in."""
-    return (v[0] + ("+pallas" if v[1] else "")
-            + ({"fp8": "+f8g", "int8": "+i8g"}.get(v[2], ""))
-            + ("+i8d" if v[3] == "int8" else "")
-            + (f"+t{v[4]}" if v[4] != 512 else "")
+    return (v[0] + ({"fp8": "+f8g", "int8": "+i8g"}.get(v[1], ""))
+            + ("+i8d" if v[2] == "int8" else "")
+            + (f"+t{v[3]}" if v[3] != 512 else "")
             + ({"ragged": "+rag", "shift": "+shift"}.get(_vhalo(v), ""))
             + ("+ovl" if _vovl(v) == "split" else "")
             + (f"+rep{_vrep(v)}" if _vrep(v) != 1 else "")
@@ -257,36 +256,34 @@ def main():
     ap.add_argument("--candidates", type=str, default="",
                     help="comma list restricting/ordering the SpMM variants "
                          "to measure after the ell anchor (names as logged: "
-                         "hybrid, hybrid+i8g+i8d, hybrid+f8g+i8d, hybrid+f8g, "
-                         "ell+i8g, ell+f8g, hybrid+pallas, hybrid+pallas+i8g; "
+                         "hybrid, hybrid+i8g, hybrid+i8d, hybrid+i8g+i8d, "
+                         "hybrid+f8g+i8d, hybrid+f8g, ell+i8g, ell+f8g; "
                          "a +rag suffix runs the same recipe under the "
                          "exact-bytes ragged halo exchange: hybrid+rag, "
-                         "ell+rag, hybrid+pallas+rag; a +ovl suffix runs it "
-                         "with --overlap split interior/frontier "
-                         "aggregation: hybrid+ovl, ell+ovl, "
-                         "hybrid+pallas+ovl, hybrid+pallas+rag+ovl; a +repN "
-                         "suffix runs it on an (N, 1) replica mesh — N "
-                         "independently-BNS-sampled replicas, fused "
-                         "cross-replica gradient mean, needs N devices: "
-                         "hybrid+rep2, ell+rep2, hybrid+pallas+rep2, "
-                         "hybrid+pallas+rag+ovl+rep2; a +featT suffix "
+                         "ell+rag; a +ovl suffix runs it with --overlap "
+                         "split interior/frontier aggregation: hybrid+ovl, "
+                         "ell+ovl, hybrid+rag+ovl; a +repN suffix runs it "
+                         "on an (N, 1) replica mesh — N independently-"
+                         "BNS-sampled replicas, fused cross-replica "
+                         "gradient mean, needs N devices: hybrid+rep2, "
+                         "ell+rep2, hybrid+rag+ovl+rep2; a +featT suffix "
                          "shards hidden dims T-ways on the innermost feat "
                          "axis — H/T halo payloads, one psum per layer, "
                          "needs T devices: hybrid+feat2, ell+feat2, "
-                         "hybrid+pallas+feat2, hybrid+pallas+rag+ovl+feat2; "
-                         "a +hrK suffix reuses cached halos for up to K "
-                         "epochs (--halo-refresh K staleness-bounded "
-                         "refresh, ~1/K steady-state wire bytes): "
-                         "hybrid+pallas+hr2, hybrid+pallas+hr4, "
-                         "hybrid+pallas+rag+ovl+hr4; a +ro suffix bakes "
-                         "the --reorder cluster LPA+FFD row permutation "
-                         "into the artifact before layouts build — higher "
+                         "hybrid+rag+ovl+feat2; a +hrK suffix reuses "
+                         "cached halos for up to K epochs (--halo-refresh "
+                         "K staleness-bounded refresh, ~1/K steady-state "
+                         "wire bytes): hybrid+hr2, hybrid+hr4, "
+                         "hybrid+rag+ovl+hr4; a +ro suffix bakes the "
+                         "--reorder cluster LPA+FFD row permutation into "
+                         "the artifact before layouts build — higher "
                          "dense-tile coverage on low-locality graphs: "
-                         "hybrid+ro, hybrid+t256+ro, hybrid+pallas+ro, "
-                         "hybrid+pallas+t256+ro; a +at suffix runs the "
+                         "hybrid+ro, hybrid+t256+ro; a +at suffix runs the "
                          "closed-loop staleness anneal (tune.bench_schedule"
-                         ": K=4 from epoch 0, K=2 at 40%, K=1 at 70%, "
-                         "retune rebuilds untimed): hybrid+pallas+at)"
+                         ": K=4 from epoch 0, K=2 at 40%%, K=1 at 70%%, "
+                         "retune rebuilds untimed): hybrid+at; the dense "
+                         "tiles run the Pallas kernel on a TPU, and an "
+                         "older name's +pallas is dropped)"
                          ". An unknown name, or one that needs more "
                          "devices than the host has, is an error (exit 2) "
                          "before any work")
@@ -336,53 +333,53 @@ def main():
     # from the full documented name set. Candidate validation runs HERE,
     # before graph generation + artifact build, so a --candidates typo
     # exits in seconds instead of burning minutes of cold prep first.
-    # variant = (spmm, use_pallas, gather_dtype, dense_dtype, tile).
+    # variant = (spmm, gather_dtype, dense_dtype, tile).
     # MEASURED WINNERS FIRST (v5e 2026-07-30: hybrid+pallas 0.573 s/epoch,
     # hybrid 0.87, ell 1.67, i8g/f8g reduce-path variants lose) so a
     # budget-starved run still measures the best known before exploring.
-    universe = [("hybrid", True, "native", "native", 512),
+    universe = [("hybrid", "native", "native", 512),
                  # finer tiles: 4x tiles/budget-byte, less ELL residual
-                 ("hybrid", True, "native", "native", 256),
+                 ("hybrid", "native", "native", 256),
                  # fused Pallas dense + 1-byte int8-unroll residual rows
-                 ("hybrid", True, "int8", "native", 512),
-                 ("hybrid", True, "int8", "native", 256),
+                 ("hybrid", "int8", "native", 512),
+                 ("hybrid", "int8", "native", 256),
                  # int8 slabs inside the fused kernel (int8 MXU, one
                  # per-call scale) — alone and with int8 residual rows
-                 ("hybrid", True, "native", "int8", 512),
-                 ("hybrid", True, "int8", "int8", 512),
+                 ("hybrid", "native", "int8", 512),
+                 ("hybrid", "int8", "int8", 512),
                  # the full-lever endgame: finer tiles + int8 residual
                  # rows + int8 slabs
-                 ("hybrid", True, "int8", "int8", 256)]
+                 ("hybrid", "int8", "int8", 256)]
     # exact-bytes ragged halo exchange under the headline recipe: on the
     # single bench chip this measures the ragged collective's dispatch
     # cost inside the real train step (cross-chip bytes need a pod);
     # ragged_all_to_all lowered on a v5e at axis size 1 (2026-07-30)
-    universe += [("hybrid", True, "native", "native", 512, "ragged"),
+    universe += [("hybrid", "native", "native", 512, "ragged"),
                  # interior/frontier split aggregation (--overlap split):
                  # a single bench chip measures the split-layout overhead
                  # (P=1 has zero frontier rows); the latency hiding
                  # itself needs several chips
-                 ("hybrid", True, "native", "native", 512, "padded",
+                 ("hybrid", "native", "native", 512, "padded",
                   "split"),
-                 ("hybrid", True, "native", "native", 512, "ragged",
+                 ("hybrid", "native", "native", 512, "ragged",
                   "split"),
                  # replica-axis hybrid parallelism: 2 independently-
                  # BNS-sampled graph replicas on a (2, 1) mesh with the
                  # fused cross-replica gradient mean — needs >= 2 chips
                  # (left out of a default run on a 1-chip host); measures
                  # the variance-reduction recipe's wall-clock cost
-                 ("hybrid", True, "native", "native", 512, "padded",
+                 ("hybrid", "native", "native", 512, "padded",
                   "off", 2),
-                 ("hybrid", True, "native", "native", 512, "ragged",
+                 ("hybrid", "native", "native", 512, "ragged",
                   "split", 2),
                  # feat/tensor axis (parallel/feat.py): hidden dims
                  # sharded 2-ways on a (1, 1, 2) mesh — measures the
                  # per-layer feat-psum + sliced-SpMM recipe on 2 chips
                  # (the T x halo-byte win itself needs a multi-part pod);
                  # wide-hidden (--hidden 512) is where it should win
-                 ("hybrid", True, "native", "native", 512, "padded",
+                 ("hybrid", "native", "native", 512, "padded",
                   "off", 1, 2),
-                 ("hybrid", True, "native", "native", 512, "ragged",
+                 ("hybrid", "native", "native", 512, "ragged",
                   "split", 1, 2),
                  # staleness-bounded halo refresh (--halo-refresh K):
                  # steady-state epochs redraw only chunk epoch%K of each
@@ -390,55 +387,40 @@ def main():
                  # the single bench chip this measures the cached step's
                  # compute cost (plan + where-combine overhead); the
                  # ~K x wire-byte win itself needs a multi-part pod
-                 ("hybrid", True, "native", "native", 512, "padded",
+                 ("hybrid", "native", "native", 512, "padded",
                   "off", 1, 1, 2),
-                 ("hybrid", True, "native", "native", 512, "padded",
+                 ("hybrid", "native", "native", 512, "padded",
                   "off", 1, 1, 4),
-                 ("hybrid", True, "native", "native", 512, "ragged",
+                 ("hybrid", "native", "native", 512, "ragged",
                   "split", 1, 1, 4),
                  # graph reordering (--reorder cluster): the LPA+FFD
                  # artifact permutation raises dense-tile coverage
                  # before layouts build — the uniform/dcsbm-mid graphs
                  # are the headline targets
-                 ("hybrid", True, "native", "native", 512, "padded",
+                 ("hybrid", "native", "native", 512, "padded",
                   "off", 1, 1, 1, "cluster"),
-                 ("hybrid", True, "native", "native", 256, "padded",
+                 ("hybrid", "native", "native", 256, "padded",
                   "off", 1, 1, 1, "cluster"),
                  # closed-loop staleness anneal (--tune / tune.py): the
                  # fixed coarse->fine schedule K=4 -> 2 -> 1 with each
                  # retune's rebuild+compile epochs untimed — measures
                  # what a tuned run's STEADY epochs cost vs the static
                  # +hrK points on either side of the anneal
-                 ("hybrid", True, "native", "native", 512, "padded",
+                 ("hybrid", "native", "native", 512, "padded",
                   "off", 1, 1, 1, "off", "sched")]
-    universe += [("hybrid", False, "native", "native", 512),
-                 ("hybrid", False, "native", "native", 256),
-                 ("hybrid", False, "native", "int8", 512),
-                 ("hybrid", False, "int8", "int8", 512),
-                 ("hybrid", False, "fp8", "int8", 512),
-                 ("hybrid", False, "fp8", "native", 512),
-                 ("ell", False, "int8", "native", 512),
-                 ("ell", False, "fp8", "native", 512),
-                 ("hybrid", False, "native", "native", 512, "ragged"),
-                 ("ell", False, "native", "native", 512, "ragged"),
-                 ("hybrid", False, "native", "native", 512, "padded",
-                  "split"),
-                 ("hybrid", False, "native", "native", 512, "ragged",
-                  "split"),
-                 ("ell", False, "native", "native", 512, "padded", "split"),
-                 ("hybrid", False, "native", "native", 512, "padded",
-                  "off", 2),
-                 ("ell", False, "native", "native", 512, "padded", "off", 2),
-                 ("hybrid", False, "native", "native", 512, "padded",
-                  "off", 1, 2),
-                 ("ell", False, "native", "native", 512, "padded",
-                  "off", 1, 2),
-                 # XLA-dense reorder twins of the pallas +ro entries
-                 ("hybrid", False, "native", "native", 512, "padded",
-                  "off", 1, 1, 1, "cluster"),
-                 ("hybrid", False, "native", "native", 256, "padded",
-                  "off", 1, 1, 1, "cluster")]
-    anchor = ("ell", False, "native", "native", 512)
+    # the dense tiles run the Pallas kernel on every TPU run (PR 40:
+    # block_spmm.dense_path), so the XLA-dense twins of the entries above
+    # are gone; what is left has no kernel-path sibling
+    universe += [("hybrid", "fp8", "int8", 512),
+                 ("hybrid", "fp8", "native", 512),
+                 ("ell", "int8", "native", 512),
+                 ("ell", "fp8", "native", 512),
+                 ("ell", "native", "native", 512, "ragged"),
+                 ("ell", "native", "native", 512, "padded", "split"),
+                 ("ell", "native", "native", 512, "padded", "off", 2),
+                 ("ell", "native", "native", 512, "padded",
+                  "off", 1, 2)]
+    anchor = ("ell", "native", "native", 512)
     n_dev = len(jax.devices())
 
     def fits(v):
@@ -450,7 +432,10 @@ def main():
         # this host has too few devices for, is an argument error (exit 2)
         # before graph generation — never a silently shorter run
         by_name = {_vname(v): v for v in universe}
-        names = [nm.strip() for nm in args.candidates.split(",") if nm.strip()]
+        # a '+pallas' in an older name is dropped: every TPU hybrid run
+        # takes the kernel now (PR 40), so the name it kept apart is gone
+        names = [nm.strip().replace("+pallas", "")
+                 for nm in args.candidates.split(",") if nm.strip()]
         unknown = [nm for nm in names if nm not in by_name]
         too_wide = [nm for nm in names
                     if nm in by_name and not fits(by_name[nm])]
@@ -469,7 +454,7 @@ def main():
             log(f"  {n_dev} device(s): leaving out {wide}")
         candidates = [anchor] + [v for v in universe if fits(v)]
     else:
-        candidates = [(args.spmm, False, "native", "native", 512)]
+        candidates = [(args.spmm, "native", "native", 512)]
 
     if args.model == "gat":
         # GAT's hot loop is the dense per-row ELL attention (edge softmax +
@@ -506,7 +491,7 @@ def main():
     skey, dkey = jax.random.key(0), jax.random.key(1)
 
     def make_cfg(variant):
-        spmm, use_pallas, gather, dense, tile = variant[:5]
+        spmm, gather, dense, tile = variant[:4]
         cfg = Config(model=args.model,
                       halo_exchange=_vhalo(variant),
                       overlap=_vovl(variant),
@@ -518,7 +503,7 @@ def main():
                       n_layers=args.layers,
                       n_hidden=args.hidden, use_pp=True, dropout=0.5,
                       lr=0.01, sampling_rate=0.1, spmm=spmm,
-                      use_pallas=use_pallas, spmm_gather=gather,
+                      spmm_gather=gather,
                       spmm_dense=dense,
                       block_occupancy=args.occupancy,
                       block_tile_budget_mb=args.tile_budget_mb,
@@ -550,7 +535,7 @@ def main():
     def art_for(variant):
         if _vro(variant) == "off":
             return art
-        tile = variant[4]
+        tile = variant[3]
         if tile not in ro_arts:
             from bnsgcn_tpu.data.reorder import apply_reorder, compute_orders
             t0 = time.time()
@@ -588,7 +573,7 @@ def main():
             log(f"  [perf] no prediction for {_vname(variant)}: {ex.args[0]}")
         if table is not None:
             nbytes = 2 if cfg.dtype == "bfloat16" else 4
-            gb = {"int8": 1, "fp8": 1}.get(variant[2], nbytes)
+            gb = {"int8": 1, "fp8": 1}.get(variant[1], nbytes)
             slots_full = 0.0
             tiles = 0
             if v_art.ell_geometry:
@@ -618,8 +603,8 @@ def main():
                 n_apps=2 * int(cfg.n_layers), gather_slots=float(slots),
                 row_bytes=int(cfg.n_hidden) * gb,
                 gather_path="materialize",
-                dense_tiles=tiles, tile=int(variant[4]),
-                dense_path=(("pallas" if variant[1] else "xla")
+                dense_tiles=tiles, tile=int(variant[3]),
+                dense_path=(fns.spmm_counts["dense_path_fwd"]
                             if tiles else "none"),
                 wire_mb=wire_mb)
             perf_pred[_vname(variant)] = {
@@ -867,8 +852,8 @@ def main():
             # a GAT run caches under 'gat' (trainer's ELL-SpMM branch is
             # gcn/graphsage-only, so variant_key's 'ell' never appears)
             key = "gat" if args.model == "gat" else variant_key(variant)
-            if variant[1] or key in layout_cache:   # pallas + fp8 twins
-                continue                            # share the same layouts
+            if key in layout_cache:     # fp8 twins share the same layouts
+                continue
             t0 = time.time()
             build_step_fns(make_cfg(variant), spec, art_for(variant), mesh,
                            layout_cache=layout_cache)
@@ -920,7 +905,7 @@ def main():
         finally:
             persist_layouts()     # keep layouts even if compile failed
         l0 = float(built[6])      # first-step (forward-dominated) loss
-        quantized = variant[2] != "native" or variant[3] == "int8"
+        quantized = variant[1] != "native" or variant[2] == "int8"
         # multi-device variants (+repN replica mean, +featT psum-order
         # drift) are gated wider and must never become native twins —
         # 'base' strips their suffixes, so without this exclusion a
@@ -941,7 +926,7 @@ def main():
         # drift of every rung it visits, so it rides the widened gate
         # like +hrK and never becomes a native twin
         at = _vat(variant) != "off"
-        base = variant[0] + ("+pallas" if variant[1] else "")
+        base = variant[0]
         # quantized variants gate against their NATIVE TWIN (same SpMM
         # base, native gathers/tiles) at 5%: the twin isolates exactly
         # the quantizers' legitimate loss. Only when the twin wasn't

@@ -94,7 +94,6 @@ class Config:
     spmm: str = "ell"                   # 'ell' (scatter-free bucketed) | 'hybrid'
                                         # (dense int8 MXU tiles + ELL residual) | 'auto'
                                         # (estimate tile coverage, pick hybrid/ell) | 'segment'
-    use_pallas: bool = False            # use Pallas aggregation kernels where available
     spmm_gather: str = "native"         # 'native' | 'fp8' | 'int8': quantize SpMM gather rows to
                                         # e4m3 (+1 scale per call) — the gather unit is
                                         # row-rate bound, so 256B rows move ~1.5x faster
@@ -655,7 +654,11 @@ def create_parser() -> argparse.ArgumentParser:
     both("cache-dir", type=str,
          default=os.environ.get("BNSGCN_CACHE_DIR", ""))
     both("edge-chunk", type=int, default=0)
-    both("use-pallas", action="store_true", default=False)
+    # no Config field: config_from_args drops it
+    both("use-pallas", action="store_true", default=False,
+         help="no-op: the dense tiles run the Pallas kernel on every TPU "
+              "run (ops/block_spmm.dense_path); accepted until the "
+              "benchmark stops passing it")
     both("spmm-gather", type=str, default="native", choices=["native", "fp8", "int8"])
     both("spmm-dense", type=str, default="native", choices=["native", "int8"])
     both("block-occupancy", type=int, default=0)

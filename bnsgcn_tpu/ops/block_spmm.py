@@ -661,14 +661,15 @@ def _dense_apply(spec: BlockSpec, tiles, rowb, colb, perm_src, perm_out, h,
 _I8_ROW_CAP = (2**31 - 1) // (127 * 127)
 
 
-def dense_path(spec_d: BlockSpec, use_pallas: bool, dense_dtype: str) -> str:
+def dense_path(spec_d: BlockSpec, dense_dtype: str) -> str:
     """Which implementation runs one direction's dense tiles: 'pallas' (the
-    fused Mosaic kernel — `use_pallas` on a TPU backend) or 'xla'
-    (_dense_apply: any other backend, use_pallas off, or an int8 layout
-    past the accumulator bound). The ONE place the choice is made: the
-    compiled step and the run header (trainer.StepFns.spmm_desc) both read
-    it, so a log always shows what ran."""
-    if (use_pallas and jax.default_backend() == "tpu"
+    fused Mosaic kernel, ops/pallas_block) on a TPU backend, else 'xla'
+    (_dense_apply: a backend Mosaic does not lower to, or an int8 layout
+    past the kernel's int32 accumulator bound). It reads only what it can
+    observe: the backend, the slab dtype and the layout's max_row_dense.
+    The ONE place the choice is made: the compiled step and the run header
+    (trainer.dense_paths) both read it, so a log always shows what ran."""
+    if (jax.default_backend() == "tpu"
             and (dense_dtype != "int8"
                  or getattr(spec_d, "max_row_dense", 0) <= _I8_ROW_CAP)):
         return "pallas"
@@ -676,7 +677,7 @@ def dense_path(spec_d: BlockSpec, use_pallas: bool, dense_dtype: str) -> str:
 
 
 def make_block_spmm(fwd: BlockSpec, bwd: BlockSpec, ell_pair,
-                    use_pallas: bool = False, gather_dtype: str = "native",
+                    gather_dtype: str = "native",
                     dense_dtype: str = "native", accum: str = "auto"):
     """Returns spmm(arrays, h_ext) -> [n_dst, H]: dense tiles on the MXU +
     ELL residual, custom VJP running the transposed tiles.
@@ -686,14 +687,14 @@ def make_block_spmm(fwd: BlockSpec, bwd: BlockSpec, ell_pair,
     accum: residual-ELL accumulation strategy (ops/ell._bucket_sum)."""
     ell_fwd, ell_bwd = ell_pair
     ell = make_ell_spmm(ell_fwd, ell_bwd, len(ell_fwd.widths),
-                        len(ell_bwd.widths), use_pallas=use_pallas,
-                        gather_dtype=gather_dtype, accum=accum)
+                        len(ell_bwd.widths), gather_dtype=gather_dtype,
+                        accum=accum)
     # transposed residual operator for the backward: same tables with the
     # fwd/bwd roles swapped (a nested vjp at a dummy point would record an
     # unvarying primal and trip shard_map's varying-axes check)
     ell_t = make_ell_spmm(ell_bwd, ell_fwd, len(ell_bwd.widths),
-                          len(ell_fwd.widths), use_pallas=use_pallas,
-                          gather_dtype=gather_dtype, accum=accum)
+                          len(ell_fwd.widths), gather_dtype=gather_dtype,
+                          accum=accum)
 
     def _res_arrays(arrays):
         return {k[len("res_"):]: v for k, v in arrays.items()
@@ -702,7 +703,7 @@ def make_block_spmm(fwd: BlockSpec, bwd: BlockSpec, ell_pair,
     @jax.named_scope(tp.AGG_TILES)
     def _dense(spec_d, arrays, tiles_key, rowb_key, colb_key, perm_src_key,
                perm_out_key, h):
-        if dense_path(spec_d, use_pallas, dense_dtype) == "pallas":
+        if dense_path(spec_d, dense_dtype) == "pallas":
             from bnsgcn_tpu.ops.pallas_block import dense_apply_pallas
             return dense_apply_pallas(
                 spec_d, arrays[tiles_key], arrays[rowb_key], arrays[colb_key],

@@ -458,7 +458,7 @@ def _unroll_sum(hp, idx):
 
 
 def _bucket_sum(hp, idx, w, chunk_gathers: int = 4_000_000,
-                use_pallas: bool = False, accum: str = "auto"):
+                accum: str = "auto"):
     """sum over ELL width for one bucket.
 
     accum='unroll' (the TPU default for native-dtype rows): per-column
@@ -482,25 +482,18 @@ def _bucket_sum(hp, idx, w, chunk_gathers: int = 4_000_000,
     gathered intermediate never exceeds ~chunk_gathers * H elements; it
     serves fp8 gathers (their convert must happen on the gathered block;
     e4m3 decode is VPU-emulated and loses anyway) and non-TPU backends
-    (unrolled gathers lower poorly there).
-
-    use_pallas no longer affects this function (round 5): the
-    pallas_bucket_reduce dispatch was retired — superseded by the unroll,
-    never hardware-validated; the kernel remains in tools/pallas_spmm as a
-    study artifact. The parameter stays for signature stability with
-    make_ell_spmm/make_block_spmm, whose use_pallas switches the fused
-    dense-tile kernel (ops/pallas_block), which IS hardware-validated."""
+    (unrolled gathers lower poorly there)."""
     if accum not in ("auto", "unroll", "reduce"):
         raise ValueError(f"unknown accum mode {accum!r}")
     r = idx.shape[0]
     h_dim = hp.shape[1]
     if accum == "auto":
         # unroll beats BOTH the jnp chunked reduce and pallas_bucket_reduce
-        # (which only fuses the reduction, not the gather materialization),
-        # so use_pallas does not disable it — pass accum='reduce' explicitly
-        # to study the materializing paths. int8 rows unroll too (exact
-        # int32 chains, v5e-native converts); fp8 stays on reduce — e4m3
-        # decode is emulated on the VPU and measured 1.8x slower than bf16.
+        # (which only fuses the reduction, not the gather materialization);
+        # pass accum='reduce' explicitly to study the materializing paths.
+        # int8 rows unroll too (exact int32 chains, v5e-native converts);
+        # fp8 stays on reduce — e4m3 decode is emulated on the VPU and
+        # measured 1.8x slower than bf16.
         from bnsgcn_tpu.utils.platform import tpu_codepaths
         accum = ("unroll" if hp.dtype != jnp.float8_e4m3fn
                  and tpu_codepaths() else "reduce")
@@ -569,8 +562,8 @@ def ell_combine(spec: EllSpec, outs, perm, chunk_pos=None, chunk_seg=None):
 
 
 @jax.named_scope(tp.AGG_RESIDUAL)
-def _ell_apply(spec: EllSpec, idx_list, perm, h, use_pallas: bool = False,
-               chunk_pos=None, chunk_seg=None, gather_dtype: str = "native",
+def _ell_apply(spec: EllSpec, idx_list, perm, h, chunk_pos=None,
+               chunk_seg=None, gather_dtype: str = "native",
                accum: str = "auto"):
     """Bucketed gather+sum (+ split-row combine), then one permutation gather.
     The only scatter is the tiny sorted segment-sum over split-row chunks.
@@ -599,8 +592,7 @@ def _ell_apply(spec: EllSpec, idx_list, perm, h, use_pallas: bool = False,
         hp = jnp.concatenate([h, jnp.zeros((1, h.shape[1]), h.dtype)], 0)
     outs = []
     for k, w in enumerate(spec.widths):
-        outs.append(_bucket_sum(hp, idx_list[k], w, use_pallas=use_pallas,
-                                accum=accum))
+        outs.append(_bucket_sum(hp, idx_list[k], w, accum=accum))
     out = ell_combine(spec, outs, perm, chunk_pos, chunk_seg)
     if scale is not None:
         out = (out.astype(jnp.float32) * scale).astype(h.dtype)
@@ -608,8 +600,8 @@ def _ell_apply(spec: EllSpec, idx_list, perm, h, use_pallas: bool = False,
 
 
 def make_ell_spmm(fwd_spec: EllSpec, bwd_spec: EllSpec, n_buckets_fwd: int,
-                  n_buckets_bwd: int, use_pallas: bool = False,
-                  gather_dtype: str = "native", accum: str = "auto"):
+                  n_buckets_bwd: int, gather_dtype: str = "native",
+                  accum: str = "auto"):
     """Returns spmm(arrays, h_ext) -> [n_dst, H] with a custom VJP that runs
     the transposed layout (also scatter-free) on the backward pass. The
     backward quantizes the cotangent with its OWN fp8 scale when
@@ -618,7 +610,7 @@ def make_ell_spmm(fwd_spec: EllSpec, bwd_spec: EllSpec, n_buckets_fwd: int,
     @jax.custom_vjp
     def spmm(arrays, h_ext):
         idx = [arrays[f"fwd_idx_{k}"] for k in range(n_buckets_fwd)]
-        return _ell_apply(fwd_spec, idx, arrays["fwd_perm"], h_ext, use_pallas,
+        return _ell_apply(fwd_spec, idx, arrays["fwd_perm"], h_ext,
                           arrays.get("fwd_chunk_pos"), arrays.get("fwd_chunk_seg"),
                           gather_dtype=gather_dtype, accum=accum)
 
@@ -628,7 +620,7 @@ def make_ell_spmm(fwd_spec: EllSpec, bwd_spec: EllSpec, n_buckets_fwd: int,
     def bwd(res, g):
         (arrays,) = res
         idx = [arrays[f"bwd_idx_{k}"] for k in range(n_buckets_bwd)]
-        d_h = _ell_apply(bwd_spec, idx, arrays["bwd_perm"], g, use_pallas,
+        d_h = _ell_apply(bwd_spec, idx, arrays["bwd_perm"], g,
                          arrays.get("bwd_chunk_pos"), arrays.get("bwd_chunk_seg"),
                          gather_dtype=gather_dtype, accum=accum)
         return None, d_h

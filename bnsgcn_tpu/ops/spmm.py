@@ -8,8 +8,8 @@ intermediate never exceeds `edge_chunk * H` (HBM bound for 100M-edge graphs).
 
 Padded-edge convention (shared with the partition artifacts): `dst == n_dst`
 (one trash row, sliced off) and `src == 0` (value irrelevant). This module is
-the pure-XLA reference implementation; a Pallas kernel path is selected by the
-trainer when `Config.use_pallas` is set and the kernel module is present.
+the pure-XLA reference implementation; the Pallas kernel runs the hybrid
+layout's dense tiles on a TPU (ops/block_spmm.dense_path).
 """
 
 from __future__ import annotations

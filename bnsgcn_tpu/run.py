@@ -519,9 +519,9 @@ def run_training(cfg: Config, g: Optional[Graph] = None,
            f" MB at layer-0 feature width {cfg.n_feat})"))
 
     # what the step was BUILT with, as opposed to what the flags asked for:
-    # the dense-tile path that really runs (--use-pallas is the XLA twin
-    # off-TPU) and whether 'ragged' is the native collective or its
-    # all_to_all emulation — so a log always shows what ran
+    # the dense-tile path that really runs (the XLA twin off-TPU) and
+    # whether 'ragged' is the native collective or its all_to_all
+    # emulation — so a log always shows what ran
     log(f"Step: spmm {fns.spmm_desc} | halo exchange {hspec.strategy}"
         + ("" if hspec.strategy != "ragged" else
            " (native ragged_all_to_all)" if ragged_native_ok() else
@@ -575,7 +575,7 @@ def run_training(cfg: Config, g: Optional[Graph] = None,
             config={k: getattr(cfg, k) for k in (
                 "dataset", "graph_name", "model", "n_layers", "n_hidden",
                 "heads", "sampling_rate", "lr", "dtype", "spmm",
-                "use_pallas", "spmm_gather", "spmm_dense", "halo_exchange",
+                "spmm_gather", "spmm_dense", "halo_exchange",
                 "halo_wire", "halo_refresh", "halo_mode", "overlap",
                 "reorder", "tune", "tune_schedule", "tune_prior",
                 "n_epochs", "log_every", "seed",

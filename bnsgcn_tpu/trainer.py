@@ -239,7 +239,7 @@ class StepFns:
                               # the run header: resolved spmm kind and, for
                               # hybrid, the dense-tile count, their edge
                               # share and the dense path that really runs
-                              # (block_spmm.dense_path: pallas | xla)
+                              # (dense_paths: pallas | xla)
 
 
 def _local_env(spec: ModelSpec, hspec: HaloSpec, blk: dict, plan,
@@ -378,17 +378,35 @@ def agg_calls(spec: ModelSpec) -> tuple[int, int]:
     return n, n if spec.use_pp else max(n - 1, 0)
 
 
+def dense_paths(spec_pairs: Optional[dict], dense_dtype: str) -> dict:
+    """The dense-tile implementation each direction of a hybrid step runs
+    (block_spmm.dense_path, the choice the compiled step makes): 'pallas'
+    or 'xla', or both joined by '+' where an --overlap split layout's spec
+    pairs differ; 'none' without dense tiles. `spec_pairs` as in
+    _hybrid_desc."""
+    from bnsgcn_tpu.ops.block_spmm import dense_path
+    out = {}
+    for i, d in enumerate(("fwd", "bwd")):
+        paths = {dense_path(pair[i], dense_dtype)
+                 for pair in (spec_pairs or {}).values()}
+        out[d] = "+".join(sorted(paths)) or "none"
+    return out
+
+
 def spmm_counts(kind: str, spec: ModelSpec, arrays: dict, n_local: int,
                 spec_pairs: Optional[dict] = None,
-                dense_per_part=()) -> dict:
+                dense_per_part=(), dense_dtype: str = "native") -> dict:
     """Counts at the boundaries where the aggregation's work is defined, per
     part maxima: dense tiles and the edges they carry (hybrid;
-    `dense_per_part` from block_spmm.dense_edge_count), the slots the
-    residual ELL gathers (rows x width summed over buckets, padding included:
-    what ell._bucket_sum reads) and the real edges among them (slots over
-    edges is what the bucket geometry costs), and the aggregations per step.
+    `dense_per_part` from block_spmm.dense_edge_count) and the
+    implementation that runs them (`dense_paths`), the slots the residual
+    ELL gathers (rows x width summed over buckets, padding included: what
+    ell._bucket_sum reads) and the real edges among them (slots over edges
+    is what the bucket geometry costs), and the aggregations per step.
     `spec_pairs` as in _hybrid_desc."""
     out = {"path": kind}
+    for d, p in dense_paths(spec_pairs, dense_dtype).items():
+        out[f"dense_path_{d}"] = p
     for d, other in (("fwd", "bwd"), ("bwd", "fwd")):
         per_part = np.zeros(n_local, np.int64)
         for pre, pair in (spec_pairs or {}).items():
@@ -421,7 +439,6 @@ def _hybrid_desc(cfg: Config, art: PartitionArtifacts, arrays: dict,
     """Run-header text for a built hybrid layout. `spec_pairs` maps each
     array-key prefix ('' fused; 'int_'/'fro_' --overlap split) to its
     (fwd, bwd) BlockSpecs; `dense` is the edges the local tiles carry."""
-    from bnsgcn_tpu.ops.block_spmm import dense_path
     n_local = art.feat.shape[0]
     tiles = 0
     for pre, (f, _) in spec_pairs.items():
@@ -429,14 +446,9 @@ def _hybrid_desc(cfg: Config, art: PartitionArtifacts, arrays: dict,
         if rb is not None:                # pad slots carry rowb == n_row_blocks
             tiles += int((np.asarray(rb) < f.n_row_blocks).sum())
     edges = max(int((art.dst < art.pad_inner).sum()), 1)
-    paths = sorted({dense_path(d, cfg.use_pallas, cfg.spmm_dense)
-                    for pair in spec_pairs.values() for d in pair})
-    via = "+".join(paths)
-    if cfg.use_pallas and jax.default_backend() != "tpu":
-        via += (f" (--use-pallas needs the tpu backend; this is "
-                f"{jax.default_backend()})")
-    elif cfg.use_pallas and paths != ["pallas"]:
-        via += " (int8 rows past the int32 accumulator bound stay on xla)"
+    paths = dense_paths(spec_pairs, cfg.spmm_dense)
+    via = (paths["fwd"] if paths["fwd"] == paths["bwd"]
+           else f"{paths['fwd']} fwd / {paths['bwd']} bwd")
     return (f"hybrid, {tiles} dense {cfg.block_tile}x{cfg.block_tile} tiles "
             f"on {n_local} local part(s) carry {dense / edges:.1%} of "
             f"{edges} edges via {via}"
@@ -690,13 +702,13 @@ def build_step_fns(cfg: Config, spec: ModelSpec, art: PartitionArtifacts,
                 layout_cache[hyb_key] = sb
         _record_build("hybrid_split", t0_b, hyb_cached)
         (int_f, int_b, int_pair), (fro_f, fro_b, fro_pair), s_arrays, _, _ = sb
-        mk = partial(make_block_spmm, use_pallas=cfg.use_pallas)
-        split_spmms = (mk(int_f, int_b, int_pair, gather_dtype=cfg.spmm_gather,
-                          dense_dtype=cfg.spmm_dense),
-                       mk(fro_f, fro_b, fro_pair, gather_dtype=cfg.spmm_gather,
-                          dense_dtype=cfg.spmm_dense))
-        split_pre = (mk(int_f, int_b, int_pair, accum="reduce"),
-                     mk(fro_f, fro_b, fro_pair, accum="reduce"))
+        split_spmms = tuple(
+            make_block_spmm(f, b, pair, gather_dtype=cfg.spmm_gather,
+                            dense_dtype=cfg.spmm_dense)
+            for f, b, pair in ((int_f, int_b, int_pair),
+                               (fro_f, fro_b, fro_pair)))
+        split_pre = (make_block_spmm(int_f, int_b, int_pair, accum="reduce"),
+                     make_block_spmm(fro_f, fro_b, fro_pair, accum="reduce"))
         ell_arrays = dict(s_arrays)
         ell_spmm = _compose_split(split_spmms, art.pad_inner)
         ell_spmm_pre = _compose_split(split_pre, art.pad_inner)
@@ -744,7 +756,6 @@ def build_step_fns(cfg: Config, spec: ModelSpec, art: PartitionArtifacts,
         _record_build("hybrid", t0_b, hyb_cached)
         ell_arrays = dict(ell_arrays)   # never alias the cache (extra_blk is
         ell_spmm = make_block_spmm(fwd_b, bwd_b, ell_pair,  # caller-mutable)
-                                   use_pallas=cfg.use_pallas,
                                    gather_dtype=cfg.spmm_gather,
                                    dense_dtype=cfg.spmm_dense)
         # the one-time use_pp precompute always aggregates with NATIVE
@@ -753,7 +764,6 @@ def build_step_fns(cfg: Config, spec: ModelSpec, art: PartitionArtifacts,
         # v5e HBM at the raw-feature width (602) the precompute runs at
         # (round-4 measured RESOURCE_EXHAUSTED; H=256 train steps fit)
         ell_spmm_pre = make_block_spmm(fwd_b, bwd_b, ell_pair,
-                                       use_pallas=cfg.use_pallas,
                                        accum="reduce")
         ell_keys = tuple(ell_arrays.keys())
         hybrid_pairs = {"": (fwd_b, bwd_b)}
@@ -774,8 +784,7 @@ def build_step_fns(cfg: Config, spec: ModelSpec, art: PartitionArtifacts,
         (int_f, int_b), (fro_f, fro_b), s_arrays, _, _ = sb
 
         def mke(f, b, **kw):
-            return make_ell_spmm(f, b, len(f.widths), len(b.widths),
-                                 use_pallas=cfg.use_pallas, **kw)
+            return make_ell_spmm(f, b, len(f.widths), len(b.widths), **kw)
 
         split_spmms = (mke(int_f, int_b, gather_dtype=cfg.spmm_gather),
                        mke(fro_f, fro_b, gather_dtype=cfg.spmm_gather))
@@ -803,12 +812,10 @@ def build_step_fns(cfg: Config, spec: ModelSpec, art: PartitionArtifacts,
         ell_arrays = dict(ell_arrays)   # never alias the cache
         ell_spmm = make_ell_spmm(fwd_spec, bwd_spec,
                                  len(fwd_spec.widths), len(bwd_spec.widths),
-                                 use_pallas=cfg.use_pallas,
                                  gather_dtype=cfg.spmm_gather)
         ell_spmm_pre = make_ell_spmm(fwd_spec, bwd_spec,
                                      len(fwd_spec.widths),
                                      len(bwd_spec.widths),
-                                     use_pallas=cfg.use_pallas,
                                      accum="reduce")
         ell_keys = tuple(ell_arrays.keys())
     elif overlap == "split" and spec.model in ("gcn", "graphsage"):
@@ -1324,7 +1331,7 @@ def build_step_fns(cfg: Config, spec: ModelSpec, art: PartitionArtifacts,
                       else "ell" if ell_spmm is not None
                       else "gat-ell" if gat_spec is not None else "segment",
                       spec, ell_arrays, art.feat.shape[0], hybrid_pairs,
-                      dense_pp),
+                      dense_pp, cfg.spmm_dense),
                   spmm_desc=spmm_desc,
                   **refresh_fns)
     return fns, hspec, tables, tables_full

@@ -6,9 +6,9 @@ One process, no arguments, no JAX_PLATFORMS set here: it runs on whatever
 JAX finds and REFUSES (non-zero exit, no result line) unless that is a TPU.
 It drives the product's own entry point, `bnsgcn_tpu.main.main(argv)` ->
 `run_training`, at the flagship widths (GraphSAGE 602 -> 4 x 256 -> 41,
-scripts/reddit.sh) with the on-chip recipe (bf16, hybrid SpMM, the Pallas
-dense-tile kernel, BNS rate 0.1, use_pp), a dozen epochs, host eval every
---log-every, checkpoints written. Widths are not cut; the graph is
+scripts/reddit.sh) with the on-chip recipe (bf16, hybrid SpMM, whose dense
+tiles run the Pallas kernel on a TPU, BNS rate 0.1, use_pp), a dozen epochs,
+host eval every --log-every, checkpoints written. Widths are not cut; the graph is
 (`synth-reddit:0.1`: 23,296 nodes, 2.3M edges - large enough that the
 hybrid layout selects dense tiles, small enough that a cold run fits the
 chip tool's limit). Weights are random, from --fix-seed.
@@ -17,7 +17,9 @@ Phases (every one runs; any failure fails the script):
 
   kernel    ops/pallas_block.dense_apply_pallas against its XLA twin
             ops/block_spmm._dense_apply and a float64 host reference, on the
-            chip, at tile 512 and 256, H=256, bf16 and int8 slabs.
+            chip, at tile 512 and 256, H = 41, 256 and 602, bf16, float32
+            and int8 slabs (KERNEL_CASES), and on the four tile stacks of
+            an --overlap split layout.
   P=4       the recipe at --n-partitions 4, when the host has >= 4 chips
             (first, so each chip's peak memory is this run's alone).
   P=1       the recipe at --n-partitions 1.
@@ -54,13 +56,15 @@ import sys
 import time
 import traceback
 
+import numpy as np
+
 ROOT = os.path.dirname(os.path.abspath(__file__))
 WORK = os.path.join(ROOT, "smoke_work")
 
 RECIPE = ["--dataset", "synth-reddit:0.1", "--model", "graphsage",
           "--n-layers", "4", "--n-hidden", "256", "--use-pp",
           "--sampling-rate", "0.1", "--dtype", "bfloat16",
-          "--spmm", "hybrid", "--use-pallas",
+          "--spmm", "hybrid",
           "--n-epochs", "12", "--log-every", "4", "--fix-seed"]
 
 # kernel-agreement tolerances (phase `kernel`).
@@ -78,6 +82,11 @@ NATIVE_RTOL, NATIVE_ATOL = 2.0 ** -7, 2e-2
 # so the rms error over all outputs is at most scale/2 x sqrt(mean dense
 # edges per row) (+ bf16 rounding); 1.5x that is allowed.
 INT8_SLACK, INT8_RMS_SLACK = 1.05, 1.5
+# float32 slabs: each path is held to the float64 result within the rounding
+# of a one-pass bf16 MXU product (unit roundoff 2^-9 a term, so 2^-8 of the
+# sum of the terms' magnitudes) - the coarsest precision either path may
+# use for a float32 dot on the chip - and to each other by the same bound.
+F32_RTOL, F32_ATOL = 2.0 ** -8, 1e-5
 
 
 def fail(msg: str, code: int = 1):
@@ -103,18 +112,109 @@ class _Tee(io.TextIOBase):
 # phase: kernel agreement
 # ---------------------------------------------------------------------------
 
-def kernel_phase(report: dict):
+# (tile, H, slab dtype, dense dtypes): the widths a TPU run reaches (41 the
+# last layer, 256 the hidden width, 602 the use_pp precompute) in both slab
+# dtypes the recipes state (bf16: sage-reddit; float32: the products recipe)
+KERNEL_CASES = [(512, 256, "bfloat16", ("native", "int8")),
+                (256, 256, "bfloat16", ("native", "int8")),
+                (512, 256, "float32", ("native",)),
+                (256, 256, "float32", ("native",)),
+                (512, 602, "float32", ("native",)),
+                (256, 602, "bfloat16", ("native",)),
+                (512, 41, "bfloat16", ("native",)),
+                (256, 41, "float32", ("native",))]
+
+
+def _exact(tiles, rowb, colb, perm_src, perm_out, h64, tile, n_rb, n_cb):
+    """float64 host reference of one direction's dense-tile contraction, and
+    the same with every term's magnitude (what a rounding bound scales
+    with)."""
+    H = h64.shape[1]
+    x_cl = np.zeros((n_cb * tile, H))
+    x_cl[perm_src] = h64
+    flat = np.zeros(((n_rb + 1) * tile, H))
+    absflat = np.zeros_like(flat)
+    for b in range(len(rowb)):
+        t = tiles[b].astype(np.float64)
+        xs = x_cl[colb[b] * tile:(colb[b] + 1) * tile]
+        flat[rowb[b] * tile:(rowb[b] + 1) * tile] += t @ xs
+        absflat[rowb[b] * tile:(rowb[b] + 1) * tile] += t @ np.abs(xs)
+    return flat[perm_out], absflat[perm_out]
+
+
+def _agree(name, spec, args, dense, exact, absdot, deg, amax, out,
+           zero_rows=None):
+    """Run one direction's dense tiles through the kernel and its XLA twin
+    on the chip and hold both to the float64 reference."""
     import jax
     import jax.numpy as jnp
-    import numpy as np
 
-    from bnsgcn_tpu.ops.block_spmm import BlockSpec, _dense_apply
+    from bnsgcn_tpu.ops.block_spmm import _dense_apply
     from bnsgcn_tpu.ops.pallas_block import dense_apply_pallas
 
-    H, n_rows, n_src = 256, 2048, 3072
+    pal = jax.jit(lambda *a: dense_apply_pallas(spec, *a, dense_dtype=dense))
+    xla = jax.jit(lambda *a: _dense_apply(spec, *a, dense_dtype=dense))
+    if "tpu_custom_call" not in pal.lower(*args).compile().as_text():
+        raise AssertionError(f"{name}: the Pallas path compiled without a "
+                             f"Mosaic custom call")
+    got_p = np.asarray(pal(*args).astype(jnp.float32), np.float64)
+    got_x = np.asarray(xla(*args).astype(jnp.float32), np.float64)
+    if not (np.isfinite(got_p).all() and np.isfinite(got_x).all()):
+        raise AssertionError(f"{name}: non-finite output")
+    if zero_rows is not None and np.abs(got_p[zero_rows]).max() != 0:
+        raise AssertionError(
+            f"{name}: rows of the unvisited row block are not zero "
+            f"(uninitialized kernel output leaked)")
+    if dense == "int8":
+        bound = (INT8_SLACK * deg * amax / 127.0 / 2.0
+                 + 2.0 ** -7 * np.abs(exact) + NATIVE_ATOL)
+        rms_bound = (INT8_RMS_SLACK * amax / 127.0 / 2.0
+                     * np.sqrt(deg.mean())
+                     + 2.0 ** -8 * np.sqrt((exact ** 2).mean()))
+        errs = {"pallas_vs_exact": np.abs(got_p - exact) - bound,
+                "xla_vs_exact": np.abs(got_x - exact) - bound,
+                "pallas_rms": np.sqrt(((got_p - exact) ** 2).mean())
+                - rms_bound,
+                "xla_rms": np.sqrt(((got_x - exact) ** 2).mean())
+                - rms_bound}
+    else:
+        if args[-1].dtype == jnp.float32:
+            bound_px = F32_RTOL * absdot + F32_ATOL
+            bound_ex = bound_px
+        else:
+            bound_px = NATIVE_RTOL * np.abs(got_x) + NATIVE_ATOL
+            bound_ex = NATIVE_RTOL * np.abs(exact) + NATIVE_ATOL
+        errs = {"pallas_vs_xla": np.abs(got_p - got_x) - bound_px,
+                "pallas_vs_exact": np.abs(got_p - exact) - bound_ex,
+                "xla_vs_exact": np.abs(got_x - exact) - bound_ex}
+    worst = {k: float(np.max(v)) for k, v in errs.items()}
+    bad = {k: v for k, v in worst.items() if v > 0}
+    if bad:
+        raise AssertionError(f"{name}: outside tolerance by {bad}")
+    scale = float(absdot.max()) or 1.0
+    row = out[name] = {
+        "max_abs_pallas_vs_xla": float(np.abs(got_p - got_x).max()),
+        "max_abs_pallas_vs_exact": float(np.abs(got_p - exact).max()),
+        "max_abs_xla_vs_exact": float(np.abs(got_x - exact).max()),
+        "max_abs_terms": scale,
+        "rms_pallas_vs_exact": float(np.sqrt(((got_p - exact) ** 2).mean())),
+        "out_rms": float(np.sqrt((exact ** 2).mean()))}
+    print(f"[smoke] kernel {name}: pallas vs xla max "
+          f"{row['max_abs_pallas_vs_xla']:.4g}, vs exact max pallas "
+          f"{row['max_abs_pallas_vs_exact']:.4g} / xla "
+          f"{row['max_abs_xla_vs_exact']:.4g} (largest sum of |terms| "
+          f"{scale:.4g}, output rms {row['out_rms']:.3g})")
+
+
+def kernel_phase(report: dict):
+    import jax.numpy as jnp
+
+    from bnsgcn_tpu.ops.block_spmm import BlockSpec
+
+    n_rows, n_src = 2048, 3072
     out = {}
-    for tile in (512, 256):
-        rng = np.random.default_rng(tile)
+    for tile, H, slab, denses in KERNEL_CASES:
+        rng = np.random.default_rng(tile + H)
         n_rb, n_cb = n_rows // tile, n_src // tile
         # every (row block, col block) pair except row block 1, which stays
         # UNVISITED (the kernel never writes it: the caller's mask must),
@@ -129,80 +229,75 @@ def kernel_phase(report: dict):
         tiles[-2:] = 0
         perm_src = rng.permutation(n_src).astype(np.int32)
         perm_out = rng.permutation(n_rows).astype(np.int32)
-        h = jnp.asarray(rng.normal(size=(n_src, H)), jnp.bfloat16)
+        h = jnp.asarray(rng.normal(size=(n_src, H)), jnp.dtype(slab))
         row_deg = np.zeros((n_rb + 1) * tile)
         np.add.at(row_deg.reshape(n_rb + 1, tile), rowb,
                   tiles.sum(axis=2, dtype=np.int64))
         spec = BlockSpec(n_rows=n_rows, n_src=n_src, row_tile=tile,
                          col_tile=tile, n_blocks=B, n_row_blocks=n_rb,
                          max_row_dense=int(row_deg.max()))
-
-        # float64 host reference of the same contraction
         h64 = np.asarray(h.astype(jnp.float32), np.float64)
-        x_cl = np.zeros((n_cb * tile, H))
-        x_cl[perm_src] = h64
-        flat = np.zeros(((n_rb + 1) * tile, H))
-        for b in range(B):
-            flat[rowb[b] * tile:(rowb[b] + 1) * tile] += (
-                tiles[b].astype(np.float64)
-                @ x_cl[colb[b] * tile:(colb[b] + 1) * tile])
-        exact = flat[perm_out]
-        deg = row_deg[perm_out][:, None]
-        amax = float(np.abs(h64).max())
-
+        exact, absdot = _exact(tiles, rowb, colb, perm_src, perm_out, h64,
+                               tile, n_rb, n_cb)
         args = tuple(jnp.asarray(a) for a in
                      (tiles, rowb, colb, perm_src, perm_out)) + (h,)
-        for dense in ("native", "int8"):
-            pal = jax.jit(lambda *a, d=dense: dense_apply_pallas(
-                spec, *a, dense_dtype=d))
-            xla = jax.jit(lambda *a, d=dense: _dense_apply(
-                spec, *a, dense_dtype=d))
-            if "tpu_custom_call" not in pal.lower(*args).compile().as_text():
-                raise AssertionError(
-                    f"t{tile}/{dense}: the Pallas path compiled without a "
-                    f"Mosaic custom call")
-            got_p = np.asarray(pal(*args).astype(jnp.float32), np.float64)
-            got_x = np.asarray(xla(*args).astype(jnp.float32), np.float64)
-            if not (np.isfinite(got_p).all() and np.isfinite(got_x).all()):
-                raise AssertionError(f"t{tile}/{dense}: non-finite output")
-            if np.abs(got_p[perm_out // tile == 1]).max() != 0:
-                raise AssertionError(
-                    f"t{tile}/{dense}: rows of the unvisited row block are "
-                    f"not zero (uninitialized kernel output leaked)")
-            if dense == "native":
-                bound_px = NATIVE_RTOL * np.abs(got_x) + NATIVE_ATOL
-                bound_ex = NATIVE_RTOL * np.abs(exact) + NATIVE_ATOL
-                errs = {"pallas_vs_xla": np.abs(got_p - got_x) - bound_px,
-                        "pallas_vs_exact": np.abs(got_p - exact) - bound_ex,
-                        "xla_vs_exact": np.abs(got_x - exact) - bound_ex}
-            else:
-                bound = (INT8_SLACK * deg * amax / 127.0 / 2.0
-                         + 2.0 ** -7 * np.abs(exact) + NATIVE_ATOL)
-                rms_bound = (INT8_RMS_SLACK * amax / 127.0 / 2.0
-                             * np.sqrt(deg.mean())
-                             + 2.0 ** -8 * np.sqrt((exact ** 2).mean()))
-                errs = {"pallas_vs_exact": np.abs(got_p - exact) - bound,
-                        "xla_vs_exact": np.abs(got_x - exact) - bound,
-                        "pallas_rms": np.sqrt(((got_p - exact) ** 2).mean())
-                        - rms_bound,
-                        "xla_rms": np.sqrt(((got_x - exact) ** 2).mean())
-                        - rms_bound}
-            worst = {k: float(np.max(v)) for k, v in errs.items()}
-            bad = {k: v for k, v in worst.items() if v > 0}
-            if bad:
-                raise AssertionError(
-                    f"t{tile}/{dense}: outside tolerance by {bad}")
-            row = out[f"t{tile}/{dense}"] = {
-                "max_abs_pallas_vs_xla": float(np.abs(got_p - got_x).max()),
-                "max_abs_pallas_vs_exact": float(np.abs(got_p - exact).max()),
-                "rms_pallas_vs_exact": float(
-                    np.sqrt(((got_p - exact) ** 2).mean())),
-                "out_rms": float(np.sqrt((exact ** 2).mean()))}
-            print(f"[smoke] kernel t{tile}/{dense}: pallas vs xla max "
-                  f"{row['max_abs_pallas_vs_xla']:.4g}, vs exact max "
-                  f"{row['max_abs_pallas_vs_exact']:.4g} "
-                  f"(output rms {row['out_rms']:.3g})")
+        for dense in denses:
+            name = f"t{tile}/H{H}/{slab}" + ("/int8" if dense == "int8"
+                                             else "")
+            _agree(name, spec, args, dense, exact, absdot,
+                   row_deg[perm_out][:, None], float(np.abs(h64).max()),
+                   out, zero_rows=perm_out // tile == 1)
+    split_layout_check(out)
     report["kernel"] = out
+
+
+def split_layout_check(out: dict):
+    """The kernel against its twin on the four tile stacks an --overlap
+    split build lays out (interior and frontier rows, forward and
+    transposed), from a two-part partition of a clustered graph."""
+    import jax.numpy as jnp
+
+    from bnsgcn_tpu.data.artifacts import build_artifacts
+    from bnsgcn_tpu.data.graph import sbm_graph
+    from bnsgcn_tpu.data.partitioner import partition_graph
+    from bnsgcn_tpu.ops.block_spmm import (build_split_block_layouts,
+                                           cluster_order)
+
+    H, tile = 256, 512
+    g = sbm_graph(n_nodes=4096, n_class=4, n_feat=8, p_in=0.03,
+                  p_out=0.0005, seed=40)
+    art = build_artifacts(g, partition_graph(g, 2))
+    perms = [cluster_order(art.src[p], art.dst[p], art.pad_inner, art.n_ext,
+                           target=tile) for p in range(art.n_parts)]
+    (int_f, int_b, _), (fro_f, fro_b, _), arrays, _, _ = \
+        build_split_block_layouts(
+            art.src, art.dst, art.pad_inner, art.n_ext,
+            np.stack([pi for pi, _ in perms]),
+            np.stack([pe for _, pe in perms]), occupancy_min=8,
+            tile_r=tile, tile_c=tile)
+    rng = np.random.default_rng(41)
+    for pre, pair in (("int_", (int_f, int_b)), ("fro_", (fro_f, fro_b))):
+        for d, spec in zip(("fwd", "bwd"), pair):
+            a = {k[len(pre):]: np.asarray(v[0]) for k, v in arrays.items()
+                 if k.startswith(pre)}
+            src_key, out_key = (("blk_perm_ext", "blk_perm_inner")
+                                if d == "fwd" else
+                                ("blk_perm_inner", "blk_perm_ext"))
+            ops = (a[f"blk_tiles_{d}"], a[f"blk_rowb_{d}"],
+                   a[f"blk_colb_{d}"], a[src_key], a[out_key])
+            real = ops[1] < spec.n_row_blocks
+            if not real.any():
+                raise AssertionError(f"split {pre}{d}: no dense tiles")
+            h = jnp.asarray(rng.normal(size=(spec.n_src, H)), jnp.bfloat16)
+            h64 = np.asarray(h.astype(jnp.float32), np.float64)
+            n_cb = -(-spec.n_src // tile)
+            exact, absdot = _exact(ops[0], ops[1], ops[2], ops[3], ops[4],
+                                   h64, tile, spec.n_row_blocks, n_cb)
+            _agree(f"split/{pre}{d}/t{tile}/H{H}/bfloat16", spec,
+                   tuple(jnp.asarray(x) for x in ops) + (h,), "native",
+                   exact, absdot, None, float(np.abs(h64).max()), out)
+            out[f"split/{pre}{d}/t{tile}/H{H}/bfloat16"]["tiles"] = int(
+                real.sum())
 
 
 # ---------------------------------------------------------------------------
@@ -238,8 +333,6 @@ def executed_step_ops(trace_dir: str):
 
 
 def train_phase(n_parts: int, report: dict):
-    import numpy as np
-
     from bnsgcn_tpu.main import main
     from bnsgcn_tpu.utils.timers import device_memory_stats
 

@@ -88,8 +88,7 @@ def test_calibrated_anchor_through_quantized_stack(anchor_graph, exact_acc,
     the unquantized BNS anchor."""
     monkeypatch.setenv("BNSGCN_BENCH_PREFLIGHT", "1")
     acc_q = train_eval(anchor_graph, P=4, rate=0.1, epochs=EPOCHS,
-                       spmm="hybrid", use_pallas=True,
-                       spmm_gather="int8", spmm_dense="int8",
+                       spmm="hybrid", spmm_gather="int8", spmm_dense="int8",
                        halo_wire="int8")
     assert abs(acc_q - exact_acc) <= 0.005, (acc_q, exact_acc)
 

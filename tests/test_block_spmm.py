@@ -153,6 +153,12 @@ def test_hybrid_train_step_matches_ell():
                      norm="layer", n_train=g.n_train, lr=0.01,
                      sampling_rate=0.5, spmm=spmm)
         fns, hspec, tables, tables_full = build_step_fns(cfg, spec, art, mesh)
+        # the run header's counters: the XLA twin off the TPU, no tiles
+        # at all on the pure ELL path
+        via = "xla" if spmm == "hybrid" else "none"
+        assert (fns.spmm_counts["dense_path_fwd"],
+                fns.spmm_counts["dense_path_bwd"]) == (via, via)
+        assert (f"via {via}," in fns.spmm_desc) == (spmm == "hybrid")
         blk_np = build_block_arrays(art, "graphsage")
         blk_np.update(fns.extra_blk)
         for k in fns.drop_blk_keys:
@@ -173,34 +179,136 @@ def test_hybrid_train_step_matches_ell():
                  results["hybrid"][1], results["ell"][1])
 
 
-@pytest.mark.parametrize("dense_dtype", ["native", "int8"])
-def test_pallas_tile_matmul_matches_xla(dense_dtype):
+def _tile_stack(tile, H, slab, seed=0):
+    """A stacked dense-tile layout as the builder lays it out, with what a
+    real one can hold: 4 row blocks x 3 column blocks, every pair but row
+    block 1's (UNVISITED: the kernel never writes it, the caller's mask
+    must), then two pad-only tiles (rowb == n_row_blocks, all zero)."""
+    from bnsgcn_tpu.ops.block_spmm import BlockSpec
+    rng = np.random.default_rng(seed)
+    n_rb, n_cb = 4, 3
+    rb, cb = np.meshgrid(np.arange(n_rb), np.arange(n_cb), indexing="ij")
+    keep = rb.ravel() != 1
+    rowb = np.concatenate([rb.ravel()[keep], [n_rb, n_rb]]).astype(np.int32)
+    colb = np.concatenate([cb.ravel()[keep], [0, 0]]).astype(np.int32)
+    B = len(rowb)
+    tiles = ((rng.random((B, tile, tile)) < 0.02)
+             * rng.integers(1, 4, (B, tile, tile))).astype(np.int8)
+    tiles[-2:] = 0
+    spec = BlockSpec(n_rows=n_rb * tile, n_src=n_cb * tile, row_tile=tile,
+                     col_tile=tile, n_blocks=B, n_row_blocks=n_rb,
+                     max_row_dense=int(tiles.sum(axis=2).max()))
+    perm_src = rng.permutation(spec.n_src).astype(np.int32)
+    perm_out = rng.permutation(spec.n_rows).astype(np.int32)
+    h = jnp.asarray(rng.normal(size=(spec.n_src, H)), slab)
+    unvisited = (perm_out >= tile) & (perm_out < 2 * tile)
+    return spec, tuple(map(jnp.asarray, (tiles, rowb, colb, perm_src,
+                                         perm_out))), h, unvisited
+
+
+# (layout, dense_dtype, slab dtype, H, tile): the sbm graph's own layout,
+# and stacks at the widths and tiles a TPU run reaches (H = 41, 256 and
+# 602: the last layer, the hidden width, the use_pp precompute), in both
+# slab dtypes the recipes state
+TILE_CASES = [("sbm", "native", "float32", 7, 512),
+              ("sbm", "int8", "float32", 7, 512),
+              ("stack", "native", "float32", 41, 512),
+              ("stack", "native", "float32", 256, 256),
+              ("stack", "native", "float32", 602, 512),
+              ("stack", "native", "bfloat16", 41, 256),
+              ("stack", "native", "bfloat16", 256, 512),
+              ("stack", "native", "bfloat16", 602, 256),
+              ("stack", "int8", "float32", 256, 512),
+              ("stack", "int8", "bfloat16", 41, 256)]
+
+
+@pytest.mark.parametrize("layout,dense_dtype,slab,H,tile", TILE_CASES)
+def test_pallas_tile_matmul_matches_xla(layout, dense_dtype, slab, H, tile):
     """The fused Pallas grouped-matmul (interpret mode off-TPU) == the XLA
     dense-tile path; the int8 variant quantizes with one per-call scale so
-    it gets the quantization tolerance against the NATIVE reference."""
+    it gets the quantization tolerance against the NATIVE reference. A
+    row block no tile maps to reads zero."""
     from bnsgcn_tpu.ops.block_spmm import _dense_apply
     from bnsgcn_tpu.ops.pallas_block import dense_apply_pallas
 
-    g = sbm_graph(n_nodes=300, n_class=5, n_feat=6, p_in=0.15, p_out=0.003,
-                  seed=67)
-    art = build_artifacts(g, partition_graph(g, 2, method="random", seed=3))
-    fwd, bwd, ell_pair, arrays = _hybrid_for(art, 4)
-    assert dense_edge_count(arrays, 0) > 0
-    rng = np.random.default_rng(3)
-    h = jnp.asarray(rng.normal(size=(art.n_ext, 7)), jnp.float32)
-    a = {k: jnp.asarray(v[0]) for k, v in arrays.items()}
-    ref = _dense_apply(fwd, a["blk_tiles_fwd"], a["blk_rowb_fwd"],
-                       a["blk_colb_fwd"], a["blk_perm_ext"],
-                       a["blk_perm_inner"], h)
-    got = dense_apply_pallas(fwd, a["blk_tiles_fwd"], a["blk_rowb_fwd"],
-                             a["blk_colb_fwd"], a["blk_perm_ext"],
-                             a["blk_perm_inner"], h,
-                             dense_dtype=dense_dtype, interpret=True)
-    tol = (dict(rtol=1e-4, atol=1e-4) if dense_dtype == "native"
-           else dict(atol=0.05 * float(np.abs(np.asarray(ref)).max())))
-    np.testing.assert_allclose(np.asarray(got), np.asarray(ref), **tol)
+    slab = jnp.dtype(slab)
+    if layout == "sbm":
+        g = sbm_graph(n_nodes=300, n_class=5, n_feat=6, p_in=0.15,
+                      p_out=0.003, seed=67)
+        art = build_artifacts(g, partition_graph(g, 2, method="random",
+                                                 seed=3))
+        fwd, bwd, ell_pair, arrays = _hybrid_for(art, 4, tile=tile)
+        assert dense_edge_count(arrays, 0) > 0
+        a = {k: jnp.asarray(v[0]) for k, v in arrays.items()}
+        ops = (a["blk_tiles_fwd"], a["blk_rowb_fwd"], a["blk_colb_fwd"],
+               a["blk_perm_ext"], a["blk_perm_inner"])
+        h = jnp.asarray(np.random.default_rng(3).normal(
+            size=(art.n_ext, H)), slab)
+        unvisited = None
+    else:
+        fwd, ops, h, unvisited = _tile_stack(tile, H, slab, seed=tile + H)
+    ref = np.asarray(_dense_apply(fwd, *ops, h), np.float32)
+    got = np.asarray(dense_apply_pallas(fwd, *ops, h, dense_dtype=dense_dtype,
+                                        interpret=True), np.float32)
+    amax = float(np.abs(ref).max())
     if dense_dtype == "int8":
-        assert not np.allclose(np.asarray(got), np.asarray(ref))  # quantized
+        tol = dict(atol=0.05 * amax)
+    elif slab == jnp.float32:
+        tol = dict(rtol=1e-4, atol=1e-4 * amax)
+    else:
+        # both accumulate in f32 and round to bf16 once: they differ by the
+        # f32 summation order, at most one bf16 ulp
+        tol = dict(rtol=2.0 ** -7, atol=1e-3 * amax)
+    np.testing.assert_allclose(got, ref, **tol)
+    if unvisited is not None:
+        assert unvisited.any() and not got[unvisited].any()
+    if dense_dtype == "int8":
+        assert not np.allclose(got, ref)  # quantized
+
+
+@pytest.mark.parametrize("backend,dense_dtype,row_dense,want", [
+    ("tpu", "native", 0, "pallas"),
+    ("tpu", "native", 10**6, "pallas"),
+    ("tpu", "int8", 1000, "pallas"),
+    ("tpu", "int8", 10**6, "xla"),       # past the int32 accumulator bound
+    ("cpu", "native", 0, "xla"),
+    ("cpu", "int8", 1000, "xla")])
+def test_dense_path_reads_only_what_it_observes(monkeypatch, backend,
+                                                dense_dtype, row_dense, want):
+    """The kernel on every TPU run, with no flag; the XLA twin where Mosaic
+    does not lower (the CPU) or an int8 row could wrap the kernel's int32
+    accumulator. The run header's counters read the same choice."""
+    from bnsgcn_tpu.ops import block_spmm as bs
+    from bnsgcn_tpu.trainer import dense_paths
+    assert (row_dense > bs._I8_ROW_CAP) == (row_dense == 10**6)
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    spec = bs.BlockSpec(n_rows=512, n_src=512, row_tile=512, col_tile=512,
+                        n_blocks=1, n_row_blocks=1, max_row_dense=row_dense)
+    small = bs.BlockSpec(n_rows=512, n_src=512, row_tile=512, col_tile=512,
+                         n_blocks=1, n_row_blocks=1, max_row_dense=1)
+    assert bs.dense_path(spec, dense_dtype) == want
+    assert dense_paths({"": (spec, spec)}, dense_dtype) == {"fwd": want,
+                                                            "bwd": want}
+    # --overlap split: the set over its spec pairs
+    other = bs.dense_path(small, dense_dtype)
+    split = dense_paths({"int_": (spec, small), "fro_": (small, small)},
+                        dense_dtype)
+    assert split == {"fwd": "+".join(sorted({want, other})), "bwd": other}
+    assert dense_paths(None, dense_dtype) == {"fwd": "none", "bwd": "none"}
+
+
+@pytest.mark.parametrize("at", ["first", "last"])
+def test_pallas_flag_is_a_noop(at):
+    """--use-pallas still parses (the benchmark's whole.p1 cell passes it)
+    and changes nothing: the dense-tile path is dense_path's alone."""
+    import dataclasses
+    from bnsgcn_tpu.config import parse_config
+    base = ["--spmm", "hybrid", "--dtype", "bfloat16"]
+    argv = (["--use-pallas"] + base if at == "first"
+            else base + ["--use-pallas"])
+    cfg = parse_config(argv)
+    assert cfg == parse_config(base)
+    assert not any("pallas" in f.name for f in dataclasses.fields(cfg))
 
 
 def test_cluster_order_is_permutation():

@@ -476,6 +476,8 @@ def test_obs_report_spmm_counts_and_trace_clock():
     import obs_report
     events = [{"ts": 90.0, "kind": "run_header", "rank": 0, "config": {},
                "spmm": {"path": "hybrid", "tiles_fwd": 190, "tiles_bwd": 188,
+                        "dense_path_fwd": "pallas",
+                        "dense_path_bwd": "pallas",
                         "dense_edges": 669167, "residual_slots_fwd": 220512,
                         "residual_slots_bwd": 219424,
                         "residual_edges_fwd": 200000,
@@ -489,17 +491,20 @@ def test_obs_report_spmm_counts_and_trace_clock():
     out = []
     obs_report.render(obs_report.summarize(events), write=out.append)
     spmm = next(ln for ln in out if ln.startswith("spmm: "))
-    assert spmm == ("spmm: hybrid | dense tiles 190 fwd / 188 bwd carry "
-                    "669167 edges | residual slots 220512 fwd / 219424 bwd a "
-                    "call for 200000 / 200000 edges (1.103 / 1.097 slots an "
-                    "edge) | 6 aggregations a step (3 fwd + 3 bwd)")
-    # a header written before the edges were counted keeps its old line
+    assert spmm == ("spmm: hybrid | dense tiles 190 fwd / 188 bwd via pallas "
+                    "carry 669167 edges | residual slots 220512 fwd / 219424 "
+                    "bwd a call for 200000 / 200000 edges (1.103 / 1.097 "
+                    "slots an edge) | 6 aggregations a step (3 fwd + 3 bwd)")
+    # a header written before the edges and the paths were counted keeps
+    # its old line
     for d in ("fwd", "bwd"):
         del events[0]["spmm"][f"residual_edges_{d}"]
+        del events[0]["spmm"][f"dense_path_{d}"]
     out = []
     obs_report.render(obs_report.summarize(events), write=out.append)
-    assert "slots 220512 fwd / 219424 bwd a call | 6 aggregations" in next(
-        ln for ln in out if ln.startswith("spmm: "))
+    spmm = next(ln for ln in out if ln.startswith("spmm: "))
+    assert "188 bwd carry 669167 edges" in spmm
+    assert "slots 220512 fwd / 219424 bwd a call | 6 aggregations" in spmm
     laid = out[out.index(next(ln for ln in out
                               if ln.startswith("trace @E9"))) + 1]
     assert laid.strip() == ("window opened at 102.75 (wall clock); epoch "

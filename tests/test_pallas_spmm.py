@@ -62,9 +62,8 @@ def test_ell_accum_modes_agree():
     """The ELL accumulation strategies must be numerically interchangeable:
     'unroll' (the TPU/headline path, forced here via accum) vs 'reduce'
     (the fp8/off-TPU materializing path). Replaces the retired
-    use_pallas-vs-jnp comparison, which became vacuous once the
-    pallas_bucket_reduce dispatch was removed from _bucket_sum (round 5 —
-    use_pallas now switches only the fused dense-tile kernel)."""
+    Pallas-vs-jnp comparison, which became vacuous once the
+    pallas_bucket_reduce dispatch was removed from _bucket_sum (round 5)."""
     g = synthetic_graph(n_nodes=40, avg_degree=5, n_feat=4, seed=7)
     art = build_artifacts(g, partition_graph(g, 1))
     fs, bs, arrays = build_layouts(art.src, art.dst, art.pad_inner, art.n_ext)

@@ -220,6 +220,8 @@ def test_run_header_counts_equal_the_layout_arrays(tiny_run):
     events, fns, _ = tiny_run
     head = next(e for e in events if e["kind"] == "run_header")["spmm"]
     assert head["path"] == "ell" and head["tiles_fwd"] == 0
+    assert (head["dense_path_fwd"], head["dense_path_bwd"]) == ("none",
+                                                                "none")
     # a padded slot holds the index of the zero row appended to what the
     # table gathers from: the extended rows forward, the owned rows backward
     zero_row = {"fwd": fns.extra_blk["bwd_perm"].shape[1],

@@ -54,9 +54,8 @@ def _biased_pair_sample():
 
 def train_eval(g, P, rate, epochs=120, n_hidden=32, n_layers=3, seed=5,
                break_rescale=False, biased_sampler=False, lr=0.01,
-               norm=None, use_pp=False, spmm="ell", use_pallas=False,
-               spmm_gather="native", spmm_dense="native",
-               halo_wire="native"):
+               norm=None, use_pp=False, spmm="ell", spmm_gather="native",
+               spmm_dense="native", halo_wire="native"):
     """Train a GraphSAGE on graph g over a P-part mesh at BNS `rate`;
     return full-rate eval-mode validation accuracy.
 
@@ -75,9 +74,8 @@ def train_eval(g, P, rate, epochs=120, n_hidden=32, n_layers=3, seed=5,
                  norm=norm or "none",
                  n_train=g.n_train, lr=lr, sampling_rate=rate,
                  n_feat=g.n_feat, n_hidden=n_hidden, n_layers=n_layers,
-                 n_class=g.n_class, spmm=spmm, use_pallas=use_pallas,
-                 spmm_gather=spmm_gather, spmm_dense=spmm_dense,
-                 halo_wire=halo_wire)
+                 n_class=g.n_class, spmm=spmm, spmm_gather=spmm_gather,
+                 spmm_dense=spmm_dense, halo_wire=halo_wire)
     sizes = (g.n_feat,) + (n_hidden,) * (n_layers - 1) + (g.n_class,)
     spec = ModelSpec("graphsage", sizes, norm=norm, dropout=0.1,
                      use_pp=use_pp, train_size=g.n_train)

@@ -265,6 +265,16 @@ def _slots_per_edge(sp: dict) -> str:
             f"an edge)")
 
 
+def _dense_via(sp: dict) -> str:
+    """The dense-tile implementation each direction ran (pallas | xla);
+    empty for a header written before the count."""
+    paths = [sp.get(f"dense_path_{d}") for d in ("fwd", "bwd")]
+    if None in paths:
+        return ""
+    return (f" via {paths[0]}" if paths[0] == paths[1]
+            else f" via {paths[0]} fwd / {paths[1]} bwd")
+
+
 def render(s: dict, write=print):
     if s.get("unknown_kinds"):
         write("WARNING: event kinds outside obs.EVENT_KINDS (build skew?): "
@@ -298,8 +308,9 @@ def render(s: dict, write=print):
             # maxima): what a traced second under `agg_tiles` /
             # `agg_residual` is a rate of
             write(f"spmm: {sp.get('path')} | dense tiles "
-                  f"{sp.get('tiles_fwd')} fwd / {sp.get('tiles_bwd')} bwd "
-                  f"carry {sp.get('dense_edges')} edges | residual slots "
+                  f"{sp.get('tiles_fwd')} fwd / {sp.get('tiles_bwd')} bwd"
+                  + _dense_via(sp) + f" carry {sp.get('dense_edges')} "
+                  "edges | residual slots "
                   f"{sp.get('residual_slots_fwd')} fwd / "
                   f"{sp.get('residual_slots_bwd')} bwd a call"
                   + _slots_per_edge(sp) + " | "

@@ -11,9 +11,9 @@ lives in tools/ (not the importable bnsgcn_tpu package) so the default test
 tier and the training import graph never pay for it. The unrolled
 column-chain accumulation (ops/ell._bucket_sum accum='unroll') beat the
 materializing reduce this kernel fuses by 1.9x on the v5e cap bucket and
-set the 0.573 s/epoch headline, so the `use_pallas` dispatch to
-`pallas_bucket_reduce` was retired; `use_pallas` now switches only the
-fused dense-tile kernel (ops/pallas_block), which is hardware-validated.
+set the 0.573 s/epoch headline, so the dispatch to `pallas_bucket_reduce`
+was retired; the one Pallas kernel on the training path is the fused
+dense-tile kernel (ops/pallas_block), which is hardware-validated.
 Kept for two findings a later session may build on: (a) the remote
 compiler of the July 2026 sessions rejected *any* manual-DMA kernel (even a
 minimal fixed-row `make_async_copy` one) - that compiler is gone and the
