@@ -13,6 +13,9 @@ Reference math preserved exactly:
   * GraphSAGE: linear1(h_self) + linear2(sum(h_nbr)/in_deg) with the *global*
     in-degree (module/layer.py:79-103, train.py:380); use_pp layer 0 is a
     single Linear(2*in, out) over the precomputed [feat, mean_nbr] concat.
+  * A GCN / GraphSAGE layer that narrows (`projects_first`) runs its
+    linear's matmul before the aggregation instead of after: the same sum,
+    fewer columns gathered; the bias still comes after.
   * GAT: DGL-GATConv equivalent (shared fc, additive attention, leaky_relu 0.2,
     edge softmax, feat/attn dropout, bias), mean over heads
     (module/model.py:102,111-132). Absent sampled halos are removed from the
@@ -118,7 +121,9 @@ class GraphEnv:
                                        # sees the whole graph). None/1 = the
                                        # historical parts-only reduction.
     agg_exchange: Optional[Callable] = None
-    # agg_exchange(layer, h [n_dst, d], scale_out_norm) -> [n_dst, d]:
+    # agg_exchange(layer, h [n_dst, d], scale_out_norm, w) -> [n_dst, d']:
+    # (w as in env_agg_exchange: None, or the projection a narrowing layer
+    # applies to both source sides after the exchange)
     # fused exchange + sum-aggregation override (--overlap split re-threads
     # the layer body as start-exchange -> interior-agg -> finish-exchange ->
     # frontier-agg -> merge through this seam). None = the historical
@@ -142,19 +147,31 @@ def env_agg_sum(env: "GraphEnv", h_ext: jax.Array) -> jax.Array:
     return agg_sum(h_ext, env.src, env.dst, env.n_dst, env.edge_chunk)
 
 
-def env_agg_exchange(env: "GraphEnv", i: int, h: jax.Array,
-                     scale_out_norm: bool = False) -> jax.Array:
-    """One layer's exchange + sum-aggregation: h [n_dst, d] -> [n_dst, d].
+@jax.named_scope(tp.LINEAR)
+def project(h: jax.Array, w: jax.Array) -> jax.Array:
+    """h @ w on the rows a narrowing layer aggregates (see projects_first)."""
+    return h @ w
 
+
+def env_agg_exchange(env: "GraphEnv", i: int, h: jax.Array,
+                     scale_out_norm: bool = False,
+                     w: Optional[jax.Array] = None) -> jax.Array:
+    """One layer's exchange + sum-aggregation: h [n_dst, d] -> [n_dst, d]
+    ([n_dst, w.shape[1]] with `w`).
+
+    `w` projects the extended rows AFTER the exchange and before the
+    aggregation, so the halo sees what it sees without it.
     `scale_out_norm` divides the extended rows by env.out_norm BEFORE
     aggregating (the GCN symmetric norm, module/layer.py:26-46). Default
     path is the historical fused exchange-then-aggregate, op for op; when
     `env.agg_exchange` is set (--overlap split), it runs the interior/
     frontier split so the collective overlaps interior compute."""
     if env.agg_exchange is not None:
-        return env.agg_exchange(i, h, scale_out_norm)
+        return env.agg_exchange(i, h, scale_out_norm, w)
     with jax.named_scope(tp.HALO_EXCHANGE):
         h_ext, _ = env.exchange(i, h)
+    if w is not None:
+        h_ext = project(h_ext, w)
     if scale_out_norm:
         h_ext = (h_ext / env.out_norm[:, None]).astype(h_ext.dtype)
     return env_agg_sum(env, h_ext)
@@ -365,22 +382,58 @@ def _linear(p, h):
     return h @ p["w"] + p["b"]
 
 
-def _gcn_layer(p, i, h, env: GraphEnv):
-    """Symmetric-norm SpMM then linear (module/layer.py:26-46).
+def projects_first(spec: ModelSpec, i: int) -> bool:
+    """Whether GCN / GraphSAGE graph layer i aggregates on its narrow side:
+    agg(h W) / norm + b in place of (agg(h) / norm) W + b, the same sum in
+    the other order, gathering fout columns a row instead of fin.
+
+    A layer whose input holds a parameter aggregates once forward and once
+    backward in a step in either order, so it projects first where
+    fout < fin. Layer 0 without use_pp aggregates the features, which hold
+    none: its wide order needs no backward aggregation and projecting first
+    adds one at fout, so it projects first only where 2 fout < fin. The
+    use_pp layer 0 (a matmul in training, a concat in eval), GAT and the
+    dense tail never do."""
+    if (spec.model not in ("gcn", "graphsage") or i >= spec.n_graph_layers
+            or (spec.use_pp and i == 0)):
+        return False
+    fin, fout = spec.layer_sizes[i], spec.layer_sizes[i + 1]
+    return 2 * fout < fin if i == 0 else fout < fin
+
+
+def _linear_after_agg(p, s, projected: bool):
+    """The layer's linear on the aggregated rows `s`: only its bias where
+    the rows were projected before the aggregation (a row with no
+    in-neighbour reads b either way)."""
+    if not projected:
+        return _linear(p, s)
+    with jax.named_scope(tp.LINEAR):
+        return s + p["b"]
+
+
+def _gcn_layer(p, i, h, env: GraphEnv, narrow: bool):
+    """Symmetric-norm SpMM then linear (module/layer.py:26-46); with
+    `narrow` (projects_first) the linear's matmul goes before the SpMM.
 
     Degree norms are f32; divisions happen in f32 but the result is cast back
     to the activation dtype so the (bytes-bound) gather stays bf16 in bf16 runs.
     The exchange rides inside env_agg_exchange so --overlap split can run the
     collective concurrently with the interior rows' aggregation.
     """
-    s = env_agg_exchange(env, i, h, scale_out_norm=True)
-    return _linear(p, (s / env.in_norm[:, None]).astype(h.dtype))
+    s = env_agg_exchange(env, i, h, scale_out_norm=True,
+                         w=p["w"] if narrow else None)
+    return _linear_after_agg(p, (s / env.in_norm[:, None]).astype(h.dtype),
+                             narrow)
 
 
-def _sage_layer(p, i, h, env: GraphEnv):
-    """linear1(self) + linear2(sum(nbrs)/in_deg) (module/layer.py:79-92)."""
-    ah = (env_agg_exchange(env, i, h) / env.in_norm[:, None]).astype(h.dtype)
-    return _linear(p["linear1"], h[:env.n_dst]) + _linear(p["linear2"], ah)
+def _sage_layer(p, i, h, env: GraphEnv, narrow: bool):
+    """linear1(self) + linear2(sum(nbrs)/in_deg) (module/layer.py:79-92);
+    with `narrow` (projects_first) linear2's matmul goes before the sum."""
+    p2 = p["linear2"]
+    ah = (env_agg_exchange(env, i, h, w=p2["w"] if narrow else None)
+          / env.in_norm[:, None]).astype(h.dtype)
+    return (_linear(p["linear1"], h[:env.n_dst])
+            + _linear_after_agg(p2, ah, narrow))
 
 
 @jax.named_scope(tp.ATTENTION)
@@ -509,13 +562,13 @@ def _layer_forward(h, *, i, params, state, spec: ModelSpec, env: GraphEnv, rng):
             # precomputed layer 0: pure dense matmul (module/layer.py:29-30,83-84)
             h = _linear(p, h)
         elif spec.model == "gcn":
-            h = _gcn_layer(p, i, h, env)
+            h = _gcn_layer(p, i, h, env, projects_first(spec, i))
         elif (not env.training) and spec.use_pp and i == 0:
             # eval pp layer 0: cat(feat, mean) @ W  (module/layer.py:99-100)
             ah = env_agg_exchange(env, i, h) / env.in_norm[:, None]
             h = _linear(p, jnp.concatenate([h[:env.n_dst], ah], 1))
         else:
-            h = _sage_layer(p, i, h, env)
+            h = _sage_layer(p, i, h, env, projects_first(spec, i))
     elif spec.model == "gat":
         out_feats = spec.layer_sizes[i + 1]
         if is_graph_layer:

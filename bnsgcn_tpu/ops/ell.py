@@ -423,14 +423,29 @@ def build_split_layouts(src_all: np.ndarray, dst_all: np.ndarray, n_dst: int,
     return (int_f, int_b), (fro_f, fro_b), arrays, n_int_pad, n_fro_pad
 
 
+# hp[i] for an index vector i [r, 1]: one whole row of hp per index
+_ROW_GATHER = jax.lax.GatherDimensionNumbers(
+    offset_dims=(1,), collapsed_slice_dims=(0,), start_index_map=(0,))
+
+
+def _gather_rows(hp, col):
+    """The gather jnp's `hp[i]` lowers to, without the compare/add/select
+    that wraps negative indices: a layout's indices lie in [0, n_src] by
+    construction (row n_src is the zero row `_ell_apply` appends), and
+    half the cost of tracing the jnp spelling is that wrap."""
+    return jax.lax.gather(
+        hp, col, _ROW_GATHER, (1, hp.shape[1]),
+        mode=jax.lax.GatherScatterMode.PROMISE_IN_BOUNDS)
+
+
 @jax.jit
 def _unroll_sum(hp, idx):
     """_bucket_sum's unroll path. Jitted so that the step traces a bucket
-    shape once and not once a call: jnp's indexing is slow to trace (about
-    11 ms a gather on the v5e's host, PR 28), a step holds every bucket's
-    chains 3 + 3 times, and the 16-step ladder has ten buckets where the
-    power-of-two one had six. XLA inlines the calls: the program is the one
-    the inline code gave, each copy under its caller's scope names."""
+    shape once and not once a call (a step holds every bucket's chains at
+    each aggregation width, forward and backward), and spelled in
+    `lax.gather` because jnp's indexing is slow to trace (about 11 ms a
+    gather on the v5e's host). XLA inlines the calls: the program is
+    the one the inline code gave, each copy under its caller's scope names."""
     (r, w), h_dim, BS = idx.shape, hp.shape[1], ELL_BLOCK
     # int8 rows accumulate in int32 (exact, like the reduce path's
     # int32 sums — the caller's one per-call scale multiplies back
@@ -439,14 +454,18 @@ def _unroll_sum(hp, idx):
     out_dt = jnp.int32 if hp.dtype == jnp.int8 else hp.dtype
 
     def chain(cb, n):
-        a = hp[cb[0]].astype(acc_dt)
+        # cb [n, r, 1]: one index column a gather
+        a = _gather_rows(hp, jax.lax.index_in_dim(cb, 0, keepdims=False)
+                         ).astype(acc_dt)
         for j in range(1, n):
-            a = a + hp[cb[j]].astype(acc_dt)
+            a = a + _gather_rows(
+                hp, jax.lax.index_in_dim(cb, j, keepdims=False)
+            ).astype(acc_dt)
         return a
 
     if w <= BS:
-        return chain(idx.T, w).astype(out_dt)
-    cols = idx.T.reshape(w // BS, BS, r)
+        return chain(idx.T[:, :, None], w).astype(out_dt)
+    cols = idx.T.reshape(w // BS, BS, r, 1)
     # derive the init from the input so the carry has the same varying
     # manual axes as the body output under shard_map (same contract as
     # block_spmm._dense_apply's acc0); the empty slice reads no data

@@ -35,7 +35,8 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from bnsgcn_tpu.config import Config, ConfigError
 from bnsgcn_tpu.data.artifacts import PartitionArtifacts
-from bnsgcn_tpu.models.gnn import GraphEnv, ModelSpec, apply_model, init_params
+from bnsgcn_tpu.models.gnn import (GraphEnv, ModelSpec, apply_model,
+                                   init_params, project, projects_first)
 from bnsgcn_tpu.ops.spmm import agg_sum
 from bnsgcn_tpu.parallel.halo import (HaloSpec, full_rate_spec, halo_apply,
                                       halo_finish, halo_start,
@@ -366,16 +367,35 @@ def _cluster_perms(art: PartitionArtifacts, cfg: Config):
 _ELL_IDX_KEY = re.compile(r"^((?:int_|fro_)?(?:res_)?)(fwd|bwd)_idx_\d+$")
 
 
-def agg_calls(spec: ModelSpec) -> tuple[int, int]:
-    """(forward, backward) sum-aggregations one train step runs: one per
-    GCN / GraphSAGE graph layer that aggregates (the precomputed layer 0 of
-    use_pp is a pure matmul), and in the backward one per such layer whose
-    input depends on a parameter (layer 0's never does). GAT aggregates
-    inside its attention."""
+def agg_widths(spec: ModelSpec, n_feat: int = 1
+               ) -> tuple[list[int], list[int], list[dict]]:
+    """The columns each sum-aggregation of one train step gathers, forward
+    and backward, in layer order, and the layers that project before they
+    aggregate ({layer, fin, fout}). One aggregation per GCN / GraphSAGE
+    graph layer that aggregates (the precomputed layer 0 of use_pp is a
+    pure matmul), at its output width where it projects first
+    (models/gnn.projects_first), else at its input width (a feat-sharded
+    layer's slice of it, always in the wide order); backward, one per such
+    layer whose aggregated rows depend on a parameter (layer 0's features
+    do not, unless it projects first). GAT aggregates inside its
+    attention."""
+    fwd, bwd, narrow_layers = [], [], []
     if spec.model not in ("gcn", "graphsage"):
-        return 0, 0
-    n = max(spec.n_graph_layers - (1 if spec.use_pp else 0), 0)
-    return n, n if spec.use_pp else max(n - 1, 0)
+        return fwd, bwd, narrow_layers
+    for i in range(1 if spec.use_pp else 0, spec.n_graph_layers):
+        fin, fout = spec.layer_sizes[i], spec.layer_sizes[i + 1]
+        narrow = False
+        if feat_mod.feat_shardable(spec, i, n_feat):
+            width = fin // n_feat
+        else:
+            narrow = projects_first(spec, i)
+            width = fout if narrow else fin
+        if narrow:
+            narrow_layers.append({"layer": i, "fin": fin, "fout": fout})
+        fwd.append(width)
+        if i > 0 or narrow:
+            bwd.append(width)
+    return fwd, bwd, narrow_layers
 
 
 def dense_paths(spec_pairs: Optional[dict], dense_dtype: str) -> dict:
@@ -395,14 +415,17 @@ def dense_paths(spec_pairs: Optional[dict], dense_dtype: str) -> dict:
 
 def spmm_counts(kind: str, spec: ModelSpec, arrays: dict, n_local: int,
                 spec_pairs: Optional[dict] = None,
-                dense_per_part=(), dense_dtype: str = "native") -> dict:
+                dense_per_part=(), dense_dtype: str = "native",
+                n_feat: int = 1) -> dict:
     """Counts at the boundaries where the aggregation's work is defined, per
     part maxima: dense tiles and the edges they carry (hybrid;
     `dense_per_part` from block_spmm.dense_edge_count) and the
     implementation that runs them (`dense_paths`), the slots the residual
     ELL gathers (rows x width summed over buckets, padding included: what
     ell._bucket_sum reads) and the real edges among them (slots over edges
-    is what the bucket geometry costs), and the aggregations per step.
+    is what the bucket geometry costs), the aggregations per step and the
+    columns each gathers (`agg_widths`), and the layers that project before
+    they aggregate (`narrow_layers`: layer, fin, fout).
     `spec_pairs` as in _hybrid_desc."""
     out = {"path": kind}
     for d, p in dense_paths(spec_pairs, dense_dtype).items():
@@ -428,9 +451,11 @@ def spmm_counts(kind: str, spec: ModelSpec, arrays: dict, n_local: int,
         out[f"residual_slots_{d}"] = int(slots)
         out[f"residual_edges_{d}"] = int(edges.max(initial=0))
     out["dense_edges"] = int(max(dense_per_part, default=0))
-    fwd, bwd = agg_calls(spec)
-    out.update(agg_calls_fwd=fwd, agg_calls_bwd=bwd,
-               agg_calls_per_step=fwd + bwd)
+    fwd, bwd, narrow_layers = agg_widths(spec, n_feat)
+    out.update(agg_calls_fwd=len(fwd), agg_calls_bwd=len(bwd),
+               agg_calls_per_step=len(fwd) + len(bwd),
+               agg_width_fwd=fwd, agg_width_bwd=bwd,
+               narrow_layers=narrow_layers)
     return out
 
 
@@ -909,11 +934,17 @@ def build_step_fns(cfg: Config, spec: ModelSpec, art: PartitionArtifacts,
             # identical to the fused path's single h_ext / out_norm
             return (x / norm[:, None]).astype(x.dtype)
 
+        def source(x, norm, scale_out_norm, w):
+            # a narrowing layer's projection, then the norm: the fused
+            # path's order on each side's rows
+            x = x if w is None else project(x, w)
+            return scale(x, norm) if scale_out_norm else x
+
         if split_kind == "segment":
-            def agg(i, h, scale_out_norm):
+            def agg(i, h, scale_out_norm, w=None):
                 with jax.named_scope(tp.HALO_START):
                     recv = halo_start(spec_h, plan, h)
-                h_in = scale(h, out_norm[:ni]) if scale_out_norm else h
+                h_in = source(h, out_norm[:ni], scale_out_norm, w)
                 with jax.named_scope(tp.INTERIOR_AGG):
                     o_i = agg_sum(h_in, blk["seg_int_src"],
                                   blk["seg_int_dst"], ni, cfg.edge_chunk)
@@ -921,7 +952,7 @@ def build_step_fns(cfg: Config, spec: ModelSpec, art: PartitionArtifacts,
                     buf = halo_finish(spec_h, plan, recv, h)
                 if combine is not None:
                     buf = combine(i, buf)
-                h_halo = scale(buf, out_norm[ni:]) if scale_out_norm else buf
+                h_halo = source(buf, out_norm[ni:], scale_out_norm, w)
                 with jax.named_scope(tp.FRONTIER_AGG):
                     o_f = agg_sum(jnp.concatenate([h_in, h_halo], 0),
                                   blk["seg_fro_src"], blk["seg_fro_dst"],
@@ -934,17 +965,17 @@ def build_step_fns(cfg: Config, spec: ModelSpec, art: PartitionArtifacts,
         a_f = {k[4:]: blk[k] for k in ell_keys if k.startswith("fro_")}
         mp = blk["merge_perm"]
 
-        def agg(i, h, scale_out_norm):
+        def agg(i, h, scale_out_norm, w=None):
             with jax.named_scope(tp.HALO_START):
                 recv = halo_start(spec_h, plan, h)
-            h_in = scale(h, out_norm[:ni]) if scale_out_norm else h
+            h_in = source(h, out_norm[:ni], scale_out_norm, w)
             with jax.named_scope(tp.INTERIOR_AGG):
                 o_i = int_spmm(a_i, h_in)
             with jax.named_scope(tp.HALO_FINISH):
                 buf = halo_finish(spec_h, plan, recv, h)
             if combine is not None:
                 buf = combine(i, buf)
-            h_halo = scale(buf, out_norm[ni:]) if scale_out_norm else buf
+            h_halo = source(buf, out_norm[ni:], scale_out_norm, w)
             with jax.named_scope(tp.FRONTIER_AGG):
                 o_f = fro_spmm(a_f, jnp.concatenate([h_in, h_halo], 0))
             return jnp.concatenate([o_i, o_f], 0)[mp]
@@ -1331,7 +1362,7 @@ def build_step_fns(cfg: Config, spec: ModelSpec, art: PartitionArtifacts,
                       else "ell" if ell_spmm is not None
                       else "gat-ell" if gat_spec is not None else "segment",
                       spec, ell_arrays, art.feat.shape[0], hybrid_pairs,
-                      dense_pp, cfg.spmm_dense),
+                      dense_pp, cfg.spmm_dense, n_fe),
                   spmm_desc=spmm_desc,
                   **refresh_fns)
     return fns, hspec, tables, tables_full

@@ -191,20 +191,46 @@ def test_bucket_sum_fp8_unroll_raises():
         _bucket_sum(hp, idx, 4, accum="unroll")
 
 
-def test_bucket_sum_unroll_matches_reduce():
+@pytest.mark.parametrize("dtype,h_dim", [
+    ("float32", 16), ("bfloat16", 41), ("bfloat16", 256), ("int8", 41),
+    ("int8", 256)])
+def test_bucket_sum_unroll_matches_reduce(dtype, h_dim):
     """The TPU-default unrolled f32-chain accumulation equals the
-    materialize-then-reduce path (f32 chains vs bf16 tree: compare in the
-    reduce path's own precision envelope)."""
+    materialize-then-reduce path (f32 chains vs the reduce path's sum:
+    compare in the reduce path's own precision envelope; int8 rows sum
+    exactly in int32 both ways), at the widths a narrowing layer and a
+    hidden layer aggregate. Its gathers carry no compare or select on the
+    index vectors (jnp's wrap of negative indices): the only compare left
+    is a scan's scalar loop counter."""
+    import re
     import jax.numpy as jnp
-    from bnsgcn_tpu.ops.ell import _bucket_sum
+    from bnsgcn_tpu.ops.ell import _bucket_sum, _unroll_sum
     rng = np.random.default_rng(5)
     # 16 = largest single unrolled chain, 32 = smallest 2-block scan
     for w in (2, 4, 8, 16, 32, 128):
-        hp = jnp.asarray(rng.normal(size=(500, 16)), jnp.float32)
+        x = rng.normal(size=(500, h_dim))
+        hp = (jnp.asarray(np.clip(np.round(40 * x), -127, 127), jnp.int8)
+              if dtype == "int8" else jnp.asarray(x, dtype))
         idx = jnp.asarray(rng.integers(0, 500, size=(37, w)).astype(np.int32))
         a = np.asarray(_bucket_sum(hp, idx, w, accum="unroll"))
         b = np.asarray(_bucket_sum(hp, idx, w, accum="reduce"))
-        np.testing.assert_allclose(a, b, rtol=2e-5, atol=2e-5)
+        assert a.dtype == b.dtype
+        if dtype == "int8":
+            np.testing.assert_array_equal(a, b)
+        elif dtype == "bfloat16":
+            # one rounding to bfloat16 of two f32 sums: a unit in the last
+            # place apart at most
+            np.testing.assert_allclose(a.astype(np.float32),
+                                       b.astype(np.float32),
+                                       rtol=2.0 ** -7, atol=2e-5)
+        else:
+            np.testing.assert_allclose(a, b, rtol=2e-5, atol=2e-5)
+        text = _unroll_sum.lower(hp, idx).as_text()
+        for line in text.splitlines():
+            if re.search(r"stablehlo\.(compare|select)", line):
+                sig = line.rsplit(":", 1)[1]
+                assert all("x" not in t for t in
+                           re.findall(r"tensor<([^>]*)>", sig)), line
 
 
 # ----------------------------------------------------------------------------

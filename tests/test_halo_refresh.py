@@ -224,7 +224,7 @@ def test_staggered_chunks_reconstruct_exact_exchange():
 # train-step level: full-refresh bitwise, staleness bias bound, grad-only
 # ----------------------------------------------------------------------------
 
-def _train(g, epochs, force_full_each_epoch=False, **cfg_kw):
+def _train(g, epochs, force_full_each_epoch=False, n_parts=4, **cfg_kw):
     """run.py's step dispatch in miniature: full-refresh step when the cache
     is cold, cached step after. Returns the per-epoch loss trajectory."""
     kw = dict(model="graphsage", dropout=0.0, use_pp=True, norm="layer",
@@ -233,8 +233,9 @@ def _train(g, epochs, force_full_each_epoch=False, **cfg_kw):
     cfg = Config(**kw)
     spec = ModelSpec("graphsage", (8, 16, 4), norm="layer", dropout=0.0,
                      use_pp=True, train_size=g.n_train)
-    mesh = make_parts_mesh(4)
-    art = build_artifacts(g, partition_graph(g, 4, method="random", seed=2))
+    mesh = make_parts_mesh(n_parts)
+    art = build_artifacts(g, partition_graph(g, n_parts, method="random",
+                                             seed=2))
     fns, hspec, tables, tables_full = build_step_fns(cfg, spec, art, mesh)
     blk_np = build_block_arrays(art, "graphsage")
     blk_np.update(fns.extra_blk)
@@ -303,6 +304,20 @@ def test_grad_only_converges(sbm4):
     the SBM task, if to a worse loss than the exchanging run."""
     traj = _train(sbm4, 40, halo_mode="grad-only")
     assert traj[-1] < 0.5 * traj[0], traj[-1]
+
+
+@pytest.mark.parametrize("overlap", ["off", "split"])
+def test_narrow_side_through_the_halo_cache(monkeypatch, sbm4, overlap):
+    """The 16 -> 4 layer projects the rows the cached step merges (stored
+    halo rows and the fresh chunk alike) after the exchange: at P=2, rate
+    0.1, K=2, the trajectory is the wide order's."""
+    from bnsgcn_tpu.models import gnn
+    kw = dict(n_parts=2, sampling_rate=0.1, halo_refresh=2, overlap=overlap)
+    narrow = _train(sbm4, 4, **kw)
+    with monkeypatch.context() as m:
+        m.setattr(gnn, "projects_first", lambda spec, i: False)
+        wide = _train(sbm4, 4, **kw)
+    assert np.allclose(narrow, wide, rtol=1e-5, atol=0), (narrow, wide)
 
 
 # ----------------------------------------------------------------------------

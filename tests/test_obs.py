@@ -482,7 +482,11 @@ def test_obs_report_spmm_counts_and_trace_clock():
                         "residual_slots_bwd": 219424,
                         "residual_edges_fwd": 200000,
                         "residual_edges_bwd": 200000, "agg_calls_fwd": 3,
-                        "agg_calls_bwd": 3, "agg_calls_per_step": 6}}]
+                        "agg_calls_bwd": 3, "agg_calls_per_step": 6,
+                        "agg_width_fwd": [256, 256, 41],
+                        "agg_width_bwd": [256, 256, 41],
+                        "narrow_layers": [{"layer": 3, "fin": 256,
+                                           "fout": 41}]}}]
     events += [{"ts": 100.0 + 0.5 * e, "kind": "epoch", "rank": 0, "epoch": e,
                 "loss": 1.0, "step_s": 0.5} for e in range(5, 11)]
     events.append({"ts": 104.6, "kind": "trace", "rank": 0, "epoch": 9,
@@ -494,17 +498,21 @@ def test_obs_report_spmm_counts_and_trace_clock():
     assert spmm == ("spmm: hybrid | dense tiles 190 fwd / 188 bwd via pallas "
                     "carry 669167 edges | residual slots 220512 fwd / 219424 "
                     "bwd a call for 200000 / 200000 edges (1.103 / 1.097 "
-                    "slots an edge) | 6 aggregations a step (3 fwd + 3 bwd)")
-    # a header written before the edges and the paths were counted keeps
-    # its old line
+                    "slots an edge) | 6 aggregations a step (3 fwd + 3 bwd)"
+                    " | widths [256, 256, 41] fwd / [256, 256, 41] bwd; "
+                    "projects first: layer 3 (256 -> 41)")
+    # a header written before the edges, the paths and the widths were
+    # counted keeps its old line
     for d in ("fwd", "bwd"):
         del events[0]["spmm"][f"residual_edges_{d}"]
         del events[0]["spmm"][f"dense_path_{d}"]
+        del events[0]["spmm"][f"agg_width_{d}"]
     out = []
     obs_report.render(obs_report.summarize(events), write=out.append)
     spmm = next(ln for ln in out if ln.startswith("spmm: "))
     assert "188 bwd carry 669167 edges" in spmm
     assert "slots 220512 fwd / 219424 bwd a call | 6 aggregations" in spmm
+    assert spmm.endswith("(3 fwd + 3 bwd)")
     laid = out[out.index(next(ln for ln in out
                               if ln.startswith("trace @E9"))) + 1]
     assert laid.strip() == ("window opened at 102.75 (wall clock); epoch "

@@ -256,3 +256,68 @@ def test_degenerate_single_part_no_frontier_anywhere():
     art = build_artifacts(g, partition_graph(g, 1, method="random", seed=0))
     mesh = make_parts_mesh(1)
     _assert_off_equals_split(g, art, mesh, spmm="ell", rate=1.0)
+
+
+# ----------------------------------------------------------------------------
+# a narrowing layer projects before it aggregates, on both source sides of
+# the split and behind the sampled exchange: the wide order's loss, logits
+# and gradients at P=2, boundary sampling at rate 0.1
+# ----------------------------------------------------------------------------
+
+@pytest.fixture(scope="module")
+def wide2():
+    g = synthetic_graph(n_nodes=120, avg_degree=7, n_feat=40, n_class=3,
+                        seed=43, power_law=True)
+    art = build_artifacts(g, partition_graph(g, 2, method="random", seed=3))
+    return g, art, make_parts_mesh(2)
+
+
+def _loss_grads(g, art, mesh, overlap, model, sizes, rate):
+    use_pp = model == "graphsage"
+    cfg = Config(model=model, dropout=0.0, use_pp=use_pp, norm="layer",
+                 n_train=g.n_train, lr=0.01, sampling_rate=rate, spmm="ell",
+                 overlap=overlap, n_partitions=mesh.devices.size,
+                 n_feat=g.n_feat, n_class=g.n_class)
+    spec = ModelSpec(model, sizes, norm="layer", dropout=0.0, use_pp=use_pp,
+                     train_size=g.n_train)
+    fns, _, tables, tables_full = build_step_fns(cfg, spec, art, mesh)
+    assert fns.overlap == overlap
+    blk_np = build_block_arrays(art, model)
+    blk_np.update(fns.extra_blk)
+    for k in fns.drop_blk_keys:
+        blk_np.pop(k, None)
+    blk = place_blocks(blk_np, mesh)
+    tb = place_replicated(tables, mesh)
+    if use_pp:
+        blk["feat"] = fns.precompute(blk, place_replicated(tables_full, mesh))
+    params, state = init_params(jax.random.key(5), spec)
+    params = place_replicated(params, mesh)
+    state = place_replicated(state, mesh)
+    logits = fns.forward(params, state, jnp.uint32(2), blk, tb,
+                         jax.random.key(0))
+    loss, grads = fns.loss_and_grad(params, state, jnp.uint32(3), blk, tb,
+                                    jax.random.key(0), jax.random.key(1))
+    return (np.asarray(logits), float(loss),
+            jax.tree.map(np.asarray, jax.device_get(grads)))
+
+
+@pytest.mark.parametrize("overlap", ["off", "split"])
+@pytest.mark.parametrize("model,sizes,narrow", [
+    ("gcn", (40, 8, 8, 3), (0, 2)),
+    ("graphsage", (40, 16, 16, 3), (2,))])
+def test_narrow_side_matches_wide_order_p2(monkeypatch, wide2, overlap,
+                                           model, sizes, narrow):
+    from bnsgcn_tpu.models import gnn
+    g, art, mesh = wide2
+    spec = ModelSpec(model, sizes, use_pp=model == "graphsage")
+    assert tuple(i for i in range(spec.n_layers)
+                 if gnn.projects_first(spec, i)) == narrow
+    lo, loss, grads = _loss_grads(g, art, mesh, overlap, model, sizes, 0.1)
+    with monkeypatch.context() as m:
+        m.setattr(gnn, "projects_first", lambda spec, i: False)
+        lo_w, loss_w, grads_w = _loss_grads(g, art, mesh, overlap, model,
+                                            sizes, 0.1)
+    assert np.abs(lo - lo_w).max() <= 1e-5 * np.abs(lo_w).max()
+    assert abs(loss - loss_w) <= 1e-5 * abs(loss_w)
+    for a, b in zip(jax.tree.leaves(grads), jax.tree.leaves(grads_w)):
+        assert np.abs(a - b).max() <= 1e-5 * (np.abs(b).max() + 1e-12)

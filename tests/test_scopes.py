@@ -22,7 +22,7 @@ from bnsgcn_tpu.data.graph import sbm_graph, synthetic_graph
 from bnsgcn_tpu.data.partitioner import partition_graph
 from bnsgcn_tpu.models.gnn import spec_from_config
 from bnsgcn_tpu.parallel.mesh import make_parts_mesh
-from bnsgcn_tpu.trainer import (abstract_step_inputs, agg_calls,
+from bnsgcn_tpu.trainer import (abstract_step_inputs, agg_widths,
                                 build_step_fns)
 from bnsgcn_tpu.utils import traceparse as tp
 
@@ -113,15 +113,17 @@ def test_lowered_step_carries_every_scope(tiny_art, monkeypatch, model, spmm):
 
 
 def test_agg_calls_counts_layers_that_aggregate():
-    def spec(**kw):
-        return spec_from_config(Config(n_feat=8, n_class=3, **kw))
+    def calls(**kw):
+        fwd, bwd, _ = agg_widths(spec_from_config(
+            Config(n_feat=8, n_class=3, **kw)))
+        return len(fwd), len(bwd)
     # sage-reddit: 4 layers, the precomputed layer 0 is a pure matmul
-    assert agg_calls(spec(model="graphsage", n_layers=4, use_pp=True)) == (3, 3)
+    assert calls(model="graphsage", n_layers=4, use_pp=True) == (3, 3)
     # without use_pp layer 0 aggregates, and its input holds no parameter
-    assert agg_calls(spec(model="gcn", n_layers=3, use_pp=False)) == (3, 2)
-    assert agg_calls(spec(model="graphsage", n_layers=4, n_linear=2,
-                          use_pp=True)) == (1, 1)
-    assert agg_calls(spec(model="gat", n_layers=3)) == (0, 0)
+    assert calls(model="gcn", n_layers=3, use_pp=False) == (3, 2)
+    assert calls(model="graphsage", n_layers=4, n_linear=2,
+                 use_pp=True) == (1, 1)
+    assert calls(model="gat", n_layers=3) == (0, 0)
 
 
 # ----------------------------------------------------------------------------
@@ -237,3 +239,7 @@ def test_run_header_counts_equal_the_layout_arrays(tiny_run):
     assert head["residual_edges_fwd"] == head["residual_edges_bwd"]
     assert (head["agg_calls_fwd"], head["agg_calls_bwd"],
             head["agg_calls_per_step"]) == (2, 2, 4)
+    # 8 -> 8 -> 8 -> 3 with use_pp: layer 1 keeps the wide order, layer 2
+    # narrows and aggregates its 3 projected columns
+    assert head["agg_width_fwd"] == head["agg_width_bwd"] == [8, 3]
+    assert head["narrow_layers"] == [{"layer": 2, "fin": 8, "fout": 3}]

@@ -275,6 +275,19 @@ def _dense_via(sp: dict) -> str:
             else f" via {paths[0]} fwd / {paths[1]} bwd")
 
 
+def _agg_widths(sp: dict) -> str:
+    """The columns each aggregation of a step gathers, in layer order, and
+    the layers that project before they aggregate (fin -> fout); empty for
+    a header written before the count."""
+    widths = [sp.get(f"agg_width_{d}") for d in ("fwd", "bwd")]
+    if None in widths:
+        return ""
+    narrow = ", ".join(f"layer {n['layer']} ({n['fin']} -> {n['fout']})"
+                       for n in sp.get("narrow_layers") or ())
+    return (f" | widths {widths[0]} fwd / {widths[1]} bwd"
+            + (f"; projects first: {narrow}" if narrow else ""))
+
+
 def render(s: dict, write=print):
     if s.get("unknown_kinds"):
         write("WARNING: event kinds outside obs.EVENT_KINDS (build skew?): "
@@ -316,7 +329,7 @@ def render(s: dict, write=print):
                   + _slots_per_edge(sp) + " | "
                   f"{sp.get('agg_calls_per_step')} aggregations a step "
                   f"({sp.get('agg_calls_fwd')} fwd + "
-                  f"{sp.get('agg_calls_bwd')} bwd)")
+                  f"{sp.get('agg_calls_bwd')} bwd)" + _agg_widths(sp))
     # reorder + layout-build get dedicated lines (and are dropped from the
     # generic lifecycle dump below — one record each, better as a summary)
     ro = next((ev for ev in s["lifecycle"] if ev["kind"] == "reorder"), None)
