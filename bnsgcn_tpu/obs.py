@@ -30,6 +30,12 @@ guards) and is pinned bitwise against `on` by tests/test_obs.py — the bus
 only ever reads host-side values the loop already fetched, never adds a
 device op. tools/obs_report.py renders a log (per-epoch table, comm-vs-
 compute split, serving percentiles, multi-rank merge, --compare).
+
+Set-up is named from inside too: boot stamps (`boot_begin`/`boot_end`)
+time what runs before an Obs exists, and the process's one
+`jax.monitoring` registration (`subscribe_compiles`) feeds each Obs a
+compile account (trace, lower, compile or cache load, cache hits and
+misses) that set-up spans and compiling epochs carry as `compile`.
 """
 
 from __future__ import annotations
@@ -41,13 +47,17 @@ import os
 import sys
 import threading
 import time
+import weakref
+from collections import deque
 from typing import Optional
 
 __all__ = [
     "Counter", "Gauge", "Histogram", "Registry", "EventLog", "Obs",
     "make_obs", "postmortem_dir", "write_postmortem", "load_events",
     "rank_log_path", "EVENT_KINDS", "PHASES", "SETUP_SPANS", "FIRST_CALL",
-    "SPAN_PREFIX", "EPOCH_MARK", "Span", "NO_SPAN", "span",
+    "SPAN_PREFIX", "EPOCH_MARK", "Span", "NO_SPAN", "span", "BOOT_PARENT",
+    "boot_begin", "boot_end", "proc_start_wall",
+    "subscribe_compiles", "unsubscribe_compiles", "compile_account",
 ]
 
 
@@ -61,7 +71,9 @@ EVENT_KINDS = (
     "run_header", "epoch", "epoch_ranks", "eval", "trace", "overlap",
     "halo_refresh", "reorder", "layout_build", "tune_decision", "run_end",
     # host spans (`span` below): one event per set-up phase of run_training
-    # and per `first_call:<program>`: name, parent, t0 (wall clock), dur_s
+    # and per `first_call:<program>`: name, parent, t0 (wall clock), dur_s,
+    # and `compile` where jax compiled inside it; boot stamps (`import`,
+    # `backend_init`) under parent BOOT_PARENT
     "span",
     # resilience (resilience.py: injections, rollback consensus, exits;
     # 'resize' = the elastic shrink/grow verdict: old/new world, part->slot
@@ -112,6 +124,8 @@ FIRST_CALL = "first_call:"
 # lanes' clock; the epoch body runs under StepTraceAnnotation(EPOCH_MARK)
 SPAN_PREFIX = "bns:"
 EPOCH_MARK = SPAN_PREFIX + "epoch"
+# the parent of the boot stamps: set-up that runs before any Obs exists
+BOOT_PARENT = "process"
 
 
 # ----------------------------------------------------------------------------
@@ -421,6 +435,14 @@ class Obs:
         self._epoch_mark = None
         self._rusage = None
         self._calls: dict = {}
+        # jax.monitoring records (kind, start, end, fun_name) not yet taken
+        # by an epoch: set-up spans read theirs by time, the loop takes the
+        # rest each epoch. Bounded: a process that never takes them (a
+        # server) keeps the newest. Fed only while subscribed (make_obs),
+        # from whichever thread compiles (the host eval thread too), so
+        # read and cleared under the lock.
+        self._compiles: deque = deque(maxlen=COMPILES_KEPT)
+        self._compiles_lock = threading.Lock()
 
     def emit(self, kind: str, **fields):
         if self.events is not None:
@@ -497,8 +519,33 @@ class Obs:
             if rec is not None:
                 self._emit_first_call(program)
 
+    def on_compile(self, kind: str, start: float, end: float,
+                   fun_name: str):
+        """One compile record from the dispatcher (any thread)."""
+        with self._compiles_lock:
+            self._compiles.append((kind, start, end, fun_name))
+
+    def _read_compiles(self, clear: bool) -> list:
+        with self._compiles_lock:
+            recs = list(self._compiles)
+            if clear:
+                self._compiles.clear()
+        return recs
+
+    def take_compiles(self) -> dict:
+        """The compile account of what jax did since the last call (or since
+        the set-up root closed), with the outermost compiles' `programs` and
+        the first record's wall `t0`; {} when nothing compiled. The loop's
+        one check an epoch: a record that lands after it is the next
+        epoch's."""
+        if not self._compiles:
+            return {}
+        return compile_account(self._read_compiles(clear=True),
+                               programs=True)
+
     def close(self):
         self.epoch_end()
+        unsubscribe_compiles(self.on_compile)
         if self.events is not None:
             self.events.close()
 
@@ -564,9 +611,18 @@ class Span:
         acc = self.obs.phase_s
         acc[self.name] = acc.get(self.name, 0.0) + self.dur_s - self._child_s
         if self.emit:
+            fields = {}
+            if self.obs._compiles:
+                # the outermost set-up span reads its records and clears
+                # them: what the loop takes from here on is its own
+                recs = self.obs._read_compiles(
+                    clear=not any(s.emit for s in stack))
+                compiled = compile_account(recs, self.t0_wall, time.time())
+                if compiled:
+                    fields["compile"] = compiled
             self.obs.emit("span", name=self.name, parent=self.parent,
                           t0=round(self.t0_wall, 6),
-                          dur_s=round(self.dur_s, 6))
+                          dur_s=round(self.dur_s, 6), **fields)
 
     __enter__ = begin
 
@@ -580,6 +636,164 @@ def span(obs: Optional[Obs], name: str, emit: bool = False,
     """The one host-span primitive (see Span). `obs=None` (--obs off) gets
     the shared null span: nothing is constructed, timed or annotated."""
     return NO_SPAN if obs is None else Span(obs, name, emit, parent)
+
+
+# ----------------------------------------------------------------------------
+# boot stamps: set-up that runs before an Obs exists
+# ----------------------------------------------------------------------------
+
+# {name: (wall t0, seconds, extra fields)}; a name keeps its first stamp, so
+# a second run in one process writes the process's own import and backend
+# start again, at their own t0. A stamp imports no jax and needs no Obs.
+_BOOT: dict = {}
+_BOOT_OPEN: dict = {}
+
+
+def boot_begin(name: str):
+    _BOOT_OPEN[name] = time.time()
+
+
+def boot_end(name: str, **fields):
+    t0 = _BOOT_OPEN.pop(name, None)
+    if t0 is not None and name not in _BOOT:
+        _BOOT[name] = (t0, time.time() - t0, fields)
+
+
+def proc_start_wall() -> Optional[float]:
+    """Wall time at which the OS started this process: /proc/self/stat
+    field 22 (clock ticks after boot) plus /proc/stat `btime` (whole
+    seconds, so within a second). None where these cannot be read."""
+    try:
+        with open("/proc/self/stat") as f:
+            # fields after the parenthesised command name start at field 3
+            ticks = int(f.read().rsplit(")", 1)[1].split()[19])
+        with open("/proc/stat") as f:
+            btime = next(int(ln.split()[1]) for ln in f
+                         if ln.startswith("btime "))
+        return round(btime + ticks / os.sysconf("SC_CLK_TCK"), 3)
+    except (OSError, ValueError, IndexError, StopIteration):
+        return None
+
+
+# ----------------------------------------------------------------------------
+# the compile account: the process's one jax.monitoring registration
+# ----------------------------------------------------------------------------
+
+# jax.monitoring's time spans (wall clock, `fun_name` in their metadata) and
+# counted events, by the kind the account books them under. In jax 0.9
+# `compile` wraps compile_or_get_cached, so a persistent-cache hit's load is
+# inside it; `misses` counts the executables written to that cache.
+COMPILE_SPANS = {"/jax/core/compile/jaxpr_trace_duration": "trace",
+                 "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+                 "/jax/core/compile/backend_compile_duration": "compile"}
+CACHE_EVENTS = {"/jax/compilation_cache/cache_hits": "hits",
+                "/jax/compilation_cache/cache_misses": "misses"}
+COMPILES_KEPT = 1 << 16
+
+# weak references to fn(kind, start, end, fun_name): an Obs or StrictExec
+# dropped without closing (a run that raised in set-up) stops hearing. A
+# tuple replaced whole under the lock, so the listeners read it without one.
+_subscribers: tuple = ()
+_sub_lock = threading.Lock()
+
+
+def _on_time_span(event: str, start_time: float, end_time: float, **kw):
+    kind = COMPILE_SPANS.get(event)
+    if kind is not None:
+        _dispatch(kind, start_time, end_time, kw.get("fun_name", ""))
+
+
+def _on_event(event: str, **kw):
+    kind = CACHE_EVENTS.get(event)
+    if kind is not None:
+        t = time.time()
+        _dispatch(kind, t, t, "")
+
+
+def _dispatch(kind, start, end, fun_name):
+    for ref in _subscribers:
+        fn = ref()
+        if fn is not None:
+            fn(kind, start, end, fun_name)
+
+
+def subscribe_compiles(fn):
+    """Hear every trace, lowering, compile (or cache load), cache hit and
+    miss of the process as fn(kind, start, end, fun_name), on the thread
+    that compiled. The first subscriber registers the dispatcher with
+    jax.monitoring; the last to leave unregisters it."""
+    global _subscribers
+    ref = weakref.WeakMethod(fn) if hasattr(fn, "__self__") \
+        else weakref.ref(fn)
+    with _sub_lock:
+        live = tuple(r for r in _subscribers if r() is not None)
+        if not _subscribers:
+            from jax import monitoring
+            monitoring.register_event_time_span_listener(_on_time_span)
+            monitoring.register_event_listener(_on_event)
+        _subscribers = live + (ref,)
+
+
+def unsubscribe_compiles(fn):
+    global _subscribers
+    with _sub_lock:
+        if not _subscribers:
+            return
+        _subscribers = tuple(r for r in _subscribers
+                             if r() is not None and r() != fn)
+        if not _subscribers:
+            from jax import monitoring
+            monitoring.unregister_event_time_span_listener(_on_time_span)
+            monitoring.unregister_event_listener(_on_event)
+
+
+def _union_s(spans: list) -> float:
+    total, hi = 0.0, -math.inf
+    for a, b in sorted(spans):
+        if b > hi:
+            total += b - max(a, hi)
+            hi = b
+    return total
+
+
+def compile_account(records, lo: Optional[float] = None,
+                    hi: Optional[float] = None,
+                    programs: bool = False) -> dict:
+    """{trace_s, lower_s, compile_s, hits, misses} of (kind, start, end,
+    fun_name) records, clipped to [lo, hi] where given. A kind's seconds are
+    the union of its spans, not their sum: a jit traced inside another's
+    trace reports its own span inside the outer one. `programs` adds the
+    fun_names of the outermost compiles and the first record's wall `t0`.
+    {} when no record falls inside."""
+    spans = {"trace": [], "lower": [], "compile": []}
+    counts = {"hits": 0, "misses": 0}
+    kept = []
+    for kind, a, b, name in records:
+        if lo is not None:
+            a, b = max(a, lo), min(b, hi)
+            if a > b:
+                continue
+        kept.append((a, b))
+        if kind in spans:
+            spans[kind].append((a, b, name))
+        else:
+            counts[kind] += 1
+    if not kept:
+        return {}
+    out = {f"{k}_s": round(_union_s([(a, b) for a, b, _ in v]), 6)
+           for k, v in spans.items()}
+    out.update(counts)
+    if programs:
+        names, reach = [], -math.inf
+        for a, b, name in sorted(spans["compile"],
+                                 key=lambda r: (r[0], -r[1])):
+            if b > reach:
+                reach = b
+                if name not in names:
+                    names.append(name)
+        out["programs"] = names
+        out["t0"] = round(min(a for a, _ in kept), 6)
+    return out
 
 
 def rank_log_path(path: str, rank: int) -> str:
@@ -599,6 +813,11 @@ def make_obs(cfg, rank: int = 0, log=print) -> Optional[Obs]:
     obs = Obs(path, rank=rank)
     if path:
         log(f"[obs] event log -> {path}")
+    for name, (t0, dur_s, fields) in sorted(_BOOT.items(),
+                                            key=lambda kv: kv[1][0]):
+        obs.emit("span", name=name, parent=BOOT_PARENT, t0=round(t0, 6),
+                 dur_s=round(dur_s, 6), **fields)
+    subscribe_compiles(obs.on_compile)
     return obs
 
 

@@ -16,6 +16,13 @@ measured by a compiled exchange-only microbench on identical inputs.
 
 from __future__ import annotations
 
+# the `import` boot stamp spans this module's body: the program's modules,
+# flax and optax, and whatever of jax was not loaded before (obs imports
+# nothing but the standard library)
+from bnsgcn_tpu import obs as obs_mod
+
+obs_mod.boot_begin("import")
+
 import contextlib
 import math
 import os
@@ -33,7 +40,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from bnsgcn_tpu import checkpoint as ckpt
-from bnsgcn_tpu import obs as obs_mod
 from bnsgcn_tpu import resilience
 from bnsgcn_tpu import strict as strict_mod
 from bnsgcn_tpu import tune as tune_mod
@@ -183,7 +189,11 @@ def run_training(cfg: Config, g: Optional[Graph] = None,
                  devices=None, verbose: bool = True) -> RunResult:
     log = print if verbose else (lambda *a, **k: None)
 
+    # the program's first touch of the backend: the chip's start-up, unless
+    # the caller started it (then this stamp reads about 0)
+    obs_mod.boot_begin("backend_init")
     multi_host = jax.process_count() > 1
+    obs_mod.boot_end("backend_init")
     is_rank0 = jax.process_index() == 0
 
     # ---- out-of-band rank coordination (multi-host resilience) ----
@@ -1748,6 +1758,11 @@ def run_training(cfg: Config, g: Optional[Graph] = None,
                         rec["boundary_s"] = round(boundary_s, 6)
                         rec["boundary"] = boundary
                     rec.update(obs.rusage_delta())
+                    # only an epoch in which jax compiled (or loaded) a
+                    # program carries its account, naming the programs
+                    compiled = obs.take_compiles()
+                    if compiled:
+                        rec["compile"] = compiled
                     obs.emit("epoch", **rec)
 
             # ---- --tune decision point: the epoch's measured metrics feed
@@ -2017,3 +2032,6 @@ def run_training(cfg: Config, g: Optional[Graph] = None,
                  step_hist=obs.registry.histogram("train/step_s").snapshot())
         obs.close()
     return res
+
+
+obs_mod.boot_end("import", proc_start=obs_mod.proc_start_wall())

@@ -17,15 +17,17 @@ Two mechanisms wrap the hot-loop step region in run.py:
   directions.)
 
 * **Compile listener** — `jax.monitoring` delivers a
-  `.../backend_compile...` duration event on every XLA compilation,
-  including cache-miss recompiles, and nothing on cached calls. Each
+  `.../backend_compile_duration` span on every XLA compilation (or
+  persistent-cache load), including recompiles, and nothing on calls the
+  in-memory cache serves. Each
   step VARIANT (`full`/`cached`/`step` — the `--halo-refresh` pair is
   two distinct programs) is allowed to compile during its first guarded
   step; a compile in any later step of an armed variant is a
   steady-state recompile (donation-shape drift, a host value leaking
-  into the trace) and raises StrictExecError. jax.monitoring has no
-  unregister, so ONE module-level listener is installed lazily and
-  dispatches to whichever StrictExec instance is active.
+  into the trace) and raises StrictExecError. Each StrictExec subscribes
+  to the process's one registration, which obs owns
+  (`obs.subscribe_compiles`), directly and not through an Obs: under
+  `--obs off` there is none.
 
 `finish()` logs a one-line audit summary and lands a `strict_exec` event
 on the telemetry bus (obs.EVENT_KINDS), so a pod run's log carries the
@@ -38,6 +40,8 @@ import contextlib
 from typing import Optional
 
 import jax
+
+from bnsgcn_tpu import obs as obs_mod
 
 __all__ = ["StrictExec", "StrictExecError", "TRANSFER_PRIMITIVES"]
 
@@ -62,27 +66,6 @@ class StrictExecError(RuntimeError):
     step. The message names the variant and the fix direction."""
 
 
-# jax.monitoring offers register-only listeners (no unregister), so the
-# process installs exactly one and routes through the active instance.
-_ACTIVE: Optional["StrictExec"] = None
-_LISTENER_INSTALLED = False
-
-
-def _on_event_duration(event: str, duration: float, **kw):
-    inst = _ACTIVE
-    if inst is not None and "backend_compile" in event:
-        inst._saw_compile(event)
-
-
-def _install_listener():
-    global _LISTENER_INSTALLED
-    if _LISTENER_INSTALLED:
-        return
-    from jax import monitoring
-    monitoring.register_event_duration_secs_listener(_on_event_duration)
-    _LISTENER_INSTALLED = True
-
-
 class StrictExec:
     """Per-run strict-execution auditor. run.py creates one when
     `--strict-exec` is set and wraps every hot-loop step in `step()`."""
@@ -98,18 +81,17 @@ class StrictExec:
         self.fetches = 0
         self.violations = 0
         self.rearms = 0
-        _install_listener()
+        obs_mod.subscribe_compiles(self._saw_compile)
 
     # listener path (same thread: XLA compiles synchronously under trace)
-    def _saw_compile(self, event: str):
-        if self._in_step is not None:
+    def _saw_compile(self, kind: str, start: float, end: float,
+                     fun_name: str):
+        if kind == "compile" and self._in_step is not None:
             self._step_compiles += 1
 
     @contextlib.contextmanager
     def step(self, variant: str):
         """Guard one hot-loop step of the named program variant."""
-        global _ACTIVE
-        _ACTIVE = self
         self._in_step = variant
         self._step_compiles = 0
         try:
@@ -172,9 +154,7 @@ class StrictExec:
         }
 
     def finish(self):
-        global _ACTIVE
-        if _ACTIVE is self:
-            _ACTIVE = None
+        obs_mod.unsubscribe_compiles(self._saw_compile)
         s = self.summary()
         total_steps = sum(s["steps"].values())
         self.log(

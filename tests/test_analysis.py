@@ -734,3 +734,40 @@ def test_strict_exec_unit_recompile_and_fetch():
     assert s["violations"] == 1 and s["steps"]["v"] == 3
     assert emitted and emitted[0][0] == "strict_exec"
     assert any("[strict] exec audit:" in ln for ln in lines)
+
+
+@pytest.mark.parametrize("obs_on", [True, False])
+def test_strict_exec_recompile_raises_through_the_one_registration(
+        tmp_path, obs_on):
+    """StrictExec hears compiles through obs's one jax.monitoring
+    registration, beside a run's Obs (obs on) or alone (obs off: no Obs
+    exists): a forced recompile inside a later step still raises, and
+    finish() leaves it subscribed no more."""
+    import jax
+    import jax.numpy as jnp
+
+    from bnsgcn_tpu import obs as obs_mod
+    from bnsgcn_tpu.config import Config
+    from bnsgcn_tpu.strict import StrictExec, StrictExecError
+
+    ob = obs_mod.make_obs(
+        Config(obs="on" if obs_on else "off",
+               obs_log=str(tmp_path / "o.jsonl")), log=lambda *a: None)
+    assert (ob is not None) == obs_on
+    st = StrictExec(obs=ob, log=lambda *a: None)
+
+    @jax.jit
+    def f(x):
+        return x + 1
+
+    with st.step("v"):
+        f(jnp.arange(3.0))
+    with pytest.raises(StrictExecError, match="recompile"):
+        with st.step("v"):
+            f(jnp.arange(5.0))
+    s = st.finish()
+    assert s["violations"] == 1 and s["first_compiles"]["v"] >= 1
+    assert all(r() != st._saw_compile for r in obs_mod._subscribers)
+    if ob is not None:
+        assert "jit(f)" in ob.take_compiles()["programs"]
+        ob.close()

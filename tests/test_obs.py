@@ -429,6 +429,195 @@ def test_spans_land_in_a_profiler_window_on_the_python_thread(tmp_path):
             assert e["ts"] + e["dur"] <= g["ts"] + g["dur"] + 1e-3
 
 
+# ----------------------------------------------------------------------------
+# the compile account and the boot stamps
+# ----------------------------------------------------------------------------
+
+def test_compile_account_is_the_union_of_each_kinds_spans():
+    recs = [("trace", 2.0, 3.0, "inner"),          # inside outer's trace
+            ("trace", 2.5, 2.6, "sin"),            # inside both
+            ("trace", 1.0, 4.0, "outer"),
+            ("trace", 3.5, 5.0, "tail"),           # overlaps outer's end
+            ("lower", 5.0, 6.0, "jit(outer)"),
+            ("compile", 6.0, 9.0, "jit(outer)"),
+            ("compile", 7.0, 8.0, "jit(nested)"),  # inside the outer one
+            ("hits", 6.5, 6.5, ""), ("hits", 7.5, 7.5, ""),
+            ("misses", 8.5, 8.5, ""),
+            ("compile", 10.0, 10.5, "jit(later)")]
+    a = obs_mod.compile_account(recs, programs=True)
+    # a sum would read 6.1 s of tracing for the 4 s the union holds
+    assert a == {"trace_s": 4.0, "lower_s": 1.0, "compile_s": 3.5,
+                 "hits": 2, "misses": 1, "t0": 1.0,
+                 "programs": ["jit(outer)", "jit(later)"]}
+    # clipped to a window: the parts outside it, and instants, drop out
+    w = obs_mod.compile_account(recs, 3.0, 7.0)
+    assert w == {"trace_s": 2.0, "lower_s": 1.0, "compile_s": 1.0,
+                 "hits": 1, "misses": 0}
+    assert obs_mod.compile_account(recs, 11.0, 12.0) == {}
+    assert obs_mod.compile_account([]) == {}
+
+
+def _listeners():
+    from jax._src import monitoring
+    return (list(monitoring.get_event_time_span_listeners()),
+            list(monitoring.get_event_listeners()))
+
+
+def test_setup_spans_and_epochs_carry_what_compiled_inside_them(tmp_path):
+    """make_obs subscribes the Obs to the process's one registration: an
+    emitting span carries the account of what compiled inside it, the
+    outermost one's close hands the rest to the loop, and take_compiles
+    gives an epoch its account once, {} when nothing compiled."""
+    import jax
+    import jax.numpy as jnp
+    path = str(tmp_path / "o.jsonl")
+    ob = obs_mod.make_obs(Config(obs="on", obs_log=path), log=lambda *a: None)
+    assert obs_mod._on_time_span in _listeners()[0]
+    assert obs_mod._on_event in _listeners()[1]
+
+    def fresh(k):                   # a program nothing has compiled yet
+        return jax.jit(lambda x: jnp.sin(x) * k)(np.arange(3.0 + k))
+
+    with obs_mod.span(ob, "run_training_setup", emit=True):
+        with obs_mod.span(ob, "init_training", emit=True):
+            fresh(1).block_until_ready()
+        with obs_mod.span(ob, "place", emit=True):
+            pass
+        fresh(2).block_until_ready()
+    assert ob.take_compiles() == {}         # read by the set-up spans
+    fresh(3).block_until_ready()
+    epoch0 = ob.take_compiles()
+    assert epoch0["compile_s"] > 0 and epoch0["trace_s"] > 0
+    assert "jit(<lambda>)" in epoch0["programs"]
+    assert ob.take_compiles() == {}
+    ob.close()
+    assert obs_mod._on_time_span not in _listeners()[0] or any(
+        r() is not None for r in obs_mod._subscribers)
+    fresh(4).block_until_ready()            # closed: heard by nobody
+    assert ob.take_compiles() == {}
+    ev = {e["name"]: e for e in obs_mod.load_events(path)
+          if e["kind"] == "span" and e["parent"] != obs_mod.BOOT_PARENT}
+    assert "compile" not in ev["place"]
+    inner, root = ev["init_training"]["compile"], ev[
+        "run_training_setup"]["compile"]
+    assert set(inner) == {"trace_s", "lower_s", "compile_s", "hits",
+                          "misses"}
+    assert 0 < inner["compile_s"] < root["compile_s"]
+    assert root["trace_s"] >= inner["trace_s"]
+
+
+def test_cache_hits_and_misses_are_counted():
+    ob = obs_mod.Obs("")
+    obs_mod.subscribe_compiles(ob.on_compile)
+    try:
+        from jax import monitoring
+        monitoring.record_event("/jax/compilation_cache/cache_hits")
+        monitoring.record_event("/jax/compilation_cache/cache_hits")
+        monitoring.record_event("/jax/compilation_cache/cache_misses")
+        monitoring.record_event("/jax/compilation_cache/other")
+    finally:
+        obs_mod.unsubscribe_compiles(ob.on_compile)
+    a = ob.take_compiles()
+    assert (a["hits"], a["misses"], a["compile_s"]) == (2, 1, 0.0)
+
+
+def test_compile_records_from_other_threads_are_neither_lost_nor_raced():
+    """A compile on another thread (the host eval thread) lands while the
+    loop takes an epoch's account and a set-up span reads its own: every
+    record is taken exactly once and nothing raises."""
+    import threading
+    ob = obs_mod.Obs("")
+    n_threads, n_each = 4, 5000
+    go = threading.Event()
+
+    def feed():
+        go.wait()
+        for i in range(n_each):
+            ob.on_compile("hits", float(i), float(i), "")
+
+    threads = [threading.Thread(target=feed) for _ in range(n_threads)]
+    for t in threads:
+        t.start()
+    go.set()
+    taken = 0
+    while any(t.is_alive() for t in threads):
+        ob._read_compiles(clear=False)              # a nested span's read
+        taken += ob.take_compiles().get("hits", 0)
+    for t in threads:
+        t.join()
+    taken += ob.take_compiles().get("hits", 0)
+    assert taken == n_threads * n_each
+
+
+def test_boot_stamps_are_written_out_at_make_obs(tmp_path, monkeypatch):
+    import time
+    monkeypatch.setattr(obs_mod, "_BOOT", {})
+    obs_mod.boot_begin("import")
+    time.sleep(0.01)
+    obs_mod.boot_end("import", proc_start=obs_mod.proc_start_wall())
+    obs_mod.boot_begin("backend_init")
+    obs_mod.boot_end("backend_init")
+    obs_mod.boot_begin("backend_init")          # a name keeps its first
+    time.sleep(0.02)
+    obs_mod.boot_end("backend_init")
+    obs_mod.boot_end("never_begun")
+    path = str(tmp_path / "o.jsonl")
+    obs_mod.make_obs(Config(obs="on", obs_log=path),
+                     log=lambda *a: None).close()
+    ev = obs_mod.load_events(path)
+    assert [(e["kind"], e["name"], e["parent"]) for e in ev] == [
+        ("span", "import", "process"), ("span", "backend_init", "process")]
+    imp, be = ev
+    assert imp["dur_s"] >= 0.01 and be["dur_s"] < 0.02
+    assert imp["t0"] <= be["t0"] <= time.time()
+    # the OS's start of this process: before its import, within a second
+    assert imp["proc_start"] <= imp["t0"] + 1.0
+    assert imp["proc_start"] > imp["t0"] - 7 * 24 * 3600
+
+
+def test_obs_report_setup_timeline_and_recompiled_epochs(tmp_path):
+    sys.path.insert(0, os.path.join(REPO, "tools"))
+    import obs_report
+    acct = {"trace_s": 3.5, "lower_s": 2.1, "compile_s": 2.4, "hits": 1,
+            "misses": 0}
+    events = [
+        {"ts": 103.0, "kind": "span", "rank": 0, "name": "import",
+         "parent": "process", "t0": 101.0, "dur_s": 2.0, "proc_start": 100.5},
+        {"ts": 110.0, "kind": "span", "rank": 0, "name": "backend_init",
+         "parent": "process", "t0": 103.5, "dur_s": 6.5},
+        {"ts": 112.0, "kind": "span", "rank": 0, "name": "place",
+         "parent": "run_training_setup", "t0": 111.0, "dur_s": 1.0,
+         "compile": dict(acct, trace_s=0.25)},
+        {"ts": 114.0, "kind": "span", "rank": 0,
+         "name": "run_training_setup", "parent": None, "t0": 110.0,
+         "dur_s": 4.0, "compile": acct}]
+    for e in range(5):
+        ev = {"ts": 122.0 + e, "kind": "epoch", "rank": 0, "epoch": e,
+              "loss": 1.0, "step_s": 0.4}
+        if e in (0, 3):
+            ev["compile"] = dict(acct, programs=[f"jit(p{e})"], t0=121.0 + e)
+        events.append(ev)
+    out = []
+    obs_report.render(obs_report.summarize(events), write=out.append)
+    text = "\n".join(out)
+    tree = text[text.index("set-up (span events):"):].splitlines()
+    assert [ln.split()[0] for ln in tree[2:7]] == [
+        "process", "import", "backend_init", "run_training_setup", "place"]
+    assert "at 100.500, 0.500 s before the first boot span" in tree[2]
+    assert "%" not in tree[3] and "%" not in tree[4]
+    assert "100.0%" in tree[5] and "25.0%" in tree[6]
+    assert ("[trace 3.500 lower 2.100 compile 2.400 s, hits 1 misses 0]"
+            in tree[5])
+    assert "[trace 0.250 lower" in tree[6]
+    assert tree[7].strip() == ("warm-up: 11.000 s from the root's end to "
+                               "epoch 3, the last epoch that compiled:")
+    assert tree[8].strip().startswith("E0: jit(p0) [trace 3.500")
+    assert tree[9].strip().startswith("E3: jit(p3) [trace")
+    rows = [ln for ln in out if re.match(r"^\s+\d+\s+1\.0000", ln)]
+    assert [ln.endswith("recompiled: jit(p%d)" % e) for e, ln in
+            enumerate(rows)] == [True, False, False, True, False]
+
+
 def test_obs_report_setup_tree_host_columns_and_stalls(tmp_path):
     """tools/obs_report.py renders the `span` events as a tree with shares,
     the epoch records' dispatch / wait / boundary, and the epochs whose wait
@@ -541,11 +730,18 @@ def _base_cfg(tmp_path, **kw):
     return Config(**d)
 
 
-def test_obs_off_bitwise_identical_to_on(tmp_path, small_graph):
+def test_obs_off_bitwise_identical_to_on(tmp_path, small_graph, monkeypatch):
     from bnsgcn_tpu.run import run_training
+    subscribed = []
+    real = obs_mod.subscribe_compiles
+    monkeypatch.setattr(obs_mod, "subscribe_compiles",
+                        lambda fn: (subscribed.append(fn), real(fn)))
+    before = _listeners()
     r_off = run_training(
         _base_cfg(tmp_path, obs="off", ckpt_path=str(tmp_path / "c0")),
         g=small_graph, verbose=False)
+    # off (and no --strict-exec) registers no jax.monitoring listener
+    assert subscribed == [] and _listeners() == before
     r_on = run_training(
         _base_cfg(tmp_path, obs="on",
                   obs_log=str(tmp_path / "obs.jsonl"),
@@ -553,10 +749,44 @@ def test_obs_off_bitwise_identical_to_on(tmp_path, small_graph):
         g=small_graph, verbose=False)
     np.testing.assert_array_equal(r_off.losses, r_on.losses)
     assert r_off.final_loss == r_on.final_loss
+    # on subscribes once, and the run's close leaves it subscribed no more
+    assert len(subscribed) == 1
+    assert all(r() != subscribed[0] for r in obs_mod._subscribers)
     # and the on-run actually recorded its trail
     kinds = {e["kind"] for e in
              obs_mod.load_events(str(tmp_path / "obs.jsonl"))}
     assert {"run_header", "epoch", "run_end"} <= kinds
+
+
+def test_epochs_carry_compile_only_where_a_program_first_ran(tmp_path,
+                                                             small_graph):
+    """Epoch 0 names the step it compiled; the norm probe's first log epoch
+    names the probe; no other epoch carries `compile`. A second run in the
+    process builds a new step (a jit of its own) but its probe is served by
+    jax's in-memory cache: only its epoch 0 carries one."""
+    import jax
+    from bnsgcn_tpu.run import run_training
+    jax.clear_caches()
+    runs = []
+    for i in range(2):
+        log = str(tmp_path / f"obs{i}.jsonl")
+        run_training(_base_cfg(tmp_path, obs_log=log,
+                               ckpt_path=str(tmp_path / f"c{i}")),
+                     g=small_graph, verbose=False)
+        runs.append(obs_mod.load_events(log))
+    probe = 1                               # log_every 2
+    for i, evs in enumerate(runs):
+        ep = {e["epoch"]: e for e in evs if e["kind"] == "epoch"}
+        assert sorted(e for e, ev in ep.items() if "compile" in ev) == (
+            [0, probe] if i == 0 else [0]), i
+        assert "jit(train_step)" in ep[0]["compile"]["programs"]
+        assert ep[0]["compile"]["compile_s"] > 0
+        spans = {e["name"]: e for e in evs if e["kind"] == "span"}
+        assert spans["run_training_setup"]["compile"]["trace_s"] > 0
+        assert spans["import"]["parent"] == obs_mod.BOOT_PARENT
+        assert spans["backend_init"]["parent"] == obs_mod.BOOT_PARENT
+    first = {e["epoch"]: e for e in runs[0] if e["kind"] == "epoch"}
+    assert first[probe]["compile"]["programs"] == ["jit(param_global_norm)"]
 
 
 def test_rollback_run_leaves_lifecycle_trail(tmp_path, small_graph,

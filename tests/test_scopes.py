@@ -190,6 +190,12 @@ def test_epoch_events_account_for_the_window(tiny_run):
 def test_setup_spans_form_one_tree(tiny_run):
     events, _, _ = tiny_run
     spans = [e for e in events if e["kind"] == "span"]
+    # the boot stamps ran before any Obs existed: written at make_obs, under
+    # their own parent, ahead of the tree
+    boot = [e for e in spans if e["parent"] == obs_mod.BOOT_PARENT]
+    assert {e["name"] for e in boot} == {"import", "backend_init"}
+    assert spans[:len(boot)] == boot
+    spans = spans[len(boot):]
     by = {e["name"]: e for e in spans}
     root = obs_mod.SETUP_SPANS[0]
     assert by[root]["parent"] is None

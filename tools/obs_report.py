@@ -14,11 +14,16 @@ Sections (each rendered only when the log carries its events):
   * run header — config, RxPxT mesh, halo strategy/wire, partition stats,
     the aggregation's counts (`spmm`: tiles, dense edges, residual slots,
     calls a step)
-  * set-up — the `span` events as a tree under `run_training_setup`:
-    seconds and share of the root, `first_call:<program>` (what compiling
-    or loading each jitted program of the loop cost) among them
+  * set-up — the timeline: the OS's start of the process (`proc_start`),
+    the boot spans `import` and `backend_init`, then the `span` events as
+    a tree under `run_training_setup` (seconds and share of the root, each
+    span's `compile` account: trace / lower / compile-or-cache-load
+    seconds, cache hits / misses; `first_call:<program>`, what compiling
+    or loading each jitted program of the loop cost, among them), then the
+    warm-up: the epochs that compiled, with their programs and account
   * per-epoch table — loss, step ms, comm ms ([traced]/[sampled]), param
-    norm, eval accuracy joined on epoch; multi-rank logs merge per rank
+    norm, eval accuracy joined on epoch, "recompiled: <programs>" on an
+    epoch in which jax compiled; multi-rank logs merge per rank
     (rank files `PATH.r<N>` are auto-discovered next to PATH). Where the
     records carry the loop's host account: dispatch / wait / boundary ms,
     and a stalls list (epochs whose wait exceeds the median by 10%, with
@@ -60,7 +65,8 @@ import sys
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 
-from bnsgcn_tpu.obs import EVENT_KINDS, FIRST_CALL, load_events  # noqa: E402
+from bnsgcn_tpu.obs import (BOOT_PARENT, EVENT_KINDS, FIRST_CALL,  # noqa: E402
+                            SETUP_SPANS, load_events)
 
 LIFECYCLE_KINDS = ("inject", "rollback", "preempt", "watchdog_fire",
                    "divergence_abort", "coord_decision", "profile_request",
@@ -211,33 +217,71 @@ def _resize_verdicts(s: dict) -> list[dict]:
     return out
 
 
-def _render_setup(spans: list[dict], write):
-    """The set-up `span` events as a tree: seconds, share of the root."""
+def _compile_desc(acct: dict) -> str:
+    """One `compile` account: trace / lower / compile-or-cache-load seconds
+    (each the union of its spans) and the persistent cache's hits / misses."""
+    return (f"trace {_num(acct.get('trace_s')):.3f} lower "
+            f"{_num(acct.get('lower_s')):.3f} compile "
+            f"{_num(acct.get('compile_s')):.3f} s, hits "
+            f"{acct.get('hits', 0)} misses {acct.get('misses', 0)}")
+
+
+def _render_setup(spans: list[dict], write, epochs: dict):
+    """Set-up as a timeline: the process's start, the boot spans, the
+    `span` events as a tree (seconds, share of the root, compile account),
+    then the warm-up epochs that compiled."""
     kids: dict = {}
     for ev in spans:
         kids.setdefault(ev.get("parent"), []).append(ev)
     names = {ev.get("name") for ev in spans}
     roots = [ev for ev in spans if ev.get("parent") not in names]
-    total = max((_num(ev.get("dur_s")) for ev in roots), default=0.0)
+    setup = next((ev for ev in roots if ev.get("name") == SETUP_SPANS[0]),
+                 None)
+    total = (_num(setup.get("dur_s")) if setup is not None else
+             max((_num(ev.get("dur_s")) for ev in roots), default=0.0))
     write("")
     write("set-up (span events):")
     write("  phase                                  seconds   share")
+    boot = kids.get(BOOT_PARENT, [])
+    born = next((ev["proc_start"] for ev in boot
+                 if ev.get("proc_start") is not None), None)
+    if born is not None:
+        first = min(_num(ev.get("t0")) for ev in boot)
+        write(f"  {'process start (OS)':<36}  {'-':>8}       -  at "
+              f"{_num(born):.3f}, {first - _num(born):.3f} s before "
+              f"the first boot span")
 
     def walk(ev, depth):
         d = _num(ev.get("dur_s"))
-        # a first call runs in the loop, after the root has closed
+        # a first call runs in the loop, after the root has closed; a boot
+        # span runs before it
         share = (f"{d / total:6.1%}" if total > 0
                  and not str(ev.get("name")).startswith(FIRST_CALL)
+                 and ev.get("parent") != BOOT_PARENT
                  else "     -")
         calls = f"  ({ev['calls']} calls)" if "calls" in ev else ""
+        comp = (f"  [{_compile_desc(ev['compile'])}]" if ev.get("compile")
+                else "")
         write(f"  {'  ' * depth + str(ev.get('name')):<36}  {d:8.3f}  "
-              f"{share}{calls}")
+              f"{share}{calls}{comp}")
         for kid in sorted(kids.get(ev.get("name"), []),
                           key=lambda k: _num(k.get("t0"))):
             walk(kid, depth + 1)
 
     for ev in sorted(roots, key=lambda k: _num(k.get("t0"))):
         walk(ev, 0)
+    compiled = sorted((e, by_r[0]) for e, by_r in epochs.items()
+                      if by_r.get(0, {}).get("compile"))
+    if setup is None or not compiled:
+        return
+    end = _num(setup.get("t0")) + _num(setup.get("dur_s"))
+    last_e, last = compiled[-1]
+    write(f"  warm-up: {_num(last.get('ts')) - end:.3f} s from the root's "
+          f"end to epoch {last_e}, the last epoch that compiled:")
+    for e, ev in compiled:
+        acct = ev["compile"]
+        write(f"    E{e}: {', '.join(acct.get('programs') or ['-'])} "
+              f"[{_compile_desc(acct)}]")
 
 
 def _stalls(epochs: dict) -> list[dict]:
@@ -348,7 +392,7 @@ def render(s: dict, write=print):
         write(f"layout build: {stages} | total "
               f"{sum(_num(ev.get('ms')) for ev in lb):.1f} ms")
     if s.get("spans"):
-        _render_setup(s["spans"], write)
+        _render_setup(s["spans"], write, s["epochs"])
     # --tune decision trail as a schedule table (also dropped from the
     # generic lifecycle dump): WHEN each comm lever moved, WHY, and the
     # trigger metrics the controller read — the per-run audit of the
@@ -461,7 +505,10 @@ def render(s: dict, write=print):
                         else f"  {'-':>8}"
                         for k in ("dispatch_s", "wait_s", "boundary_s"))
                        if has_host else "")
-                    + (f"     r{r}" if multi else ""))
+                    + (f"     r{r}" if multi else "")
+                    + ("  recompiled: " + ", ".join(
+                        ev["compile"].get("programs") or ["-"])
+                       if ev.get("compile") else ""))
         rows, elided = _elide(rows)
         for row in rows:
             write(row)
