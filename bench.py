@@ -226,10 +226,11 @@ def main():
                          "touches — candidates collapse to the anchor)")
     ap.add_argument("--occupancy", type=int, default=0,
                     help="hybrid: min edges per tile to densify "
-                         "(0 = auto: the tile's byte break-even, "
-                         "tile*tile/512 — 512 for 512x512, 128 for +t256)")
-    ap.add_argument("--tile-budget-mb", type=int, default=2048,
-                    help="hybrid: int8 dense-tile HBM budget per direction")
+                         "(0 = auto: the tile's time break-even, half "
+                         "the tile's edge — 256 for 512x512, 128 for +t256)")
+    ap.add_argument("--tile-budget-mb", type=int, default=4096,
+                    help="hybrid: device bytes of the one int8 dense-tile "
+                         "stack both directions read")
     ap.add_argument("--cache-dir", type=str,
                     default=os.environ.get("BNSGCN_CACHE_DIR")
                     or "./bench_cache",
@@ -802,8 +803,7 @@ def main():
     # own file and survive occupancy/budget/tile sweeps; each hybrid
     # tiling geometry gets its own file (multi-GB stacks — one file per
     # key avoids rewriting every stack when one is added).
-    from bnsgcn_tpu.trainer import (ell_layout_key, hybrid_layout_key,
-                                    hybrid_tiling)
+    from bnsgcn_tpu.trainer import ell_layout_key, hybrid_layout_key
 
     def variant_key(variant):
         return (ell_layout_key(make_cfg(variant))
@@ -811,16 +811,10 @@ def main():
                 else hybrid_layout_key(make_cfg(variant)))
 
     def hyb_path_for(variant):
-        occ, tile, budget = hybrid_tiling(make_cfg(variant))
-        suf = f"_t{tile}" if tile != 512 else ""
-        if _vovl(variant) == "split":
-            suf += "_ovl"          # interior/frontier pair: own multi-GB file
-        if _vro(variant) != "off":
-            suf += "_ro"           # permuted-artifact stacks: own file (the
-            # in-memory key carries ':ro' too, so a raw-order stack can
-            # never serve a +ro candidate or vice versa)
-        return os.path.join(
-            args.cache_dir, f"layouts_hyb_{tag}_{occ}_{budget}{suf}.pkl")
+        # the file is named by the key: tiling, layout format, ':ovl' and
+        # ':ro' each get their own multi-GB file
+        key = variant_key(variant).replace(":", "-")
+        return os.path.join(args.cache_dir, f"layouts_{tag}_{key}.pkl")
 
     hyb_variants = {variant_key(v): v for v in candidates
                     if v[0] == "hybrid"}

@@ -101,13 +101,20 @@ class Config:
                                         # (compute dtype) | 'int8' (quantized slabs,
                                         # int8x int8 MXU at ~2x bf16 rate)
     block_occupancy: int = 0            # hybrid SpMM: min edges for a tile to densify.
-                                        # 0 = auto: the tile's byte break-even,
-                                        # tile*tile/512 (512 at the default 512x512
-                                        # tile, 128 at 256x256); explicit values are
-                                        # absolute (MXU-time break-even is nearer
-                                        # ~1200 at 31 TFLOP/s for 512x512)
-    block_tile_budget_mb: int = 2048    # hybrid SpMM: int8 dense-tile HBM budget per
-                                        # direction (8192 tiles at 512x512)
+                                        # 0 = auto: the time break-even against the
+                                        # residual gather, half the tile's edge (256
+                                        # at 512x512, 128 at 256x256: on a v5e a
+                                        # residual edge costs ~22.6 ns a step, a tile
+                                        # 5.94 / 2.97 us; measured at those two sizes
+                                        # only); explicit values are absolute
+    block_tile_budget_mb: int = 4096    # hybrid SpMM: device bytes of one build's tile
+                                        # stack, the one int8 stack both directions
+                                        # read (16384 tiles at 512x512); --overlap
+                                        # split builds two, interior and frontier
+                                        # rows, each under this budget. Until the
+                                        # stacks were merged it counted each
+                                        # direction: an old explicit value now buys
+                                        # half the tiles it did
     block_tile: int = 512               # hybrid SpMM: square tile edge (512 default;
                                         # 256 = 4x more tiles per budget byte, finer
                                         # edge capture on clustered graphs at ~2x the
@@ -662,7 +669,7 @@ def create_parser() -> argparse.ArgumentParser:
     both("spmm-gather", type=str, default="native", choices=["native", "fp8", "int8"])
     both("spmm-dense", type=str, default="native", choices=["native", "int8"])
     both("block-occupancy", type=int, default=0)
-    both("block-tile-budget-mb", type=int, default=2048)
+    both("block-tile-budget-mb", type=int, default=4096)
     both("block-tile", type=int, default=512)
     p.add_argument("--reorder", type=str, default="off",
                    choices=["auto", "cluster", "off"],

@@ -294,7 +294,7 @@ def maybe_reorder(cfg: Config, art: PartitionArtifacts, log=print, obs=None
     tile = int(getattr(cfg, "block_tile", 512) or 512)
     occ = effective_occupancy(int(getattr(cfg, "block_occupancy", 0) or 0),
                               tile, tile)
-    budget = int(getattr(cfg, "block_tile_budget_mb", 2048)) << 20
+    budget = int(getattr(cfg, "block_tile_budget_mb", 4096)) << 20
     t0 = time.perf_counter()
     P = art.feat.shape[0]
     base_i = np.stack([cluster_order(art.src[p], art.dst[p], art.pad_inner,

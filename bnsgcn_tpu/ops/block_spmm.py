@@ -14,9 +14,11 @@ be aggregated on the MXU instead of the gather unit:
       >= occupancy_min edges become DENSE int8 tiles (edge multiplicities)
       with (row_block, col_block) ids sorted by row_block; every remaining
       edge goes to the usual bucketed-ELL residual;
-    * the backward layout is the exact per-tile TRANSPOSE (tiles [TC x TR],
-      ids swapped, re-sorted) — same edges, so the VJP is exact; the ELL
-      residual already builds its own fwd+bwd pair over the SAME edges.
+    * the backward reads the SAME int8 stack, transposed as it is read:
+      `blk_order_bwd` visits the tiles col_block-sorted, with (row_block,
+      col_block) ids swapped — same edges, so the VJP is exact, and one
+      stack holds every dense tile; the ELL residual already builds its
+      own fwd+bwd pair over the SAME edges.
   on device, per pass:
     * X_perm = X[inv perm] (one cheap permutation gather) sliced into
       [n_col_blocks, TC, H] slabs; slab gather by col_block id (contiguous
@@ -34,6 +36,7 @@ hybrid never loses. Replaces: reference DGL SpMM update_all(copy_u, sum)
 
 from __future__ import annotations
 
+import math
 from dataclasses import dataclass
 from functools import partial
 
@@ -71,18 +74,26 @@ class BlockSpec:
 
 def effective_occupancy(occupancy: int, tile_r: int = TR,
                         tile_c: int = TC) -> int:
-    """Resolve the occupancy knob: 0 = auto, the byte break-even of a
-    tile_r x tile_c int8 tile vs 512B gather rows (~tile_bytes/512 edges:
-    512 at the default 512x512 tile, 128 at 256x256). Explicit values are
-    absolute edge counts. Centralized so trainer CLI runs, bench variants,
-    and tools all scale the threshold with tile area identically."""
-    return occupancy if occupancy > 0 else max(tile_r * tile_c // 512, 16)
+    """Resolve the occupancy knob: 0 = auto, the TIME break-even of a
+    dense tile against the residual gather, half the tile's edge in edges
+    (256 at the default 512x512 tile, 128 at 256x256). On a v5e a residual
+    edge costs about 22.6 ns a step; the kernel's six calls a step (forward
+    and backward at widths 256, 256, 41) cost 5.94 us a 512x512 tile and
+    2.97 us a 256x256 one (over 4 GiB of tiles): a grid step's fixed cost
+    keeps the smaller tile at half the larger's time, not a quarter, so the
+    break-even follows the edge, not the area. Measured at those two sizes
+    only. Explicit values are absolute edge counts. Centralized so trainer
+    CLI runs, bench variants, and tools all resolve the threshold
+    identically."""
+    return (occupancy if occupancy > 0
+            else max(math.isqrt(tile_r * tile_c) // 2, 16))
 
 
 def _select_dense(tile_id, occupancy_min, tile_budget_bytes,
                   tile_bytes=TR * TC, need_inverse=True, n_tiles=None):
     """Which tiles densify: >= occupancy_min edges, highest-count tiles win
-    under the HBM budget (ties trimmed last). Shared by the real layout
+    under the HBM budget, the device bytes of the one int8 stack both
+    directions read (ties trimmed last). Shared by the real layout
     build and the O(E) coverage estimator behind --spmm auto (which skips
     the len(E) int64 inverse array — need_inverse=False).
 
@@ -121,7 +132,7 @@ def _select_dense(tile_id, occupancy_min, tile_budget_bytes,
 
 
 def estimate_coverage(perm_rows, perm_cols, n_rows, n_src, rows, cols,
-                      occupancy_min=512, tile_budget_bytes=2 << 30,
+                      occupancy_min=256, tile_budget_bytes=4 << 30,
                       tile_r=TR, tile_c=TC) -> float:
     """Fraction of edges that would land on dense MXU tiles under the
     given cluster order — the decision statistic for --spmm auto. One
@@ -150,25 +161,23 @@ def estimate_coverage(perm_rows, perm_cols, n_rows, n_src, rows, cols,
 
 
 def _build_tiles(perm_rows, perm_cols, n_rows, n_src, rows, cols,
-                 occupancy_min, tile_budget_bytes=2 << 30,
+                 occupancy_min, tile_budget_bytes=4 << 30,
                  tile_r=TR, tile_c=TC):
     """Dense tiles over cluster-ordered (rows x cols); fully vectorized.
 
-    A tile densifies only if it carries >= occupancy_min edges (an int8
-    512x512 tile costs TR*TC = 256KB of HBM reads per pass plus its slab
-    and output shares — byte break-even vs 512B-row gathers lands around
-    ~512 edges, the default threshold; scale occupancy with tile area) AND
-    the total dense storage stays under tile_budget_bytes (highest-count
-    tiles win; ties trimmed last).
+    A tile densifies only if it carries >= occupancy_min edges (the
+    time break-even against the residual gather, effective_occupancy) AND
+    the one stack both directions read stays under tile_budget_bytes of
+    device memory (highest-count tiles win; ties trimmed last).
     Returns (tiles int8 [B,tile_r,tile_c] sorted by row_blk, row_blk,
     col_blk, residual_edge_mask, extra_rows, extra_cols, rle) — the extras
     are >127 multiplicity overflow in PERMUTED coordinates. Tiles fill by a
     cell-id sort + run-length encode (writes only occupied cells); peak
     transient memory is O(E), not O(tiles). `rle` is the occupied-cell
     encoding (cell ids, clamped int8 counts) on the fast path (None on
-    legacy) — it lets the caller build the transposed bwd stack and the
-    per-row dense maxima by O(occupied) scatter/bincount instead of three
-    more passes over the multi-GB stack."""
+    legacy) — it lets the caller take the per-row dense maxima by an
+    O(occupied) bincount instead of two more passes over the multi-GB
+    stack."""
     n_cb = (n_src + tile_c - 1) // tile_c
     pr = perm_rows[rows]
     pc = perm_cols[cols]
@@ -244,31 +253,9 @@ def _row_dense_maxima(tiles, rb, cb, n_dst, n_src_ext, tile_r, tile_c):
     return int(per_row.max()), int(per_col.max())
 
 
-def repair_max_row_dense(fwd: BlockSpec, bwd: BlockSpec, arrays):
-    """Fill max_row_dense on BlockSpecs unpickled from a cache written
-    before the field existed (they deserialize with the class default 0 =
-    unknown, which would silently skip the int8 Pallas overflow guard).
-    Recomputed from the cached tile stacks; returns (fwd, bwd) updated.
-    A few seconds of host numpy per load at the 2 GB-stack bench scale —
-    vs invalidating every multi-GB layout cache with a version bump."""
-    if getattr(fwd, "max_row_dense", 0) and getattr(bwd, "max_row_dense", 0):
-        return fwd, bwd
-    import dataclasses
-    tiles_all = arrays["blk_tiles_fwd"]
-    mrd_f = mrd_b = 0
-    for p in range(tiles_all.shape[0]):
-        m_f, m_b = _row_dense_maxima(
-            np.asarray(tiles_all[p]), np.asarray(arrays["blk_rowb_fwd"][p]),
-            np.asarray(arrays["blk_colb_fwd"][p]), fwd.n_rows, bwd.n_rows,
-            fwd.row_tile, fwd.col_tile)
-        mrd_f, mrd_b = max(mrd_f, m_f), max(mrd_b, m_b)
-    return (dataclasses.replace(fwd, max_row_dense=mrd_f),
-            dataclasses.replace(bwd, max_row_dense=mrd_b))
-
-
 def build_block_layouts(src_all, dst_all, n_dst, n_src_ext, perm_inner,
-                        perm_ext, occupancy_min=512,
-                        tile_budget_bytes=2 << 30, agree=None,
+                        perm_ext, occupancy_min=256,
+                        tile_budget_bytes=4 << 30, agree=None,
                         tile_r=TR, tile_c=TC):
     """Hybrid layout for all local parts. perm_inner [P, n_dst] /
     perm_ext [P, n_src_ext]: cluster position per original row (the inner
@@ -378,29 +365,17 @@ def build_block_layouts(src_all, dst_all, n_dst, n_src_ext, perm_inner,
         else:
             tiles_f = np.zeros((P, B, tile_r, tile_c), dtype=np.int8)
         for p in range(P):
-            tiles, rb, cb, rle = per_part[p]
+            tiles, rb, cb, _ = per_part[p]
             bp = tiles.shape[0]
             if bp:
                 if tiles_f.base is not tiles:
                     tiles_f[p, :bp] = tiles
                 rowb_f[p, :bp] = rb
                 colb_f[p, :bp] = cb
-                # transpose: bwd tile (cb,rb) = fwd tile (rb,cb)^T, cb-sorted
+                # the backward visits the same tiles transposed, cb-sorted:
+                # bwd tile j is fwd tile order[j] read as (cb, rb)^T
                 o = np.argsort(cb, kind="stable")
-                if rle is not None:
-                    # write the transposed stack straight from the occupied-
-                    # cell RLE: O(occupied) scatter vs fancy-indexing +
-                    # assigning a strided transpose of the whole stack
-                    uc, c8 = rle
-                    t = uc // area
-                    r = (uc % area) // tile_c
-                    c = uc % tile_c
-                    pos_b = np.empty(bp, dtype=np.int64)
-                    pos_b[o] = np.arange(bp)
-                    tiles_b[p].reshape(-1)[pos_b[t] * area + c * tile_r
-                                           + r] = c8
-                else:
-                    tiles_b[p, :bp] = tiles[o].transpose(0, 2, 1)
+                order_b[p, :bp] = o
                 rowb_b[p, :bp] = cb[o]
                 colb_b[p, :bp] = rb[o]
             # release this part's stack as soon as it's copied (the P==1
@@ -410,13 +385,14 @@ def build_block_layouts(src_all, dst_all, n_dst, n_src_ext, perm_inner,
     tiles_f = None
     rowb_f = np.full((P, B), n_rb_f, dtype=np.int32)
     colb_f = np.zeros((P, B), dtype=np.int32)
-    tiles_b = np.zeros((P, B, tile_c, tile_r), dtype=np.int8)
+    # pad slots visit their own (all-zero) tile into the trash row block
+    order_b = np.tile(np.arange(B, dtype=np.int32), (P, 1))
     rowb_b = np.full((P, B), n_rb_b, dtype=np.int32)
     colb_b = np.zeros((P, B), dtype=np.int32)
     if layout_fastpath():
         # residual ELL FIRST, while the per-part stacks are the only live
-        # multi-GB objects: with the assembled fwd+bwd stacks also resident
-        # the same build measures ~5x slower on a 1-vCPU host (page-table /
+        # multi-GB objects: with the assembled stack also resident the
+        # same build measures ~5x slower on a 1-vCPU host (page-table /
         # TLB pressure from the extra GBs dominates its random gathers)
         ell_fwd, ell_bwd, ell_arrays = build_residual()
         build_stacks()
@@ -427,7 +403,7 @@ def build_block_layouts(src_all, dst_all, n_dst, n_src_ext, perm_inner,
     arrays = {
         "blk_tiles_fwd": tiles_f, "blk_rowb_fwd": rowb_f,
         "blk_colb_fwd": colb_f,
-        "blk_tiles_bwd": tiles_b, "blk_rowb_bwd": rowb_b,
+        "blk_order_bwd": order_b, "blk_rowb_bwd": rowb_b,
         "blk_colb_bwd": colb_b,
         "blk_perm_ext": perm_ext.astype(np.int32),
         "blk_perm_inner": perm_inner.astype(np.int32),
@@ -471,8 +447,8 @@ def _compact_rank_perm(perm_full: np.ndarray, mask: np.ndarray,
 
 
 def build_split_block_layouts(src_all, dst_all, n_dst, n_src_ext, perm_inner,
-                              perm_ext, occupancy_min=512,
-                              tile_budget_bytes=2 << 30,
+                              perm_ext, occupancy_min=256,
+                              tile_budget_bytes=4 << 30,
                               tile_r=TR, tile_c=TC):
     """Interior/frontier row-partitioned hybrid layouts (--overlap split).
 
@@ -529,9 +505,11 @@ def dense_edge_count(arrays, part: int = 0) -> int:
     for key in ("blk_tiles_fwd", "int_blk_tiles_fwd", "fro_blk_tiles_fwd"):
         tiles = arrays.get(key)
         if tiles is not None:
-            # sum(dtype=) accumulates in int64 without an 8x copy of the
-            # (up to multi-GB) int8 stack
-            total += int(np.asarray(tiles[part]).sum(dtype=np.int64))
+            # per-tile int32 sums (a 512x512 tile holds at most 2^25), then
+            # int64: no 8x copy of the (multi-GB) int8 stack, and half the
+            # set-up time of one int64 reduction over it
+            total += int(np.asarray(tiles[part]).sum(
+                axis=(1, 2), dtype=np.int32).sum(dtype=np.int64))
     return total
 
 
@@ -571,8 +549,13 @@ def _tile_chunk_for(n_blocks: int, row_tile: int, width: int,
 
 
 def _dense_apply(spec: BlockSpec, tiles, rowb, colb, perm_src, perm_out, h,
-                 dense_dtype: str = "native"):
+                 dense_dtype: str = "native", order=None):
     """Dense-tile aggregation; returns [n_rows, H] in ORIGINAL row order.
+
+    With `order` (the backward) `tiles` is the forward stack and tile j of
+    this direction is tiles[order[j]] transposed: the scan runs over the
+    stack as stored, each tile contracted over its rows, and segment-sums
+    into the row block it lands in (unsorted in stack order).
 
     dense_dtype='int8' quantizes each [TC, H] activation slab to int8 with
     one scale (symmetric, amax/127) and runs the tile matmul fully in int8
@@ -584,10 +567,16 @@ def _dense_apply(spec: BlockSpec, tiles, rowb, colb, perm_src, perm_out, h,
 
     The tile stack is processed in `lax.scan` chunks (bounded [C, TR, H]
     partials + one [n_row_blocks+1, TR, H] accumulator) instead of one
-    [B, TR, H] einsum, keeping HLO temps flat in B; rowb is sorted, so
-    per-chunk segment ids stay sorted."""
+    [B, TR, H] einsum, keeping HLO temps flat in B."""
     H = h.shape[1]
     B = tiles.shape[0]
+    sorted_ids = order is None
+    prod = "brc,bch->brh"
+    if order is not None:
+        # this direction's ids, in the order the stack stores its tiles
+        at = jnp.zeros_like(order).at[order].set(
+            jnp.arange(B, dtype=order.dtype))
+        rowb, colb, prod = rowb[at], colb[at], "bcr,bch->brh"
     x_perm = build_x_slabs(spec, perm_src, h)
     if dense_dtype == "int8":
         # per-slab scales from the input-dtype amax (bf16 values are exact
@@ -603,12 +592,12 @@ def _dense_apply(spec: BlockSpec, tiles, rowb, colb, perm_src, perm_out, h,
             xc = x_perm[colb_c].astype(jnp.float32)
             qc = jnp.clip(jnp.round(xc / scale[colb_c][:, None, None]),
                           -127, 127).astype(jnp.int8)
-            p = jnp.einsum("brc,bch->brh", tiles_c, qc,
+            p = jnp.einsum(prod, tiles_c, qc,
                            preferred_element_type=jnp.int32)
             return p.astype(jnp.float32) * scale[colb_c][:, None, None]
     else:
         def chunk_prod(tiles_c, colb_c):
-            return jnp.einsum("brc,bch->brh", tiles_c.astype(h.dtype),
+            return jnp.einsum(prod, tiles_c.astype(h.dtype),
                               x_perm[colb_c],
                               preferred_element_type=jnp.float32)
 
@@ -623,7 +612,7 @@ def _dense_apply(spec: BlockSpec, tiles, rowb, colb, perm_src, perm_out, h,
         tiles_c, rowb_c, colb_c = x
         s = jax.ops.segment_sum(chunk_prod(tiles_c, colb_c), rowb_c,
                                 num_segments=n_seg,
-                                indices_are_sorted=True)
+                                indices_are_sorted=sorted_ids)
         return acc + s, None
 
     # full chunks go through the scan as a prefix-slice + reshape (both
@@ -645,7 +634,8 @@ def _dense_apply(spec: BlockSpec, tiles, rowb, colb, perm_src, perm_out, h,
     if rem:
         seg = seg + jax.ops.segment_sum(
             chunk_prod(tiles[n_full * C:], colb[n_full * C:]),
-            rowb[n_full * C:], num_segments=n_seg, indices_are_sorted=True)
+            rowb[n_full * C:], num_segments=n_seg,
+            indices_are_sorted=sorted_ids)
     seg = seg[:spec.n_row_blocks]
     flat = seg.reshape(spec.n_row_blocks * spec.row_tile, H).astype(h.dtype)
     return flat[perm_out]                                  # original row order
@@ -680,7 +670,7 @@ def make_block_spmm(fwd: BlockSpec, bwd: BlockSpec, ell_pair,
                     gather_dtype: str = "native",
                     dense_dtype: str = "native", accum: str = "auto"):
     """Returns spmm(arrays, h_ext) -> [n_dst, H]: dense tiles on the MXU +
-    ELL residual, custom VJP running the transposed tiles.
+    ELL residual, custom VJP reading the same tiles transposed.
     dense_dtype='int8': quantized int8 MXU tile path — per-slab scales on
     the XLA formulation (_dense_apply), one per-call scale on the fused
     Pallas kernel (pallas_block.dense_apply_pallas).
@@ -701,17 +691,19 @@ def make_block_spmm(fwd: BlockSpec, bwd: BlockSpec, ell_pair,
                 if k.startswith("res_")}
 
     @jax.named_scope(tp.AGG_TILES)
-    def _dense(spec_d, arrays, tiles_key, rowb_key, colb_key, perm_src_key,
-               perm_out_key, h):
+    def _dense(spec_d, arrays, d, h):
+        # both directions read the one forward stack; the backward visits
+        # it through blk_order_bwd, transposed
+        perm_src, perm_out = (("blk_perm_ext", "blk_perm_inner") if d == "fwd"
+                              else ("blk_perm_inner", "blk_perm_ext"))
+        ops = (spec_d, arrays["blk_tiles_fwd"], arrays[f"blk_rowb_{d}"],
+               arrays[f"blk_colb_{d}"], arrays[perm_src], arrays[perm_out], h)
+        order = arrays["blk_order_bwd"] if d == "bwd" else None
         if dense_path(spec_d, dense_dtype) == "pallas":
             from bnsgcn_tpu.ops.pallas_block import dense_apply_pallas
-            return dense_apply_pallas(
-                spec_d, arrays[tiles_key], arrays[rowb_key], arrays[colb_key],
-                arrays[perm_src_key], arrays[perm_out_key], h,
-                dense_dtype=dense_dtype)
-        return _dense_apply(spec_d, arrays[tiles_key], arrays[rowb_key],
-                            arrays[colb_key], arrays[perm_src_key],
-                            arrays[perm_out_key], h, dense_dtype=dense_dtype)
+            return dense_apply_pallas(*ops, order=order,
+                                      dense_dtype=dense_dtype)
+        return _dense_apply(*ops, dense_dtype=dense_dtype, order=order)
 
     def _swap_dirs(arrays):
         out = {}
@@ -726,9 +718,7 @@ def make_block_spmm(fwd: BlockSpec, bwd: BlockSpec, ell_pair,
 
     @jax.custom_vjp
     def spmm(arrays, h_ext):
-        dense = _dense(fwd, arrays, "blk_tiles_fwd", "blk_rowb_fwd",
-                       "blk_colb_fwd", "blk_perm_ext", "blk_perm_inner",
-                       h_ext)
+        dense = _dense(fwd, arrays, "fwd", h_ext)
         return dense + ell(_res_arrays(arrays), h_ext)
 
     def fwd_rule(arrays, h_ext):
@@ -736,8 +726,7 @@ def make_block_spmm(fwd: BlockSpec, bwd: BlockSpec, ell_pair,
 
     def bwd_rule(res, g):
         (arrays,) = res
-        d_dense = _dense(bwd, arrays, "blk_tiles_bwd", "blk_rowb_bwd",
-                         "blk_colb_bwd", "blk_perm_inner", "blk_perm_ext", g)
+        d_dense = _dense(bwd, arrays, "bwd", g)
         d_res = ell_t(_swap_dirs(_res_arrays(arrays)), g)
         return None, (d_dense + d_res).astype(g.dtype)
 

@@ -131,13 +131,46 @@ def build_block_arrays(art: PartitionArtifacts, model: str,
     return blk
 
 
+# A v5e moves one host-to-device transfer of 2**32 bytes or more about seven
+# times slower than the same bytes in smaller pieces (one 4 GiB int8 array
+# 5.3-7.2 s; 4 GiB less 256 KiB, or two of 2 GiB, 0.56-1.05 s).
+_PUT_SLOW_BYTES = 1 << 32
+_PUT_CHUNK_BYTES = 256 << 20
+
+
+@partial(jax.jit, donate_argnums=0)
+def _put_chunk(buf, chunk, start):
+    return jax.lax.dynamic_update_slice_in_dim(buf, chunk, start, 1)
+
+
+def _device_put_parts(v, sh: NamedSharding):
+    """device_put, except that an array whose per-device shard reaches
+    _PUT_SLOW_BYTES (the hybrid layout's dense-tile stack) is copied in
+    _PUT_CHUNK_BYTES pieces along axis 1 into one donated device buffer,
+    one piece on the device at a time: the transfer then runs at the fast
+    rate, at one piece of transient device memory."""
+    shard_bytes = (int(np.prod(sh.shard_shape(np.shape(v))))
+                   * np.dtype(v.dtype).itemsize)
+    if shard_bytes < _PUT_SLOW_BYTES or np.ndim(v) < 2:
+        return jax.device_put(v, sh)
+    step = max(1, v.shape[1] * _PUT_CHUNK_BYTES // shard_bytes)
+    buf = jax.jit(partial(jnp.zeros, v.shape, v.dtype), out_shardings=sh)()
+    for start in range(0, v.shape[1], step):
+        chunk = jax.device_put(np.ascontiguousarray(v[:, start:start + step]),
+                               sh)
+        buf = _put_chunk(buf, chunk, start)
+        buf.block_until_ready()
+        del chunk
+    return buf
+
+
 def place_blocks(blk: dict, mesh: Mesh) -> dict:
     """Host [P, ...] arrays -> parts-sharded device arrays. device_put takes
     the host array itself, so each part's rows go to their own device and
     nowhere else (through jnp.asarray the whole stack would sit on device 0
     first and be re-sharded from there)."""
     sh = parts_sharding(mesh)
-    return {k: jax.device_put(v, sh) for k, v in blk.items()}
+    return {k: _device_put_parts(v, sh) for k, v in blk.items()}
 
 
 def place_replicated(tree, mesh: Mesh):
@@ -302,12 +335,15 @@ def hybrid_layout_key(cfg: Config) -> str:
     """layout_cache key for the hybrid SpMM under cfg's tiling knobs —
     shared with bench.py's on-disk layout pickles so they cannot drift.
     Uses the EFFECTIVE occupancy, so auto (0) and an equal explicit value
-    share one cache entry, and pre-tile-knob keys stay valid. --overlap
-    split builds a differently-shaped (interior/frontier row-partitioned)
-    layout and gets its own ':ovl' namespace; an applied --reorder builds
-    from permuted rows and gets ':ro'."""
+    share one cache entry. ':1stack' names the format in which both
+    directions read one tile stack (blk_order_bwd, no blk_tiles_bwd): a
+    layout cached with a transposed stack under the same knobs is never
+    loaded into a program that reads one. --overlap split builds a
+    differently-shaped (interior/frontier row-partitioned) layout and gets
+    its own ':ovl' namespace; an applied --reorder builds from permuted
+    rows and gets ':ro'."""
     occ, tile, budget = hybrid_tiling(cfg)
-    key = f"hybrid:{occ}:{budget}"
+    key = f"hybrid:{occ}:{budget}:1stack"
     if tile != 512:
         key += f":t{tile}"
     if cfg.overlap == "split":
@@ -425,19 +461,26 @@ def spmm_counts(kind: str, spec: ModelSpec, arrays: dict, n_local: int,
     ell._bucket_sum reads) and the real edges among them (slots over edges
     is what the bucket geometry costs), the aggregations per step and the
     columns each gathers (`agg_widths`), and the layers that project before
-    they aggregate (`narrow_layers`: layer, fin, fout).
+    they aggregate (`narrow_layers`: layer, fin, fout), the device bytes
+    of the tile stack both directions read (`tile_stack_bytes`) and the
+    share of the local parts' edges the tiles carry (`dense_share`).
     `spec_pairs` as in _hybrid_desc."""
     out = {"path": kind}
     for d, p in dense_paths(spec_pairs, dense_dtype).items():
         out[f"dense_path_{d}"] = p
+    stack_bytes = 0
+    tiles_pp = np.zeros(n_local, np.int64)
+    for pre, (f, _) in (spec_pairs or {}).items():
+        t = arrays.get(f"{pre}blk_tiles_fwd")
+        if t is not None:
+            stack_bytes += t[0].nbytes
+            # pad slots carry rowb == n_row_blocks; the backward visits
+            # the same tiles through blk_order_bwd
+            tiles_pp += (np.asarray(arrays[f"{pre}blk_rowb_fwd"])
+                         < f.n_row_blocks).sum(axis=1)
+    out["tiles_fwd"] = out["tiles_bwd"] = int(tiles_pp.max(initial=0))
+    out["tile_stack_bytes"] = int(stack_bytes)
     for d, other in (("fwd", "bwd"), ("bwd", "fwd")):
-        per_part = np.zeros(n_local, np.int64)
-        for pre, pair in (spec_pairs or {}).items():
-            rb = arrays.get(f"{pre}blk_rowb_{d}")
-            if rb is not None:            # pad slots carry rowb == n_row_blocks
-                per_part += (np.asarray(rb) < pair[d == "bwd"].n_row_blocks
-                             ).sum(axis=1)
-        out[f"tiles_{d}"] = int(per_part.max(initial=0))
         # a padded slot holds the index of the appended zero row: the rows
         # the table gathers from, which the other direction's perm counts
         slots, edges = 0, np.zeros(n_local, np.int64)
@@ -450,6 +493,11 @@ def spmm_counts(kind: str, spec: ModelSpec, arrays: dict, n_local: int,
                 edges += (v < n_src).sum(axis=(1, 2))
         out[f"residual_slots_{d}"] = int(slots)
         out[f"residual_edges_{d}"] = int(edges.max(initial=0))
+        if d == "fwd":
+            # every edge is in a tile or in the residual (a tile cell's
+            # multiplicity past 127 rides the residual)
+            dense = int(sum(dense_per_part))
+            out["dense_share"] = dense / max(dense + int(edges.sum()), 1)
     out["dense_edges"] = int(max(dense_per_part, default=0))
     fwd, bwd, narrow_layers = agg_widths(spec, n_feat)
     out.update(agg_calls_fwd=len(fwd), agg_calls_bwd=len(bwd),
@@ -747,16 +795,9 @@ def build_step_fns(cfg: Config, spec: ModelSpec, art: PartitionArtifacts,
         t0_b = time.perf_counter()
         hyb_cached = layout_cache is not None and hyb_key in layout_cache
         if hyb_cached:
+            # every layout under a ':1stack' key was built with
+            # BlockSpec.max_row_dense filled in
             fwd_b, bwd_b, ell_pair, ell_arrays = layout_cache[hyb_key]
-            if cfg.spmm_dense == "int8":
-                # layouts cached before BlockSpec.max_row_dense existed
-                # deserialize with 0 (= unknown), which would skip the
-                # int8 Pallas accumulator-overflow guard; recompute from
-                # the cached tile stacks (seconds of host numpy) and
-                # refresh the cache entry
-                from bnsgcn_tpu.ops.block_spmm import repair_max_row_dense
-                fwd_b, bwd_b = repair_max_row_dense(fwd_b, bwd_b, ell_arrays)
-                layout_cache[hyb_key] = (fwd_b, bwd_b, ell_pair, ell_arrays)
         else:
             agree = None
             if jax.process_count() > 1:

@@ -18,8 +18,9 @@ Phases (every one runs; any failure fails the script):
   kernel    ops/pallas_block.dense_apply_pallas against its XLA twin
             ops/block_spmm._dense_apply and a float64 host reference, on the
             chip, at tile 512 and 256, H = 41, 256 and 602, bf16, float32
-            and int8 slabs (KERNEL_CASES), and on the four tile stacks of
-            an --overlap split layout.
+            and int8 slabs (KERNEL_CASES), forward and backward (the same
+            stack read transposed), and on the two tile stacks of an
+            --overlap split layout.
   P=4       the recipe at --n-partitions 4, when the host has >= 4 chips
             (first, so each chip's peak memory is this run's alone).
   P=1       the recipe at --n-partitions 1.
@@ -143,17 +144,20 @@ def _exact(tiles, rowb, colb, perm_src, perm_out, h64, tile, n_rb, n_cb):
 
 
 def _agree(name, spec, args, dense, exact, absdot, deg, amax, out,
-           zero_rows=None):
+           zero_rows=None, order=None):
     """Run one direction's dense tiles through the kernel and its XLA twin
-    on the chip and hold both to the float64 reference."""
+    on the chip and hold both to the float64 reference (`order`: the
+    backward, reading the forward stack in `args` transposed)."""
     import jax
     import jax.numpy as jnp
 
     from bnsgcn_tpu.ops.block_spmm import _dense_apply
     from bnsgcn_tpu.ops.pallas_block import dense_apply_pallas
 
-    pal = jax.jit(lambda *a: dense_apply_pallas(spec, *a, dense_dtype=dense))
-    xla = jax.jit(lambda *a: _dense_apply(spec, *a, dense_dtype=dense))
+    pal = jax.jit(lambda *a: dense_apply_pallas(spec, *a, dense_dtype=dense,
+                                                order=order))
+    xla = jax.jit(lambda *a: _dense_apply(spec, *a, dense_dtype=dense,
+                                          order=order))
     if "tpu_custom_call" not in pal.lower(*args).compile().as_text():
         raise AssertionError(f"{name}: the Pallas path compiled without a "
                              f"Mosaic custom call")
@@ -241,20 +245,42 @@ def kernel_phase(report: dict):
                                tile, n_rb, n_cb)
         args = tuple(jnp.asarray(a) for a in
                      (tiles, rowb, colb, perm_src, perm_out)) + (h,)
+        # the backward over the same stack, as build_block_layouts lays it out:
+        # col_block-sorted, ids swapped, the pads last into the trash block
+        o = np.argsort(colb[:-2], kind="stable")
+        order = np.concatenate([o, [B - 2, B - 1]]).astype(np.int32)
+        rowb_t = np.concatenate([colb[o], [n_cb, n_cb]]).astype(np.int32)
+        colb_t = np.concatenate([rowb[o], [0, 0]]).astype(np.int32)
+        col_deg = np.zeros((n_cb + 1) * tile)
+        np.add.at(col_deg.reshape(n_cb + 1, tile), colb,
+                  tiles.sum(axis=1, dtype=np.int64))
+        spec_t = BlockSpec(n_rows=n_src, n_src=n_rows, row_tile=tile,
+                           col_tile=tile, n_blocks=B, n_row_blocks=n_cb,
+                           max_row_dense=int(col_deg.max()))
+        g = jnp.asarray(rng.normal(size=(n_rows, H)), jnp.dtype(slab))
+        g64 = np.asarray(g.astype(jnp.float32), np.float64)
+        exact_t, absdot_t = _exact(tiles[order].transpose(0, 2, 1), rowb_t,
+                                   colb_t, perm_out, perm_src, g64, tile,
+                                   n_cb, n_rb)
+        args_t = tuple(jnp.asarray(a) for a in
+                       (tiles, rowb_t, colb_t, perm_out, perm_src)) + (g,)
         for dense in denses:
             name = f"t{tile}/H{H}/{slab}" + ("/int8" if dense == "int8"
                                              else "")
             _agree(name, spec, args, dense, exact, absdot,
                    row_deg[perm_out][:, None], float(np.abs(h64).max()),
                    out, zero_rows=perm_out // tile == 1)
+            _agree(name + "/bwd", spec_t, args_t, dense, exact_t, absdot_t,
+                   col_deg[perm_src][:, None], float(np.abs(g64).max()),
+                   out, order=jnp.asarray(order))
     split_layout_check(out)
     report["kernel"] = out
 
 
 def split_layout_check(out: dict):
-    """The kernel against its twin on the four tile stacks an --overlap
-    split build lays out (interior and frontier rows, forward and
-    transposed), from a two-part partition of a clustered graph."""
+    """The kernel against its twin on the two tile stacks an --overlap
+    split build lays out (interior and frontier rows), each read forward
+    and transposed, from a two-part partition of a clustered graph."""
     import jax.numpy as jnp
 
     from bnsgcn_tpu.data.artifacts import build_artifacts
@@ -283,19 +309,23 @@ def split_layout_check(out: dict):
             src_key, out_key = (("blk_perm_ext", "blk_perm_inner")
                                 if d == "fwd" else
                                 ("blk_perm_inner", "blk_perm_ext"))
-            ops = (a[f"blk_tiles_{d}"], a[f"blk_rowb_{d}"],
+            ops = (a["blk_tiles_fwd"], a[f"blk_rowb_{d}"],
                    a[f"blk_colb_{d}"], a[src_key], a[out_key])
+            order = a.get(f"blk_order_{d}")
             real = ops[1] < spec.n_row_blocks
             if not real.any():
                 raise AssertionError(f"split {pre}{d}: no dense tiles")
             h = jnp.asarray(rng.normal(size=(spec.n_src, H)), jnp.bfloat16)
             h64 = np.asarray(h.astype(jnp.float32), np.float64)
             n_cb = -(-spec.n_src // tile)
-            exact, absdot = _exact(ops[0], ops[1], ops[2], ops[3], ops[4],
+            tiles_d = (ops[0] if order is None
+                       else ops[0][order].transpose(0, 2, 1))
+            exact, absdot = _exact(tiles_d, ops[1], ops[2], ops[3], ops[4],
                                    h64, tile, spec.n_row_blocks, n_cb)
             _agree(f"split/{pre}{d}/t{tile}/H{H}/bfloat16", spec,
                    tuple(jnp.asarray(x) for x in ops) + (h,), "native",
-                   exact, absdot, None, float(np.abs(h64).max()), out)
+                   exact, absdot, None, float(np.abs(h64).max()), out,
+                   order=None if order is None else jnp.asarray(order))
             out[f"split/{pre}{d}/t{tile}/H{H}/bfloat16"]["tiles"] = int(
                 real.sum())
 

@@ -444,25 +444,40 @@ def test_spmm_auto_resolution():
         assert np.isfinite(float(loss))
 
 
-def test_max_row_dense_repair_matches_build():
-    """Layouts cached before BlockSpec.max_row_dense existed deserialize
-    with 0 (= unknown), which would skip the int8 Pallas overflow guard;
-    repair_max_row_dense must recompute the exact build-time values from
-    the cached tile stacks (round-4 advisor / round-5 review finding)."""
-    import dataclasses
-    from bnsgcn_tpu.ops.block_spmm import repair_max_row_dense
+def test_max_row_dense_repair_matches_build(monkeypatch):
+    """The int8 Pallas overflow guard reads max_row_dense in both
+    directions. The build fills both from the RLE bincount (fast path) or
+    from a scan of the stack (legacy path); with one stored stack, the
+    backward's value must equal the column sums of the forward tiles, and
+    the backward's view (tiles taken through blk_order_bwd, grouped by
+    blk_rowb_bwd) must count the same rows."""
+    from bnsgcn_tpu.ops.block_spmm import _row_dense_maxima
     g = synthetic_graph(n_nodes=120, avg_degree=8, n_feat=4, seed=9)
     art = build_artifacts(g, partition_graph(g, 2, method="random", seed=1))
-    fwd, bwd, _, arrays = _hybrid_for(art, occupancy_min=4, tile=32)
-    assert fwd.max_row_dense > 0       # build computed real values
-    stale_f = dataclasses.replace(fwd, max_row_dense=0)
-    stale_b = dataclasses.replace(bwd, max_row_dense=0)
-    rf, rb = repair_max_row_dense(stale_f, stale_b, arrays)
-    assert rf.max_row_dense == fwd.max_row_dense
-    assert rb.max_row_dense == bwd.max_row_dense
-    # already-filled specs pass through untouched
-    pf, pb = repair_max_row_dense(fwd, bwd, arrays)
-    assert pf is fwd and pb is bwd
+    tile = 32
+    fwd, bwd, _, arrays = _hybrid_for(art, occupancy_min=4, tile=tile)
+    assert "blk_tiles_bwd" not in arrays
+    assert fwd.max_row_dense > 0 and bwd.max_row_dense > 0
+    tiles = arrays["blk_tiles_fwd"]
+    scan_f = scan_b = view_b = 0
+    for p in range(art.n_parts):
+        m_f, m_b = _row_dense_maxima(
+            tiles[p], arrays["blk_rowb_fwd"][p], arrays["blk_colb_fwd"][p],
+            art.pad_inner, art.n_ext, tile, tile)
+        scan_f, scan_b = max(scan_f, m_f), max(scan_b, m_b)
+        # backward as the kernel reads it: tile j is fwd tile order[j],
+        # its output rows are the forward tile's columns
+        per_row = np.zeros((bwd.n_row_blocks + 1, tile), np.int64)
+        np.add.at(per_row, arrays["blk_rowb_bwd"][p],
+                  tiles[p][arrays["blk_order_bwd"][p]].sum(
+                      axis=1, dtype=np.int64))
+        view_b = max(view_b, int(per_row.max()))
+    assert fwd.max_row_dense == scan_f
+    assert bwd.max_row_dense == scan_b == view_b
+    # the legacy (scan) path builds the same guard
+    monkeypatch.setenv("BNSGCN_LAYOUT_FASTPATH", "0")
+    lf, lb, _, _ = _hybrid_for(art, occupancy_min=4, tile=tile)
+    assert (lf.max_row_dense, lb.max_row_dense) == (scan_f, scan_b)
 
 
 def test_dense_edge_count_split_and_missing_keys():
@@ -512,3 +527,235 @@ def test_dense_edge_count_split_and_missing_keys():
     # arrays with no tiles keys at all (the auto path drops empty stacks
     # from extra_blk, test_spmm_auto_resolution) -> 0, not KeyError
     assert dense_edge_count({"merge_perm": np.arange(4)}) == 0
+
+
+# ---------------------------------------------------------------------------
+# one int8 stack for both directions: the backward reads the forward tiles
+# ---------------------------------------------------------------------------
+
+@pytest.fixture(scope="module")
+def stack_builds():
+    """(layout, tile) -> _stack_build's result, built once a module run."""
+    return {}
+
+
+def _stack_build(cache, layout, tile):
+    """Every tile stack one build lays out, as (name, bwd BlockSpec, one
+    part's arrays): the fused layout at P=1 and P=2, and the --overlap
+    split layout's int_ / fro_ stacks (P=2), on a clustered graph with
+    several row blocks a part at both tile sizes."""
+    from bnsgcn_tpu.ops.block_spmm import build_split_block_layouts
+    if (layout, tile) in cache:
+        return cache[layout, tile]
+    g = sbm_graph(n_nodes=1400, n_class=4, n_feat=4, p_in=0.03,
+                  p_out=0.0005, seed=70)
+    n_parts = 1 if layout == "p1" else 2
+    art = build_artifacts(g, partition_graph(g, n_parts))
+    perms = [cluster_order(art.src[p], art.dst[p], art.pad_inner, art.n_ext,
+                           target=64) for p in range(n_parts)]
+    pi = np.stack([a for a, _ in perms])
+    pe = np.stack([b for _, b in perms])
+    if layout == "split":
+        (_, int_b, _), (_, fro_b, _), arrays, _, _ = \
+            build_split_block_layouts(art.src, art.dst, art.pad_inner,
+                                      art.n_ext, pi, pe, occupancy_min=4,
+                                      tile_r=tile, tile_c=tile)
+        pairs = (("int_", int_b), ("fro_", fro_b))
+    else:
+        _, bwd, _, arrays = build_block_layouts(
+            art.src, art.dst, art.pad_inner, art.n_ext, pi, pe,
+            occupancy_min=4, tile_r=tile, tile_c=tile)
+        pairs = (("", bwd),)
+    out = []
+    for pre, bwd in pairs:
+        for p in range(n_parts):
+            a = {k[len(pre):]: np.asarray(v[p]) for k, v in arrays.items()
+                 if k.startswith(pre)}
+            out.append((f"{pre}p{p}", bwd, a))
+    assert any((a["blk_rowb_bwd"] < b.n_row_blocks).sum() > 1
+               for _, b, a in out), "no stack with two real tiles"
+    cache[layout, tile] = out
+    return out
+
+
+@pytest.mark.parametrize("layout", ["p1", "p2", "split"])
+@pytest.mark.parametrize("H,tile", [(256, 512), (41, 512), (602, 512),
+                                    (256, 256), (41, 256), (602, 256)])
+def test_backward_over_forward_stack_matches_transposed_stack(
+        stack_builds, layout, H, tile):
+    """The backward reading the forward stack through blk_order_bwd equals
+    the backward over the transposed stack the layout used to hold (tiles
+    [order] transposed, the same rowb / colb): the kernel (interpret mode)
+    for a float32 slab to 1e-6 and for an int8 slab bitwise, and the XLA
+    twin for a float32 slab to 1e-6."""
+    from bnsgcn_tpu.ops.block_spmm import _dense_apply
+    from bnsgcn_tpu.ops.pallas_block import pallas_tile_matmul
+    rng = np.random.default_rng(H + tile)
+    for name, bwd, a in _stack_build(stack_builds, layout, tile):
+        tiles, order = a["blk_tiles_fwd"], a["blk_order_bwd"]
+        rowb, colb = a["blk_rowb_bwd"], a["blk_colb_bwd"]
+        n_rb = bwd.n_row_blocks
+        assert sorted(order.tolist()) == list(range(len(order)))
+        assert (np.diff(rowb) >= 0).all()
+        transposed = np.ascontiguousarray(tiles[order].transpose(0, 2, 1))
+        n_cb = -(-bwd.n_src // tile)
+        visited = np.zeros(n_rb + 1, bool)
+        visited[rowb] = True
+        x32 = rng.normal(size=(n_cb, tile, H)).astype(np.float32)
+        x8 = rng.integers(-127, 128, (n_cb, tile, H)).astype(np.int8)
+        for x in (x32, x8):
+            ops = tuple(map(jnp.asarray, (rowb, colb, x)))
+            ref = np.asarray(pallas_tile_matmul(
+                jnp.asarray(transposed), *ops, n_rb, interpret=True))
+            got = np.asarray(pallas_tile_matmul(
+                jnp.asarray(tiles), *ops, n_rb, order=jnp.asarray(order),
+                interpret=True))
+            assert got.shape == ref.shape == (n_rb + 1, tile, H)
+            if x.dtype == np.int8:
+                assert got.dtype == np.int32, name
+                np.testing.assert_array_equal(got[visited], ref[visited],
+                                              err_msg=name)
+            else:
+                np.testing.assert_allclose(
+                    got[visited], ref[visited], rtol=1e-6,
+                    atol=1e-6 * float(np.abs(ref[visited]).max()),
+                    err_msg=name)
+        g = jnp.asarray(rng.normal(size=(bwd.n_src, H)), jnp.float32)
+        perms = tuple(map(jnp.asarray, (rowb, colb, a["blk_perm_inner"],
+                                        a["blk_perm_ext"])))
+        ref = np.asarray(_dense_apply(bwd, jnp.asarray(transposed), *perms, g))
+        got = np.asarray(_dense_apply(bwd, jnp.asarray(tiles), *perms, g,
+                                      order=jnp.asarray(order)))
+        np.testing.assert_allclose(got, ref, rtol=1e-6,
+                                   atol=1e-6 * float(np.abs(ref).max()),
+                                   err_msg=name)
+
+
+@pytest.mark.parametrize("path", ["xla", "pallas"])
+def test_backward_vjp_is_dense_transpose(monkeypatch, path):
+    """jax.vjp of the hybrid SpMM is A^T g against the dense oracle on
+    every part, through the XLA twin and through the kernel (interpret
+    mode), with the backward reading the forward stack."""
+    from functools import partial
+
+    from bnsgcn_tpu.ops import block_spmm as bs
+    from bnsgcn_tpu.ops import pallas_block
+    if path == "pallas":
+        monkeypatch.setattr(bs, "dense_path", lambda *a: "pallas")
+        monkeypatch.setattr(pallas_block, "dense_apply_pallas",
+                            partial(pallas_block.dense_apply_pallas,
+                                    interpret=True))
+    g = sbm_graph(n_nodes=300, n_class=5, n_feat=6, p_in=0.15, p_out=0.003,
+                  seed=71)
+    art = build_artifacts(g, partition_graph(g, 2, method="random", seed=3))
+    fwd, bwd, ell_pair, arrays = _hybrid_for(art, 4, tile=64)
+    assert fwd.n_row_blocks > 1 and dense_edge_count(arrays, 0) > 0
+    assert "blk_tiles_bwd" not in arrays
+    _assert_oracle_and_grads(art, make_block_spmm(fwd, bwd, ell_pair),
+                             arrays)
+
+
+def test_layout_holds_one_stack_of_the_budgeted_bytes():
+    """No transposed stack: one int8 stack a part, whose device bytes the
+    budget bounds (it binds here), read by both directions through
+    blk_order_bwd, a col_block-sorted permutation of the stored tiles; the
+    split layout's two builds likewise. The run header counts the same
+    tiles both ways, the stack's bytes and the dense share of all edges."""
+    from bnsgcn_tpu.models.gnn import ModelSpec
+    from bnsgcn_tpu.ops.block_spmm import build_split_block_layouts
+    from bnsgcn_tpu.trainer import spmm_counts
+    g = sbm_graph(n_nodes=300, n_class=5, n_feat=6, p_in=0.15, p_out=0.003,
+                  seed=61)
+    art = build_artifacts(g, partition_graph(g, 2, method="random", seed=3))
+    tile = 32
+    spec_free, _, _, free = _hybrid_for(art, 4, tile=tile)
+    n_free = int((free["blk_rowb_fwd"] < spec_free.n_row_blocks).sum(
+        axis=1).max())
+    budget = (n_free // 2) * tile * tile
+    perms = [cluster_order(art.src[p], art.dst[p], art.pad_inner, art.n_ext,
+                           target=tile) for p in range(2)]
+    pi = np.stack([a for a, _ in perms])
+    pe = np.stack([b for _, b in perms])
+    fwd, bwd, _, arrays = build_block_layouts(
+        art.src, art.dst, art.pad_inner, art.n_ext, pi, pe, occupancy_min=4,
+        tile_budget_bytes=budget, tile_r=tile, tile_c=tile)
+    assert not any(k.endswith("tiles_bwd") for k in arrays)
+    assert arrays["blk_order_bwd"].dtype == np.int32
+    stack = arrays["blk_tiles_fwd"]
+    assert stack[0].nbytes <= budget
+    real = (arrays["blk_rowb_fwd"] < fwd.n_row_blocks).sum(axis=1)
+    assert real.max() == budget // (tile * tile) == n_free // 2
+    for p in range(2):
+        order = arrays["blk_order_bwd"][p]
+        assert sorted(order.tolist()) == list(range(stack.shape[1]))
+        np.testing.assert_array_equal(arrays["blk_rowb_bwd"][p][:real[p]],
+                                      arrays["blk_colb_fwd"][p][order][
+                                          :real[p]])
+        assert (np.diff(arrays["blk_rowb_bwd"][p]) >= 0).all()
+    dense = [dense_edge_count(arrays, p) for p in range(2)]
+    spec = ModelSpec("graphsage", (6, 8, 5), train_size=art.n_train)
+    counts = spmm_counts("hybrid", spec, arrays, 2,
+                         spec_pairs={"": (fwd, bwd)}, dense_per_part=dense)
+    assert counts["tiles_fwd"] == counts["tiles_bwd"] == int(real.max())
+    assert counts["tile_stack_bytes"] == stack[0].nbytes
+    n_edges = int((art.dst < art.pad_inner).sum())
+    assert counts["dense_share"] == pytest.approx(sum(dense) / n_edges)
+    _, _, split, _, _ = build_split_block_layouts(
+        art.src, art.dst, art.pad_inner, art.n_ext, pi, pe, occupancy_min=4,
+        tile_r=tile, tile_c=tile)
+    assert not any(k.endswith("tiles_bwd") for k in split)
+    assert {"int_blk_order_bwd", "fro_blk_order_bwd"} <= set(split)
+
+
+def test_new_defaults_densify_twice_the_tiles():
+    """At the defaults (time break-even, one stack in the device bytes both
+    stacks used to take) _select_dense picks twice the 512 x 512 tiles of
+    the old ones (byte break-even, the same bytes a direction) on a
+    clustered stand-in whose tiles thin out from 2,000 edges to 242, the
+    shape of the benchmark graph's ranking; every tile it adds carries
+    more edges than the time break-even."""
+    from bnsgcn_tpu.config import Config
+    from bnsgcn_tpu.ops.block_spmm import _select_dense, effective_occupancy
+    tile_bytes = 512 * 512
+    counts = (242 + 1758 * np.exp(-np.arange(24576) / 6000.0)).astype(
+        np.int64)
+    tile_id = np.repeat(np.arange(len(counts), dtype=np.int32), counts)
+    occ = effective_occupancy(Config().block_occupancy, 512, 512)
+    assert occ == 256
+    new = _select_dense(tile_id, occ, Config().block_tile_budget_mb << 20,
+                        tile_bytes, need_inverse=False,
+                        n_tiles=len(counts))[3]
+    old = _select_dense(tile_id, 512, 2048 << 20, tile_bytes,
+                        need_inverse=False, n_tiles=len(counts))[3]
+    assert int(old.sum()) == 8192
+    assert int(new.sum()) == 2 * int(old.sum()) == 16384
+    assert (counts[new & ~old] >= occ).all()
+
+
+@pytest.mark.parametrize("n_parts", [1, 2])
+def test_place_blocks_copies_a_large_shard_in_chunks(monkeypatch, n_parts):
+    """A tile stack whose per-device shard reaches the slow-transfer size is
+    placed in pieces along its tile axis into one donated buffer: the same
+    values and sharding as one device_put, the last piece short; smaller
+    arrays go through device_put as they are."""
+    from bnsgcn_tpu import trainer
+    from bnsgcn_tpu.parallel.mesh import make_parts_mesh
+    monkeypatch.setattr(trainer, "_PUT_SLOW_BYTES", 1 << 12)
+    monkeypatch.setattr(trainer, "_PUT_CHUNK_BYTES", 1 << 10)
+    puts = []
+    real_put = jax.device_put
+    monkeypatch.setattr(jax, "device_put",
+                        lambda x, *a, **k: puts.append(np.shape(x))
+                        or real_put(x, *a, **k))
+    mesh = make_parts_mesh(n_parts)
+    rng = np.random.default_rng(5)
+    tiles = rng.integers(-127, 128, (n_parts, 37, 8, 16)).astype(np.int8)
+    rowb = np.arange(n_parts * 37, dtype=np.int32).reshape(n_parts, 37)
+    out = trainer.place_blocks({"blk_tiles_fwd": tiles, "blk_rowb_fwd": rowb},
+                               mesh)
+    np.testing.assert_array_equal(np.asarray(out["blk_tiles_fwd"]), tiles)
+    np.testing.assert_array_equal(np.asarray(out["blk_rowb_fwd"]), rowb)
+    assert out["blk_tiles_fwd"].sharding == out["blk_rowb_fwd"].sharding
+    # 37 tiles of 128 B a shard, 1 KiB pieces: 8 + 8 + 8 + 8 + 5
+    assert puts == [(n_parts, 8, 8, 16)] * 4 + [(n_parts, 5, 8, 16),
+                                                 (n_parts, 37)]

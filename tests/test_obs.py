@@ -664,7 +664,8 @@ def test_obs_report_spmm_counts_and_trace_clock():
     sys.path.insert(0, os.path.join(REPO, "tools"))
     import obs_report
     events = [{"ts": 90.0, "kind": "run_header", "rank": 0, "config": {},
-               "spmm": {"path": "hybrid", "tiles_fwd": 190, "tiles_bwd": 188,
+               "spmm": {"path": "hybrid", "tiles_fwd": 190, "tiles_bwd": 190,
+                        "tile_stack_bytes": 190 << 18, "dense_share": 0.76993,
                         "dense_path_fwd": "pallas",
                         "dense_path_bwd": "pallas",
                         "dense_edges": 669167, "residual_slots_fwd": 220512,
@@ -684,14 +685,17 @@ def test_obs_report_spmm_counts_and_trace_clock():
     out = []
     obs_report.render(obs_report.summarize(events), write=out.append)
     spmm = next(ln for ln in out if ln.startswith("spmm: "))
-    assert spmm == ("spmm: hybrid | dense tiles 190 fwd / 188 bwd via pallas "
-                    "carry 669167 edges | residual slots 220512 fwd / 219424 "
+    assert spmm == ("spmm: hybrid | dense tiles 190 fwd / 190 bwd via pallas "
+                    "carry 669167 edges (77.0% of all) in one 48 MiB stack | "
+                    "residual slots 220512 fwd / 219424 "
                     "bwd a call for 200000 / 200000 edges (1.103 / 1.097 "
                     "slots an edge) | 6 aggregations a step (3 fwd + 3 bwd)"
                     " | widths [256, 256, 41] fwd / [256, 256, 41] bwd; "
                     "projects first: layer 3 (256 -> 41)")
-    # a header written before the edges, the paths and the widths were
-    # counted keeps its old line
+    # a header written before the edges, the paths, the widths and the
+    # stack were counted keeps its old line
+    del events[0]["spmm"]["tile_stack_bytes"]
+    del events[0]["spmm"]["dense_share"]
     for d in ("fwd", "bwd"):
         del events[0]["spmm"][f"residual_edges_{d}"]
         del events[0]["spmm"][f"dense_path_{d}"]
@@ -699,7 +703,7 @@ def test_obs_report_spmm_counts_and_trace_clock():
     out = []
     obs_report.render(obs_report.summarize(events), write=out.append)
     spmm = next(ln for ln in out if ln.startswith("spmm: "))
-    assert "188 bwd carry 669167 edges" in spmm
+    assert "190 bwd carry 669167 edges | residual" in spmm
     assert "slots 220512 fwd / 219424 bwd a call | 6 aggregations" in spmm
     assert spmm.endswith("(3 fwd + 3 bwd)")
     laid = out[out.index(next(ln for ln in out

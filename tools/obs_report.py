@@ -319,6 +319,16 @@ def _dense_via(sp: dict) -> str:
             else f" via {paths[0]} fwd / {paths[1]} bwd")
 
 
+def _dense_share(sp: dict) -> str:
+    """The share of all edges the dense tiles carry and the device bytes of
+    the one stack both directions read; empty for a header written before
+    the count."""
+    share, nbytes = sp.get("dense_share"), sp.get("tile_stack_bytes")
+    if share is None or nbytes is None:
+        return ""
+    return f" ({share:.1%} of all) in one {nbytes / 2**20:.0f} MiB stack"
+
+
 def _agg_widths(sp: dict) -> str:
     """The columns each aggregation of a step gathers, in layer order, and
     the layers that project before they aggregate (fin -> fout); empty for
@@ -367,7 +377,7 @@ def render(s: dict, write=print):
             write(f"spmm: {sp.get('path')} | dense tiles "
                   f"{sp.get('tiles_fwd')} fwd / {sp.get('tiles_bwd')} bwd"
                   + _dense_via(sp) + f" carry {sp.get('dense_edges')} "
-                  "edges | residual slots "
+                  "edges" + _dense_share(sp) + " | residual slots "
                   f"{sp.get('residual_slots_fwd')} fwd / "
                   f"{sp.get('residual_slots_bwd')} bwd a call"
                   + _slots_per_edge(sp) + " | "
