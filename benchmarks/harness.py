@@ -4,17 +4,22 @@ Everything that belongs to one configuration, one cell or one per-layer metric
 is a file the harness finds by name under a benchmark root (this directory):
 
     configs/<config>.json      flags for `parse_config`, the model's sizes,
-                               the name of its plain reference
-    workloads/<cell>.json      config, graph parameters, graph_seed, chips
+                               the names of its data module and its plain
+                               reference
+    datasets/<data>.py         the configuration's data: what names it, the
+                               program's data flags, the cache it builds at
+                               set-up, the reference's inputs and the layout
+                               the reference replays (benchmarks/README.md)
+    workloads/<cell>.json      config, chips, flags, limits, and what the
+                               data module reads (a graph and its seed)
     metrics/<metric>.json      layer, unit, moves, reducer + arguments
     reducers/<reducer>.py      `reduce(ctx, **args) -> number or None`
     reference/<name>.py        the model family's plain reference and FLOP
-                               counter: `build_graph_tables`, `run_steps`,
-                               `step_flops`, `ADAM_B1`
+                               counter: `run_steps`, `step_flops`, `ADAM_B1`
 
-A run is: set-up (first run in a checkout: generate the graph from
-`graph_seed`, partition it, build layouts, compile; later runs: load all of
-that from `cache/` and `.jax_cache/`), then ONE `run_training` call whose
+A run is: set-up (first run in a checkout: the data module builds the cell's
+data cache, the program builds its layouts and compiles; later runs: load all
+of that from `cache/` and `.jax_cache/`), then ONE `run_training` call whose
 epochs 0-9 warm up (through the loop's first checkpoint and norm probe) and
 whose later epochs are the timed window, then the comparison with the plain
 reference that decides `correct`.
@@ -56,7 +61,7 @@ class BenchError(RuntimeError):
 # files found by name
 # ---------------------------------------------------------------------------
 
-def _load_json(path):
+def load_json(path):
     with open(path) as f:
         return json.load(f)
 
@@ -65,7 +70,7 @@ def load_workload(name: str, root: str = HERE) -> dict:
     path = os.path.join(root, "workloads", name + ".json")
     if not os.path.exists(path):
         raise BenchError(f"no workload file {path}")
-    wl = _load_json(path)
+    wl = load_json(path)
     wl["name"] = name
     return wl
 
@@ -74,7 +79,7 @@ def load_config(name: str, root: str = HERE) -> dict:
     path = os.path.join(root, "configs", name + ".json")
     if not os.path.exists(path):
         raise BenchError(f"no configuration file {path}")
-    return _load_json(path)
+    return load_json(path)
 
 
 def list_names(kind: str, root: str = HERE) -> list:
@@ -83,7 +88,7 @@ def list_names(kind: str, root: str = HERE) -> list:
 
 
 def load_metrics(root: str = HERE) -> dict:
-    return {n: _load_json(os.path.join(root, "metrics", n + ".json"))
+    return {n: load_json(os.path.join(root, "metrics", n + ".json"))
             for n in list_names("metrics", root)}
 
 
@@ -108,6 +113,14 @@ def load_module(kind: str, name: str, root: str = HERE):
     raise BenchError(f"no {name}.py under {root}/{kind}")
 
 
+def load_data(config: dict, root: str = HERE):
+    """The data module the configuration's file names."""
+    if "data" not in config:
+        raise BenchError(f"configuration {config.get('name')!r} names no "
+                         f"data module")
+    return load_module("datasets", config["data"], root)
+
+
 def load_reducer(name: str, root: str = HERE):
     return load_module("reducers", name, root).reduce
 
@@ -128,30 +141,31 @@ def flag_value(flags: list, flag: str, default=None):
 # the cell's directories and the program's argv
 # ---------------------------------------------------------------------------
 
-def data_key(wl: dict, config: dict) -> str:
-    """Names the dataset as the program sees it: the graph and its seed, the
-    configuration's flags and the cell's `flags`. Cells of one configuration
-    that differ only in `step_flags` (flags that touch neither the partition
-    nor its artifacts, such as --use-pallas) share one set-up."""
-    what = {"graph": wl["graph"], "graph_seed": wl["graph_seed"],
-            "dataset": config["dataset"], "reference": config["reference"],
+def data_key(wl: dict, config: dict, root: str = HERE) -> str:
+    """Names the dataset as the program sees it: what the data module names
+    it by (a graph and its seed), the reference, the configuration's flags
+    and the cell's `flags`. Cells of one configuration that differ only in
+    `step_flags` (flags that touch neither the data nor its artifacts, such
+    as --use-pallas) share one set-up."""
+    what = {**load_data(config, root).key_fields(wl, config),
+            "reference": config["reference"],
             "config_flags": config["flags"], "flags": wl.get("flags", [])}
     digest = hashlib.sha1(json.dumps(what, sort_keys=True).encode())
     return f"{wl['config']}-{digest.hexdigest()[:10]}"
 
 
 class CellDirs:
-    """`cache/data/<key>/` holds what cells on one dataset share (partition,
-    layouts, the reference's inputs); `cache/cells/<cell>/` what is the
-    cell's own (its runs' directories and the epoch wall that sizes them)."""
+    """`cache/data/<key>/` holds what cells on one dataset share (whatever
+    the data module builds there, and under `reference/` the reference's
+    inputs with `done`, the sizes `step_flops` takes); `cache/cells/<cell>/`
+    what is the cell's own (its runs' directories and the epoch wall that
+    sizes them)."""
 
     def __init__(self, cell: str, wl: dict, config: dict, root: str = HERE):
-        self.graph_name = data_key(wl, config)
-        data = os.path.join(root, "cache", "data", self.graph_name)
-        self.parts = os.path.join(data, "parts")
-        self.layouts = os.path.join(data, "layouts")
-        self.ref = os.path.join(data, "reference")
-        self.meta = os.path.join(self.parts, self.graph_name, "meta.json")
+        self.root = root
+        self.key = data_key(wl, config, root)
+        self.data = os.path.join(root, "cache", "data", self.key)
+        self.ref = os.path.join(self.data, "reference")
         own = os.path.join(root, "cache", "cells", cell)
         self.run = os.path.join(own, "run")
         self.calibration = os.path.join(own, "calibration.json")
@@ -161,15 +175,23 @@ class CellDirs:
         os.makedirs(self.run)
 
 
+def cell_flags(wl: dict, config: dict) -> list:
+    """The configuration's flags, then the cell's own."""
+    return (list(config["flags"]) + list(wl.get("flags", []))
+            + list(wl.get("step_flags", [])))
+
+
 def build_argv(config: dict, wl: dict, dirs: CellDirs, seed: int,
                n_epochs: int, trace: bool, tag: str) -> list:
-    """The product's flags for one `run_training` call. Everything about the
-    loop stays at the product's defaults except what the timing protocol
-    fixes: --no-eval, --fix-seed, and the profiler off unless traced."""
+    """The product's flags for one `run_training` call: the cell's, the data
+    module's, then what the timing protocol fixes. Everything about the loop
+    stays at the product's defaults except --no-eval, --fix-seed, the
+    profiler off unless traced, and --skip-partition: the data module's
+    `ensure` prepared the program's data at set-up, and a call only loads
+    it."""
     argv = cell_flags(wl, config)
-    argv += ["--dataset", config["dataset"], "--graph-name", dirs.graph_name,
-             "--part-path", dirs.parts, "--cache-dir", dirs.layouts,
-             "--ckpt-path", os.path.join(dirs.run, tag, "ckpt"),
+    argv += load_data(config, dirs.root).program_flags(config, wl, dirs)
+    argv += ["--ckpt-path", os.path.join(dirs.run, tag, "ckpt"),
              "--results-path", os.path.join(dirs.run, tag, "results"),
              "--obs-log", os.path.join(dirs.run, tag, "obs.jsonl"),
              "--seed", str(seed), "--fix-seed", "--no-eval",
@@ -249,90 +271,6 @@ class StepTap:
 
 
 # ---------------------------------------------------------------------------
-# set-up of a cell's cache (first run in a checkout)
-# ---------------------------------------------------------------------------
-
-def is_inductive(config: dict) -> bool:
-    return "--inductive" in config["flags"]
-
-
-def cell_flags(wl: dict, config: dict) -> list:
-    """The configuration's flags, then the cell's own."""
-    return (list(config["flags"]) + list(wl.get("flags", []))
-            + list(wl.get("step_flags", [])))
-
-
-def n_partitions(wl: dict, config: dict) -> int:
-    return int(flag_value(cell_flags(wl, config), "--n-partitions", 1))
-
-
-def ensure_dataset(wl: dict, config: dict, dirs: CellDirs, ref, log) -> bool:
-    """Generate, partition and store the cell's dataset unless the cache
-    holds it. Returns True when it was built now."""
-    if os.path.exists(dirs.meta) and os.path.exists(
-            os.path.join(dirs.ref, "done")):
-        return False
-    from benchmarks import graphgen
-    from bnsgcn_tpu.config import parse_config
-    from bnsgcn_tpu.data.graph import Graph
-    from bnsgcn_tpu.run import prepare_partition
-
-    t0 = time.time()
-    full = graphgen.make_graph(wl["graph"], int(wl["graph_seed"]))
-    log(f"[bench] graph generated in {time.time() - t0:.1f}s: "
-        f"{full['n_nodes']} nodes, {full['src'].shape[0]} edges")
-    inductive = is_inductive(config)
-    # the program gets the dataset and takes its own inductive split, the
-    # lines main.py -> prepare_partition run when they load a dataset
-    g = Graph(n_nodes=full["n_nodes"], src=full["src"], dst=full["dst"],
-              feat=full["feat"], label=full["label"],
-              train_mask=full["train_mask"], val_mask=full["val_mask"],
-              test_mask=full["test_mask"], multilabel=full["multilabel"])
-    if inductive:
-        g = g.subgraph(g.train_mask)
-    shutil.rmtree(dirs.parts, ignore_errors=True)
-    argv = build_argv(config, wl, dirs, int(wl["graph_seed"]), 1, False,
-                      "partition")
-    argv.remove("--skip-partition")
-    t0 = time.time()
-    prepare_partition(parse_config(argv), g, load=False)
-    log(f"[bench] partition + artifacts in {time.time() - t0:.1f}s")
-    del g
-    # the reference's inputs, from the benchmark's own split of the graph
-    t0 = time.time()
-    tg = graphgen.training_graph(full, inductive)
-    del full
-    shutil.rmtree(dirs.ref, ignore_errors=True)
-    os.makedirs(dirs.ref)
-    for k in ("src", "dst"):
-        np.save(os.path.join(dirs.ref, f"{k}.npy"), tg[k].astype(np.int32))
-    for k in ("feat", "label", "train_mask"):
-        np.save(os.path.join(dirs.ref, f"{k}.npy"), tg[k])
-    np.save(os.path.join(dirs.ref, "in_deg.npy"),
-            np.bincount(tg["dst"], minlength=tg["n_nodes"]).astype(np.float32))
-    tables = ref.build_graph_tables(tg["src"], tg["dst"], tg["n_nodes"])
-    for k, v in tables.items():
-        np.save(os.path.join(dirs.ref, f"table_{k}.npy"), v)
-    with open(os.path.join(dirs.ref, "done"), "w") as f:
-        json.dump({"n_nodes": tg["n_nodes"],
-                   "n_edges": int(tg["src"].shape[0])}, f)
-    log(f"[bench] reference inputs in {time.time() - t0:.1f}s")
-    return True
-
-
-def load_reference_inputs(dirs: CellDirs, edges: bool = False):
-    info = _load_json(os.path.join(dirs.ref, "done"))
-    graph = {"n_nodes": info["n_nodes"], "n_edges": info["n_edges"]}
-    for k in ("feat", "label", "train_mask", "in_deg") + (
-            ("src", "dst") if edges else ()):
-        graph[k] = np.load(os.path.join(dirs.ref, f"{k}.npy"))
-    tables = {}
-    for p in glob.glob(os.path.join(dirs.ref, "table_*.npy")):
-        tables[os.path.basename(p)[len("table_"):-len(".npy")]] = np.load(p)
-    return graph, tables
-
-
-# ---------------------------------------------------------------------------
 # one run
 # ---------------------------------------------------------------------------
 
@@ -376,7 +314,7 @@ def calibrated_epoch_s(wl, config, dirs, seed, log) -> float:
     builds the layout cache and compiles)."""
     from benchmarks import obsread
     if os.path.exists(dirs.calibration):
-        return float(_load_json(dirs.calibration)["epoch_s"])
+        return float(load_json(dirs.calibration)["epoch_s"])
     n = TIMER_WARMUP + CALIBRATION_EPOCHS
     argv = build_argv(config, wl, dirs, seed, n, False, "calibrate")
     t0 = time.time()
@@ -413,78 +351,6 @@ def program_numbers(tap: StepTap, losses: list, adam_b1: float) -> dict:
             "grad1": leaf_norms(g1), "dparam": leaf_norms(dp)}
 
 
-def read_layout(wl: dict, config: dict, dirs: CellDirs, graph: dict) -> dict:
-    """The deployment's layout, read from the partition on disk: which part
-    holds each node of the training graph and in which row, the padded row
-    and boundary counts, and the cell's sampling rate. It is all the
-    reference takes from the program's files: it needs it to replay dropout
-    and boundary sampling, which are drawn by part and row.
-
-    What the replay rests on is first held against the benchmark's own graph
-    (`graph`: its features, labels, training flags and the in-degrees counted
-    from its own edges): every node sits in exactly one row, under `pad_inner`,
-    and that row carries this node's own features, label, training flag and
-    in-degree. A row order or padding that departs from the node ids the
-    files declare is an error here, and is not mirrored into the reference.
-    Boundary sets are the reference's own, from the benchmark's edges
-    (`boundary_lists`), held against `pad_boundary` there."""
-    meta = _load_json(dirs.meta)
-    n = int(graph["n_nodes"])
-    n_parts, pad_inner = int(meta["n_parts"]), int(meta["pad_inner"])
-    part_of = np.full(n, -1, np.int32)
-    row_of = np.zeros(n, np.int32)
-    seen = np.zeros(n, np.int64)
-
-    def fault(what):
-        raise BenchError(f"the partition on disk {what}")
-
-    for p in range(n_parts):
-        with np.load(os.path.join(os.path.dirname(dirs.meta),
-                                  f"part{p}.npz")) as f:
-            nid = f["global_nid"]
-            rows = np.nonzero(nid >= 0)[0]
-            ids = nid[rows]
-            if rows.size and (rows.max() >= pad_inner or ids.max() >= n):
-                fault(f"holds a row over pad_inner or a node over {n} in "
-                      f"part {p}")
-            feat = f["feat"]
-            if meta.get("feat_dtype", "float32") == "bfloat16":
-                import ml_dtypes
-                feat = feat.view(ml_dtypes.bfloat16)
-            held = {"features": np.allclose(
-                        feat[rows].astype(np.float32), graph["feat"][ids],
-                        rtol=2.0 ** -7, atol=1e-6),
-                    "labels": np.array_equal(f["label"][rows],
-                                             graph["label"][ids]),
-                    "training flags": np.array_equal(
-                        f["train_mask"][rows], graph["train_mask"][ids]),
-                    "in-degrees": np.array_equal(f["in_deg"][rows],
-                                                 graph["in_deg"][ids])}
-        for what, ok in held.items():
-            if not ok:
-                fault(f"holds other {what} in part {p} than the nodes its "
-                      f"rows name")
-        seen += np.bincount(ids, minlength=n)
-        part_of[ids] = p
-        row_of[ids] = rows
-    if (seen != 1).any():
-        fault("does not hold every node of the training graph exactly once")
-    return {"n_parts": n_parts, "pad_inner": pad_inner,
-            "pad_boundary": int(meta["pad_boundary"]),
-            "rate": float(flag_value(cell_flags(wl, config),
-                                     "--sampling-rate", 1.0)),
-            "part_of": part_of, "row_of": row_of}
-
-
-def reference_model(config: dict, graph: dict) -> dict:
-    model = dict(config["model"])
-    lab = graph["label"]
-    model["multilabel"] = bool(lab.ndim == 2)
-    if lab.ndim == 1:
-        model["n_class"] = int(lab.max()) + 1
-    return model
-
-
 def run(workload: str, seed: int, seconds: float, trace: bool,
         root: str = HERE, require_tpu: bool = True, out=sys.stdout,
         err=sys.stderr) -> int:
@@ -497,6 +363,7 @@ def run(workload: str, seed: int, seconds: float, trace: bool,
 
     wl = load_workload(workload, root)
     config = load_config(wl["config"], root)
+    data = load_data(config, root)
     chips = int(wl["chips"])
     if REPO not in sys.path:
         sys.path.insert(0, REPO)
@@ -519,9 +386,10 @@ def run(workload: str, seed: int, seconds: float, trace: bool,
         f"{cache_dir}")
 
     ref = load_reference(config, root)
+    data.check_config(config, [wl], ref)
     dirs = CellDirs(workload, wl, config, root)
     dirs.fresh_run_dir()
-    built = ensure_dataset(wl, config, dirs, ref, log)
+    built = data.ensure(wl, config, dirs, ref, log)
     ep_cal = calibrated_epoch_s(wl, config, dirs, seed, log)
     first = (TRACE_LAST_EPOCH + 2) if trace else WINDOW_FIRST
     n_epochs = first + max(int(math.ceil(seconds / ep_cal)), 2)
@@ -547,7 +415,7 @@ def run(workload: str, seed: int, seconds: float, trace: bool,
            "device": device, "events": events, "first_epoch": first,
            "epoch_s": epoch_s, "setup_s": setup_s, "peak_bytes": peak,
            "trace_events": None, "reference": ref,
-           "ref_info": _load_json(os.path.join(dirs.ref, "done")),
+           "ref_info": load_json(os.path.join(dirs.ref, "done")),
            "breakdown_notes": {}}
 
     # ---- correct: the timed call's first steps against the reference ----
@@ -556,12 +424,11 @@ def run(workload: str, seed: int, seconds: float, trace: bool,
     gc.collect()
     jax.clear_caches()
     t_ref = time.time()
-    graph, tables = load_reference_inputs(
-        dirs, edges=n_partitions(wl, config) > 1)
-    model = reference_model(config, graph)
-    layout = read_layout(wl, config, dirs, graph)
-    want = ref.run_steps(graph, tables, model, seed, layout, REF_STEPS)
-    del graph, tables
+    inputs, tables = data.reference_inputs(wl, config, dirs)
+    model = data.reference_model(config, inputs)
+    layout = data.layout(wl, config, dirs, inputs)
+    want = ref.run_steps(inputs, tables, model, seed, layout, REF_STEPS)
+    del inputs, tables
     numbers = ref_check.compare(prog, want, wl["limits"])
     correct = bool(failed == 0 and all(v <= lim for v, lim
                                        in numbers.values()))

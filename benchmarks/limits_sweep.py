@@ -50,21 +50,23 @@ def main(argv=None) -> int:
     from bnsgcn_tpu import run as run_mod
     from bnsgcn_tpu.utils.platform import place_compile_cache
     place_compile_cache()
+    data = harness.load_data(config)
     ref = harness.load_reference(config)
+    data.check_config(config, [wl], ref)
     dirs = harness.CellDirs(args.workload, wl, config)
     dirs.fresh_run_dir()
     log = lambda m: print(m, file=sys.stderr, flush=True)
-    harness.ensure_dataset(wl, config, dirs, ref, log)
+    data.ensure(wl, config, dirs, ref, log)
     ints = lambda text: [int(s) for s in text.split(",") if s]
     seeds, cseeds = ints(args.seeds), ints(args.control_seeds)
     sseeds = ints(args.store_seeds)
     if not os.path.exists(dirs.calibration):
         harness.calibrated_epoch_s(
             wl, config, dirs, (seeds + cseeds + sseeds)[0], log)
-    n_parts = harness.n_partitions(wl, config)
-    graph, tables = harness.load_reference_inputs(dirs, edges=n_parts > 1)
-    model = harness.reference_model(config, graph)
-    layout = harness.read_layout(wl, config, dirs, graph)
+    graph, tables = data.reference_inputs(wl, config, dirs)
+    model = data.reference_model(config, graph)
+    layout = data.layout(wl, config, dirs, graph)
+    n_parts = layout["n_parts"]
     free = {k: 1.0 for k in ("loss1_gap", "loss2_gap", "loss3_gap",
                              "grad1_gap", "dparam_gap")}
     refs, progs, rows = {}, {}, []

@@ -48,7 +48,7 @@ def make_root(root: str) -> str:
     # a second tiny configuration and a four-part cell: the Yelp recipe's
     # shape (float32, two dense tail layers, multilabel) at BNS rate 0.25
     cfg = {"name": "yelp-tiny", "source": "test", "reduced": [],
-           "dataset": "yelp", "reference": "sage",
+           "data": "graph", "dataset": "yelp", "reference": "sage",
            "model": {"model": "graphsage", "n_layers": 4, "n_hidden": 32,
                      "n_linear": 2, "dropout": 0.1, "lr": 0.001,
                      "use_pp": True, "dtype": "float32", "n_feat": 24,

@@ -7,6 +7,7 @@ import os
 import pytest
 
 import bench_tiny
+from benchmarks.datasets import graph
 
 
 @pytest.fixture(scope="module")
@@ -58,7 +59,7 @@ def test_first_run_builds_and_second_run_only_loads(root, first_run):
 
 def test_seed_is_the_training_run_not_the_graph(root, first_run):
     """Same cell, another --seed: same partition on disk, other losses."""
-    meta = bench_tiny.cell_dirs(root, "tiny.p1").meta
+    meta = graph.meta_path(bench_tiny.cell_dirs(root, "tiny.p1"))
     before = os.path.getmtime(meta)
     rc, res, _ = bench_tiny.run_cell(root, seed=99)
     assert rc == 0 and res["correct"] is True
@@ -146,8 +147,8 @@ def test_new_config_cell_metric_and_reference_as_files_only(root, first_run):
     assert res["metrics"]["loop.first_wall_s"]["value"] > 0
     assert res["metrics"]["step.flops"]["value"] == 7 * 2000 * 48
     # another configuration is another dataset to the program: its own cache
-    assert (bench_tiny.cell_dirs(root, "tiny-wide.p1").meta
-            != bench_tiny.cell_dirs(root, "tiny.p1").meta)
+    assert (graph.meta_path(bench_tiny.cell_dirs(root, "tiny-wide.p1"))
+            != graph.meta_path(bench_tiny.cell_dirs(root, "tiny.p1")))
 
 
 def test_a_configuration_without_a_reference_is_refused(root):
@@ -174,13 +175,13 @@ def test_cells_that_differ_in_step_flags_share_one_set_up(root, first_run):
         json.dump(wl, f)
     a = bench_tiny.cell_dirs(root, "tiny.p1")
     b = bench_tiny.cell_dirs(root, "tiny-pallas.p1")
-    assert (a.parts, a.layouts, a.ref) == (b.parts, b.layouts, b.ref)
+    assert (a.key, a.data, a.ref) == (b.key, b.data, b.ref)
     assert a.run != b.run and a.calibration != b.calibration
-    before = os.path.getmtime(a.meta)
+    before = os.path.getmtime(graph.meta_path(a))
     rc, res, err = bench_tiny.run_cell(root, seed=41, cell="tiny-pallas.p1")
     assert rc == 0 and res["correct"] is True
     assert "graph generated" not in err and "calibrating call" in err
-    assert os.path.getmtime(a.meta) == before
+    assert os.path.getmtime(graph.meta_path(a)) == before
     from benchmarks import harness
     cfg = harness.load_config(wl["config"], root)
     assert "--use-pallas" in harness.build_argv(cfg, wl, b, 1, 20, False, "t")
@@ -188,7 +189,7 @@ def test_cells_that_differ_in_step_flags_share_one_set_up(root, first_run):
     wl["flags"] = wl["flags"] + ["--partition-obj", "cut"]
     with open(os.path.join(root, "workloads", "tiny-cut.p1.json"), "w") as f:
         json.dump(wl, f)
-    assert bench_tiny.cell_dirs(root, "tiny-cut.p1").parts != a.parts
+    assert bench_tiny.cell_dirs(root, "tiny-cut.p1").data != a.data
 
 
 def test_refuses_without_a_tpu(root):

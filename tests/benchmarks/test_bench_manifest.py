@@ -38,8 +38,8 @@ def test_cells_and_configs_have_their_files(manifest):
         assert on_disk["source"] == c["source"]
         assert on_disk["reduced"] == c["reduced"]
         ref = harness.load_reference(on_disk)   # named in the file, found
-        for name in ("build_graph_tables", "run_steps", "step_flops",
-                     "ADAM_B1"):
+        harness.load_data(on_disk)              # named in the file, found
+        for name in ("run_steps", "step_flops", "ADAM_B1"):
             assert hasattr(ref, name), name
         assert NAME.match(c["name"]) and len(c["why"]) <= 200
     cells = [w["name"] for w in manifest["workloads"]]
@@ -99,21 +99,60 @@ def test_every_cell_reports_enough(manifest):
 
 
 def test_config_flags_and_model_block_agree():
-    from bnsgcn_tpu.config import parse_config
+    """Each configuration's data module holds it to what its family needs
+    (for `graph`: the flags against the model block, the reference's
+    `build_graph_tables`, each cell's graph against the model's widths)."""
+    wls = [harness.load_workload(w) for w in harness.list_names("workloads")]
     for name in harness.list_names("configs"):
         c = harness.load_config(name)
-        cfg = parse_config(c["flags"] + ["--dataset", c["dataset"]])
-        m = c["model"]
-        assert (cfg.model, cfg.n_layers, cfg.n_hidden, cfg.n_linear) == (
-            m["model"], m["n_layers"], m["n_hidden"], m["n_linear"])
-        assert (cfg.dropout, cfg.lr, cfg.use_pp, cfg.dtype) == (
-            m["dropout"], m["lr"], m["use_pp"], m["dtype"])
-        assert cfg.norm == "layer" and cfg.weight_decay == 0.0
-        for w in harness.list_names("workloads"):
-            wl = harness.load_workload(w)
-            if wl["config"] == name:
-                assert wl["graph"]["n_feat"] == m["n_feat"]
-                assert wl["graph"]["n_class"] == m["n_class"]
+        harness.load_data(c).check_config(
+            c, [wl for wl in wls if wl["config"] == name],
+            harness.load_reference(c))
+
+
+def _no_tables(c, wl):
+    return c, wl, object()
+
+
+def _flag(flag, value):
+    def change(c, wl):
+        flags = c["flags"]
+        if flag in flags:
+            flags[flags.index(flag) + 1] = value
+        else:
+            flags += [flag, value]
+        return c, wl, None
+    return change
+
+
+def _graph(key):
+    def change(c, wl):
+        wl["graph"][key] += 1
+        return c, wl, None
+    return change
+
+
+@pytest.mark.parametrize("change, says", [
+    (_no_tables, "build_graph_tables"),
+    (_flag("--n-hidden", "128"), "n_hidden"),
+    (_flag("--n-layers", "3"), "n_layers"),
+    (_flag("--dropout", "0.4"), "dropout"),
+    (_flag("--lr", "0.02"), "lr"),
+    (_flag("--dtype", "float32"), "dtype"),
+    (_flag("--norm", "batch"), "norm"),
+    (_flag("--weight-decay", "0.001"), "weight_decay"),
+    (_graph("n_feat"), "n_feat"),
+    (_graph("n_class"), "n_class"),
+], ids=["tables", "n_hidden", "n_layers", "dropout", "lr", "dtype", "norm",
+        "weight_decay", "n_feat", "n_class"])
+def test_graph_data_refuses_a_configuration_that_disagrees(change, says):
+    """Every check the graph data module makes bites on the real files."""
+    c = harness.load_config("sage-reddit")
+    wl = harness.load_workload("sage-reddit.whole.p1")
+    c, wl, ref = change(c, wl)
+    data = harness.load_data(c)
+    with pytest.raises(harness.BenchError, match=says):
+        data.check_config(c, [wl], ref or harness.load_reference(c))
 
 
 def test_paths_hold_only_plain_names():

@@ -9,6 +9,7 @@ import numpy as np
 import pytest
 
 import bench_tiny
+from benchmarks.datasets import graph as graph_data
 from benchmarks.reference import sage
 
 
@@ -54,13 +55,13 @@ def _layout_inputs(root):
     wl = harness.load_workload("tiny.p4", root)
     cfg = harness.load_config("yelp-tiny", root)
     dirs = bench_tiny.cell_dirs(root, "tiny.p4")
-    graph, _ = harness.load_reference_inputs(dirs, edges=True)
+    graph, _ = graph_data.reference_inputs(wl, cfg, dirs)
     return harness, wl, cfg, dirs, graph
 
 
 def test_layout_is_read_from_the_partition_on_disk(root, first_run):
     harness, wl, cfg, dirs, graph = _layout_inputs(root)
-    lay = harness.read_layout(wl, cfg, dirs, graph)
+    lay = graph_data.layout(wl, cfg, dirs, graph)
     assert lay["n_parts"] == 4 and lay["rate"] == 0.25
     assert sorted(np.bincount(lay["part_of"]))[0] > 400
     # a node sits in one row of one part
@@ -72,7 +73,8 @@ def test_layout_is_read_from_the_partition_on_disk(root, first_run):
 
 
 def _rewrite_part(dirs, part, change):
-    path = os.path.join(os.path.dirname(dirs.meta), f"part{part}.npz")
+    path = os.path.join(os.path.dirname(graph_data.meta_path(dirs)),
+                        f"part{part}.npz")
     with np.load(path) as f:
         arrays = {k: f[k] for k in f.files}
     change(arrays)
@@ -105,13 +107,12 @@ def test_a_layout_that_departs_from_the_benchmarks_graph_is_refused(
     that do not carry the nodes they name are a fault, not a layout."""
     import shutil
     harness, wl, cfg, dirs, graph = _layout_inputs(root)
-    copy = str(tmp_path / "parts")
-    shutil.copytree(dirs.parts, copy)
-    dirs.meta = os.path.join(copy, dirs.graph_name, "meta.json")
-    harness.read_layout(wl, cfg, dirs, graph)
+    shutil.copytree(graph_data.parts_dir(dirs), str(tmp_path / "parts"))
+    dirs.data = str(tmp_path)
+    graph_data.layout(wl, cfg, dirs, graph)
     _rewrite_part(dirs, 2, change)
     with pytest.raises(harness.BenchError, match=says):
-        harness.read_layout(wl, cfg, dirs, graph)
+        graph_data.layout(wl, cfg, dirs, graph)
 
 
 def test_a_boundary_larger_than_the_partition_pads_is_refused():
